@@ -26,6 +26,8 @@ def main() -> None:
                          "[{name, us_per_call, repeats, derived}, ...]")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import micro, paper_tables
 
     json_rows = []

@@ -68,6 +68,7 @@ so a link that dropped since the snapshot was taken contributes nothing.
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -120,6 +121,10 @@ class Trainer(NamedTuple):
     # data, per-variant rng/eta/gamma/lr — under ONE vmapped scan (see
     # run_rounds_batch in build_trainer); None only on hand-built stubs
     run_rounds_batch: Callable = None
+    # the same two drivers' programs, lowered and not run (compiled-HLO
+    # inspection: which kernels a round runs); None on hand-built stubs
+    lower_rounds: Callable = None
+    lower_rounds_batch: Callable = None
 
 
 def _node_sketches(node_items, fed: FedConfig):
@@ -516,10 +521,16 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                                  (fed.num_nodes, fed.num_nodes))
             return flatten.apply_matrix_flat(buf, a), tstate
         if fed.algorithm == "cdfa_m":
-            # C-DFA(M): only the leaf-prefix columns travel the wire
+            # C-DFA(M): only the leaf-prefix columns travel the wire; a
+            # prefix ending inside a lane tile takes the XLA mix
             prefix = flatten.prefix_length(layout, fed.cdfa_fraction)
-            head, tstate = transport.exchange(buf[:, :prefix], eta, gamma,
-                                              tstate, rnd)
+            tr = transport
+            if hasattr(transport, "use_kernel"):
+                tr = dataclasses.replace(
+                    transport, use_kernel=flatten.prefix_use_kernel(
+                        prefix, transport.use_kernel))
+            head, tstate = tr.exchange(buf[:, :prefix], eta, gamma,
+                                       tstate, rnd)
             return jnp.concatenate([head, buf[:, prefix:]], axis=1), tstate
         if hier_fmt:
             # two-tier cluster consensus: codec the wire payloads the
@@ -527,16 +538,9 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
             # read the — possibly fault-overridden — codec'd frames,
             # the self-cancellation keeps the node's own clean payload),
             # then run intra + leader tiers + re-merge burst in one shot
-            sim = getattr(transport, "simulate_wire", False)
-            codec = transport.codec
-            if sent is None:
-                w_nb = transport_lib._fused_wire(codec, buf, sim)
-                w_self = w_nb
-            elif transport_lib._cast_noops(codec, buf, sim):
-                w_nb, w_self = sent, buf
-            else:
-                w_nb = codec.roundtrip(sent)
-                w_self = codec.roundtrip(buf)
+            w_nb, w_self = transport_lib._wire_pair(
+                transport.codec, buf, sent,
+                getattr(transport, "simulate_wire", False))
             mixed = hier_lib.hier_mix_flat(
                 buf, eta, gamma, wire=w_nb, wire_self=w_self,
                 use_kernel=getattr(transport, "use_kernel", None),
@@ -930,10 +934,10 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                                           donate_argnums=(0,))
         return _batched_cache[key]
 
-    def run_rounds_batch(states: FedState, data, num_rounds: int, *,
-                         rngs: Optional[jax.Array] = None,
-                         n_items: Optional[jax.Array] = None,
-                         eta_stacks=None, gamma_stacks=None, lrs=None):
+    def _batch_call(states: FedState, data, num_rounds: int, *,
+                    rngs: Optional[jax.Array] = None,
+                    n_items: Optional[jax.Array] = None,
+                    eta_stacks=None, gamma_stacks=None, lrs=None):
         """Batched multi-round driver: V whole runs under ONE compiled
         ``vmap(scan)`` — the fleet-sweep twin of :func:`run_rounds`.
 
@@ -958,8 +962,10 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         lrs:   optional (V,) per-variant learning rates — promoted to a
                runtime argument of the shared program; ``None`` keeps
                the TrainConfig rate baked in.
-        Returns ``(final_states, metrics)`` with every leaf/metric
-        stacked along a leading (V,) axis (metrics: ``(V, R, K)``).
+        :func:`run_rounds_batch` returns ``(final_states, metrics)``
+        with every leaf/metric stacked along a leading (V,) axis
+        (metrics: ``(V, R, K)``); this returns the jitted program and
+        its arguments.
         """
         from repro import mobility as mobility_lib
         from repro.mobility import mixing as mobility_mixing
@@ -1086,14 +1092,27 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                         jnp.asarray(plan.straggle))
         fn = _batched_scan(shared, lrs is not None, num_rounds,
                            max_items)
-        return fn(states, data, round_keys, n_items, etas, gammas,
-                  fault_xs, slot_hashes, lrs)
+        return fn, (states, data, round_keys, n_items, etas, gammas,
+                    fault_xs, slot_hashes, lrs)
 
-    def run_rounds(state: FedState, data, num_rounds: int,
-                   rng: Optional[jax.Array] = None,
-                   n_items: Optional[jax.Array] = None,
-                   eta_stack: Optional[jax.Array] = None,
-                   gamma_stack: Optional[jax.Array] = None):
+    def run_rounds_batch(states: FedState, data, num_rounds: int, **kw):
+        fn, args = _batch_call(states, data, num_rounds, **kw)
+        return fn(*args)
+
+    run_rounds_batch.__doc__ = _batch_call.__doc__
+
+    def lower_rounds_batch(states: FedState, data, num_rounds: int, **kw):
+        """The :func:`run_rounds_batch` program for these inputs, lowered
+        and not run (``.compile().as_text()`` shows what the device
+        runs); the states are not donated."""
+        fn, args = _batch_call(states, data, num_rounds, **kw)
+        return fn.lower(*args)
+
+    def _rounds_args(state: FedState, data, num_rounds: int,
+                     rng: Optional[jax.Array] = None,
+                     n_items: Optional[jax.Array] = None,
+                     eta_stack: Optional[jax.Array] = None,
+                     gamma_stack: Optional[jax.Array] = None):
         """Device-resident multi-round driver.
 
         Runs ``num_rounds`` full C-DFL rounds (consensus + local steps)
@@ -1125,8 +1144,9 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         gamma_stack: optional (num_rounds,) per-round step sizes; derived
                from ``eta_stack`` rows via the paper's stability bound
                when omitted.
-        Returns (final_state, metrics) with every metric stacked along a
-        leading (num_rounds,) axis.
+        :func:`run_rounds` returns (final_state, metrics) with every
+        metric stacked along a leading (num_rounds,) axis; this returns
+        the arguments of the jitted scan.
         """
         if rng is None:
             rng = jax.random.PRNGKey(train.seed + 1)
@@ -1242,9 +1262,24 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                         jnp.asarray(plan.byz),
                         jnp.asarray(plan.corrupt),
                         jnp.asarray(plan.straggle))
-        return _scan_rounds(state, data, round_keys, num_rounds, max_items,
-                            n_items, etas, gammas, fault_xs, slot_hashes)
+        return (state, data, round_keys, num_rounds, max_items, n_items,
+                etas, gammas, fault_xs, slot_hashes)
+
+    def run_rounds(state: FedState, data, num_rounds: int, *args, **kw):
+        return _scan_rounds(*_rounds_args(state, data, num_rounds, *args,
+                                          **kw))
+
+    run_rounds.__doc__ = _rounds_args.__doc__
+
+    def lower_rounds(state: FedState, data, num_rounds: int, *args, **kw):
+        """The :func:`run_rounds` scan for these inputs, lowered and not
+        run (``.compile().as_text()`` shows what the device runs); the
+        state is not donated."""
+        return _scan_rounds.lower(*_rounds_args(state, data, num_rounds,
+                                                *args, **kw))
 
     return Trainer(init=init, round=jax.jit(round_fn), eta_fn=eta_fn,
                    run_rounds=run_rounds, mixing_stack=mixing_stack,
-                   run_rounds_batch=run_rounds_batch)
+                   run_rounds_batch=run_rounds_batch,
+                   lower_rounds=lower_rounds,
+                   lower_rounds_batch=lower_rounds_batch)

@@ -218,15 +218,25 @@ def prefix_length(layout: FlatLayout, fraction: float) -> int:
 # Fused consensus operations on the flat buffer
 # --------------------------------------------------------------------------
 
-def _use_kernel(use_kernel: bool | None, width: int) -> bool:
-    """Kernel path needs a lane-aligned buffer width (the Pallas grid
-    tiles whole 128-lane columns); unaligned widths — e.g. the column
-    prefix of a partial mix — fall back to the XLA einsum."""
-    if width % LANE != 0:
-        return False
+def _use_kernel(use_kernel: bool | None) -> bool:
+    """Kernel selection for the flat mixes: ``None`` -> the Pallas kernel
+    on TPU, the XLA form elsewhere; an explicit bool wins (``True`` off
+    TPU runs the kernel body in interpret mode — correctness tests).
+    The kernels tile whole 128-lane columns, so a selected kernel on an
+    unaligned width fails loudly; the one caller with unaligned widths,
+    the C-DFA(M) column prefix, picks the XLA form itself
+    (:func:`prefix_use_kernel`)."""
     if use_kernel is None:
         return jax.default_backend() == "tpu"
     return use_kernel
+
+
+def prefix_use_kernel(prefix: int, use_kernel: bool | None = None):
+    """``use_kernel`` for a mix over the first ``prefix`` columns
+    (C-DFA(M), paper Sec. 5.3): a prefix that ends inside a 128-lane
+    tile cannot be tiled by the Pallas kernels, so it takes the XLA form
+    (``False``) — the only place an unaligned width leaves the kernel."""
+    return False if prefix % LANE else use_kernel
 
 
 # Above this node count the K-term broadcast-sum expansion of the
@@ -254,7 +264,7 @@ def apply_matrix_flat(buf: jax.Array, matrix: jax.Array,
                       use_kernel: bool | None = None) -> jax.Array:
     """``A @ BUF``: one (K,K)@(K,P) operation applies any linear
     consensus operator to every parameter of every node at once."""
-    if _use_kernel(use_kernel, buf.shape[1]):
+    if _use_kernel(use_kernel):
         from repro.kernels import ops
         # an EXPLICIT use_kernel=True off-TPU still runs the Pallas body
         # (interpret mode — correctness tests); auto never does
@@ -266,7 +276,8 @@ def apply_matrix_flat(buf: jax.Array, matrix: jax.Array,
 def mix_flat(buf: jax.Array, eta: jax.Array, gamma,
              self_weight: float = 1.0,
              use_kernel: bool | None = None,
-             wire: jax.Array | None = None) -> jax.Array:
+             wire: jax.Array | None = None,
+             wire_self: jax.Array | None = None) -> jax.Array:
     """Paper eq. (5) on the flat buffer, one fused operation:
 
         phi_k = sw * W_k + gamma * sum_i eta_ki (W_i - W_k)
@@ -280,27 +291,30 @@ def mix_flat(buf: jax.Array, eta: jax.Array, gamma,
     ``buf``): pass a bf16 cast to halve exchanged bytes, or a stale
     gossip snapshot for bounded-delay rounds. Only the difference terms
     see the wire precision — they vanish at consensus — while ``buf``
-    stays the f32 master copy.
+    stays the f32 master copy. ``wire_self`` (default ``wire``) is each
+    node's own payload in the self-cancellation term: under fault
+    injection the neighbor frames diverge from it.
     """
     eta32 = eta.astype(buf.dtype)
     g = jnp.asarray(gamma, buf.dtype)
     w = buf if wire is None else wire
-    if _use_kernel(use_kernel, buf.shape[1]):
+    if _use_kernel(use_kernel):
         # the whole delta form (matmul + row-sum rescale + master add)
         # fuses into ONE Pallas pass; the wire slab is read at its wire
         # dtype and upcast in VMEM, so a bf16 wire halves neighbor-read
         # bytes too. Off TPU this kernel runs only on an EXPLICIT
         # use_kernel=True (interpret-mode correctness tests).
         from repro.kernels import ops
-        out = ops.flat_mix(eta32, buf, w, g,
+        out = ops.flat_mix(eta32, buf, w, g, wire_self,
                            force_kernel=use_kernel is True)
         if self_weight == 1.0:
             return out
         return out + jnp.asarray(self_weight - 1.0, buf.dtype) * buf
     row = eta32.sum(axis=1)
     w32 = w.astype(buf.dtype)
+    ws32 = w32 if wire_self is None else wire_self.astype(buf.dtype)
     mixed = matmul_nodes(eta32, w32)
-    out = g * (mixed - row[:, None] * w32)
+    out = g * (mixed - row[:, None] * ws32)
     if self_weight == 1.0:
         return buf + out
     return jnp.asarray(self_weight, buf.dtype) * buf + out
@@ -330,29 +344,31 @@ def sparse_neighbor_sum(idx: jax.Array, val: jax.Array,
 
 def sparse_mix_flat(buf: jax.Array, idx: jax.Array, val: jax.Array,
                     gamma, use_kernel: bool | None = None,
-                    wire: jax.Array | None = None) -> jax.Array:
+                    wire: jax.Array | None = None,
+                    wire_self: jax.Array | None = None) -> jax.Array:
     """Paper eq. (5) on the flat buffer with top-D sparse weights:
 
         phi_k = W_k + gamma * (sum_d val_kd W_{idx_kd} - rowsum_k W_k)
 
     The sparse twin of :func:`mix_flat` — same delta form (cancellation
-    at the f32 noise floor), same ``wire`` convention (difference terms
-    at wire precision, ``buf`` the f32 master). All-zero rows reduce to
+    at the f32 noise floor), same ``wire``/``wire_self`` convention
+    (difference terms at wire precision, ``buf`` the f32 master). All-zero rows reduce to
     a pure self-update. Dispatches to the Pallas gather-mix kernel on
     TPU (or on an explicit ``use_kernel=True``, interpret mode); the
     XLA ``take`` + ``einsum`` path is the auto-selected path off-TPU.
     """
     g = jnp.asarray(gamma, buf.dtype)
     w = buf if wire is None else wire
-    if _use_kernel(use_kernel, buf.shape[1]):
+    if _use_kernel(use_kernel):
         from repro.kernels import ops
-        return ops.sparse_mix(idx, val, buf, w, g,
+        return ops.sparse_mix(idx, val, buf, w, g, wire_self,
                               force_kernel=use_kernel is True)
     val32 = val.astype(buf.dtype)
     w32 = w.astype(buf.dtype)
+    ws32 = w32 if wire_self is None else wire_self.astype(buf.dtype)
     row = val32.sum(axis=1)
     mixed = sparse_neighbor_sum(idx, val32, w32)
-    return buf + g * (mixed - row[:, None] * w32)
+    return buf + g * (mixed - row[:, None] * ws32)
 
 
 def cluster_mix_flat(buf: jax.Array, idx: jax.Array, val: jax.Array,
@@ -372,14 +388,14 @@ def cluster_mix_flat(buf: jax.Array, idx: jax.Array, val: jax.Array,
     convention of the dense transport: the neighbor term reads ``wire``
     (possibly a fault-overridden, codec'd payload), the self rescale
     reads ``wire_self`` (default ``wire``), and ``buf`` stays the f32
-    master. Dispatches to the Pallas ``kernels/cluster_mix`` kernel on
+    master. Dispatches to the Pallas ``cluster_mix`` kernel on
     TPU (or on explicit ``use_kernel=True``, interpret mode); off-TPU
     the auto path is the same D-pass gather-axpy as
     :func:`sparse_mix_flat`."""
     g = gamma_node.astype(buf.dtype)
     w = buf if wire is None else wire
     ws = w if wire_self is None else wire_self
-    if _use_kernel(use_kernel, buf.shape[1]):
+    if _use_kernel(use_kernel):
         from repro.kernels import ops
         return ops.cluster_mix(idx, val, buf, ws, w, g,
                                force_kernel=use_kernel is True)
@@ -396,7 +412,9 @@ def partial_mix_flat(buf: jax.Array, eta: jax.Array, gamma, prefix: int,
     """Eq. (5) on the first ``prefix`` buffer columns only (C-DFA(M):
     federated optimization on Q <= N layers). ``eta`` may be dense
     (K, K) or a ``topology.SparseEta`` (duck-typed on ``.idx`` to keep
-    this module free of repro imports)."""
+    this module free of repro imports). An unaligned prefix takes the
+    XLA form (:func:`prefix_use_kernel`)."""
+    use_kernel = prefix_use_kernel(prefix, use_kernel)
     if hasattr(eta, "idx"):
         head = sparse_mix_flat(buf[:, :prefix], eta.idx, eta.val, gamma,
                                use_kernel=use_kernel)

@@ -191,6 +191,33 @@ def _cast_noops(codec: WireCodec, buf: jax.Array, simulate: bool) -> bool:
     return jax.default_backend() == "cpu" and not simulate
 
 
+def _wire_pair(codec: WireCodec, buf: jax.Array, sent, simulate: bool):
+    """``(wire, wire_self)`` for :func:`_mix`. Fault-free (``sent`` is
+    None): the fused wire and no separate self payload. Fault-injected:
+    per-node wire payloads (``sent``) diverge from the master buffer, so
+    the neighbor terms read the codec'd payloads while the
+    self-cancellation term keeps each node's OWN clean buffer (a node
+    never receives itself). The codec applies per GATHERED row on the
+    sparse path: the gather reads the codec'd payload matrix."""
+    if sent is None:
+        return _fused_wire(codec, buf, simulate), None
+    if _cast_noops(codec, buf, simulate):
+        return sent, buf
+    return codec.roundtrip(sent), codec.roundtrip(buf)
+
+
+def _mix(buf, eta, gamma, use_kernel, wire, wire_self):
+    """Eq. 5 delta mix in the format of ``eta``: the dense
+    :func:`flatten.mix_flat` or the sparse top-D gather
+    :func:`flatten.sparse_mix_flat` (Pallas kernels on TPU)."""
+    if isinstance(eta, SparseEta):
+        return flatten.sparse_mix_flat(buf, eta.idx, eta.val, gamma,
+                                       use_kernel=use_kernel, wire=wire,
+                                       wire_self=wire_self)
+    return flatten.mix_flat(buf, eta, gamma, use_kernel=use_kernel,
+                            wire=wire, wire_self=wire_self)
+
+
 @dataclasses.dataclass(frozen=True)
 class DenseTransport(_FlatTransport):
     """Fused dense exchange: every node mixes every neighbor in one
@@ -204,41 +231,10 @@ class DenseTransport(_FlatTransport):
     simulate_wire: bool = False
 
     def exchange(self, buf, eta, gamma, state=(), rnd=None, sent=None):
-        sparse = isinstance(eta, SparseEta)
-        if sent is None:
-            wire = _fused_wire(self.codec, buf, simulate=self.simulate_wire)
-            if sparse:
-                out = flatten.sparse_mix_flat(buf, eta.idx, eta.val, gamma,
-                                              use_kernel=self.use_kernel,
-                                              wire=wire)
-            else:
-                out = flatten.mix_flat(buf, eta, gamma,
-                                       use_kernel=self.use_kernel,
-                                       wire=wire)
-            return out, state
-        # fault-injected exchange: per-node wire payloads (``sent``)
-        # diverge from the master buffer, so the neighbor terms read the
-        # codec'd payloads while the self-cancellation term keeps each
-        # node's OWN clean buffer (a node never receives itself). The
-        # codec applies per GATHERED row on the sparse path: the gather
-        # reads the codec'd payload matrix, so each of a node's D
-        # neighbor reads sees the decoded wire representation.
-        codec = self.codec
-        if _cast_noops(codec, buf, self.simulate_wire):
-            w_nb, w_self = sent, buf
-        else:
-            w_nb = codec.roundtrip(sent)
-            w_self = codec.roundtrip(buf)
-        g = jnp.asarray(gamma, buf.dtype)
-        if sparse:
-            row = eta.val.astype(buf.dtype).sum(axis=1)
-            mixed = flatten.sparse_neighbor_sum(eta.idx, eta.val, w_nb)
-        else:
-            eta32 = eta.astype(buf.dtype)
-            row = eta32.sum(axis=1)
-            mixed = flatten.matmul_nodes(eta32, w_nb)
-        out = buf + g * (mixed - row[:, None] * w_self)
-        return out, state
+        wire, wire_self = _wire_pair(self.codec, buf, sent,
+                                     self.simulate_wire)
+        return _mix(buf, eta, gamma, self.use_kernel, wire,
+                    wire_self), state
 
 
 @dataclasses.dataclass(frozen=True)
@@ -317,6 +313,7 @@ class GossipTransport(_FlatTransport):
     staleness: int = 0
     wire_dtype: str = "f32"
     simulate_wire: bool = False
+    use_kernel: bool | None = None      # None -> auto (TPU)
 
     @property
     def stateful(self) -> bool:
@@ -332,29 +329,11 @@ class GossipTransport(_FlatTransport):
 
     def exchange(self, buf, eta, gamma, state=(), rnd=None, sent=None):
         codec = self.codec
-        sparse = isinstance(eta, SparseEta)
         if self.staleness == 0:
-            if sent is None:
-                wire = _fused_wire(codec, buf, simulate=self.simulate_wire)
-                if sparse:
-                    return flatten.sparse_mix_flat(buf, eta.idx, eta.val,
-                                                   gamma, wire=wire), state
-                return flatten.mix_flat(buf, eta, gamma, wire=wire), state
-            if _cast_noops(codec, buf, self.simulate_wire):
-                w_nb, w_self = sent, buf
-            else:
-                w_nb = codec.roundtrip(sent)
-                w_self = codec.roundtrip(buf)
-            g = jnp.asarray(gamma, buf.dtype)
-            if sparse:
-                row = eta.val.astype(buf.dtype).sum(axis=1)
-                mixed = flatten.sparse_neighbor_sum(eta.idx, eta.val, w_nb)
-            else:
-                eta32 = eta.astype(buf.dtype)
-                row = eta32.sum(axis=1)
-                mixed = flatten.matmul_nodes(eta32, w_nb)
-            out = buf + g * (mixed - row[:, None] * w_self)
-            return out, state
+            wire, wire_self = _wire_pair(codec, buf, sent,
+                                         self.simulate_wire)
+            return _mix(buf, eta, gamma, self.use_kernel, wire,
+                        wire_self), state
         if rnd is None:
             raise ValueError("stale gossip needs the round index (rnd)")
         # slot r % s was last written at round r - s: exactly s rounds old
@@ -370,23 +349,14 @@ class GossipTransport(_FlatTransport):
             lambda a, fresh: jax.lax.dynamic_update_index_in_dim(
                 a, fresh[None], slot, 0),
             state, codec.encode(buf if sent is None else sent))
-        g = jnp.asarray(gamma, buf.dtype)
         # neighbor terms from the stale snapshot, self term from the
         # CURRENT buffer at wire precision (so staleness->0 recovers the
         # synchronous delta form term by term); the sparse path gathers
         # its D stale rows from the decoded snapshot — stale-snapshot
         # bookkeeping is format-independent
         stale = codec.decode(stale_enc, buf.dtype)
-        if sparse:
-            row = eta.val.astype(buf.dtype).sum(axis=1)
-            mixed = flatten.sparse_neighbor_sum(eta.idx, eta.val, stale)
-        else:
-            eta32 = eta.astype(buf.dtype)
-            row = eta32.sum(axis=1)
-            mixed = flatten.matmul_nodes(eta32, stale)
-        w_self = codec.roundtrip(buf)
-        out = buf + g * (mixed - row[:, None] * w_self)
-        return out, new_state
+        return _mix(buf, eta, gamma, self.use_kernel, stale,
+                    codec.roundtrip(buf)), new_state
 
 
 # --------------------------------------------------------------------------

@@ -624,6 +624,16 @@ class Session:
             cb.on_run_end(self, result)
         return result
 
+    def lower(self, rounds: int, rng: Optional[jax.Array] = None):
+        """The scan one callback-free ``run(rounds)`` dispatches, lowered
+        and not run: ``.compile()`` gives the device program (its
+        ``as_text()`` shows which kernels a round runs). The live state
+        is not donated."""
+        trainer = self.experiment.trainer(self.data)
+        return trainer.lower_rounds(self._state, self.data, rounds,
+                                    rng=self._rng if rng is None else rng,
+                                    n_items=self._n_items)
+
     # -- checkpoint / resume -------------------------------------------------
     def save(self, path: str) -> str:
         """Checkpoint the FULL resumable state (params, optimizer, CND
@@ -720,6 +730,30 @@ class BatchedSession:
         for cb in callbacks:
             cb.on_run_start(self, rounds)
         t0 = time.time()
+        self._states, metrics = trainer.run_rounds_batch(
+            self._states, self.data, rounds,
+            **self._batch_inputs(trainer, rounds))
+        jax.block_until_ready(self._states.params)
+        result = BatchResult(state=self._states, metrics=metrics,
+                             rounds=rounds,
+                             wall_time_s=time.time() - t0,
+                             variants=self.variants)
+        for cb in callbacks:
+            cb.on_run_end(self, result)
+        return result
+
+    def lower(self, rounds: int):
+        """The vmapped scan one callback-free ``run_batch(rounds)``
+        dispatches, lowered and not run (see :meth:`Session.lower`)."""
+        trainer = self.experiment.trainer(self.data)
+        return trainer.lower_rounds_batch(
+            self._states, self.data, rounds,
+            **self._batch_inputs(trainer, rounds))
+
+    def _batch_inputs(self, trainer: Trainer, rounds: int) -> dict:
+        """The per-variant keyword inputs of the batched scan: sampling
+        keys, item counts, and — when mobility or gamma is swept — the
+        (V,)-stacked mixing and step-size stacks."""
         start = self.rounds_completed
         etas = gammas = None
         mob_swept = self._axes.mobility is not None
@@ -749,18 +783,8 @@ class BatchedSession:
         if self._axes.lr is not None:
             lrs = jnp.asarray([v["lr"] for v in self.variants],
                               jnp.float32)
-        self._states, metrics = trainer.run_rounds_batch(
-            self._states, self.data, rounds, rngs=self._rngs,
-            n_items=self._n_items, eta_stacks=etas,
-            gamma_stacks=gammas, lrs=lrs)
-        jax.block_until_ready(self._states.params)
-        result = BatchResult(state=self._states, metrics=metrics,
-                             rounds=rounds,
-                             wall_time_s=time.time() - t0,
-                             variants=self.variants)
-        for cb in callbacks:
-            cb.on_run_end(self, result)
-        return result
+        return dict(rngs=self._rngs, n_items=self._n_items,
+                    eta_stacks=etas, gamma_stacks=gammas, lrs=lrs)
 
     # -- checkpoint / resume: deliberately unsupported ----------------------
     def save(self, path: str) -> str:

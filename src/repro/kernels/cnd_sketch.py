@@ -10,8 +10,12 @@ TPU-native rewrite is:
     items' one-hot contributions (compare + shift + reduce), tiled so the
     (block_items x words) compare matrix stays in VMEM.
 
-The bitmap scratch (num_hashes x m/32 words) persists in VMEM across the
-sequential item-block grid dimension and is written out once at the end.
+Each grid step folds its item block into a ``(num_hashes, 8, m/32)``
+partial-OR accumulator (the output block, resident in VMEM across the
+sequential item-block grid dimension): the block's rows are OR-ed one
+8-row tile at a time, and the last 8 -> 1 fold runs in XLA after the
+kernel. Everything in the body is tile-aligned 2-D VPU work — Pallas
+TPU has no lowering for a general ``lax.reduce`` with ``bitwise_or``.
 """
 from __future__ import annotations
 
@@ -20,44 +24,37 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sketch import _mix32
 
-
-def _or_reduce_items(vals: jax.Array) -> jax.Array:
-    """(n_items, W) uint32 -> (W,) uint32 bitwise-OR over items."""
-    return jax.lax.reduce(vals, jnp.uint32(0), jax.lax.bitwise_or, (0,))
+SUBLANES = 8
 
 
-def _kernel(items_ref, out_ref, bm_scr, *, num_hashes: int, m: int):
-    step = pl.program_id(0)
-    nsteps = pl.num_programs(0)
-
-    @pl.when(step == 0)
+def _kernel(items_ref, out_ref, *, num_hashes: int, m: int):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        bm_scr[...] = jnp.zeros_like(bm_scr)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
     items = items_ref[...].astype(jnp.uint32)            # (blk, f)
     blk, f = items.shape
     words = m // 32
+    wid = jax.lax.broadcasted_iota(jnp.int32, (blk, words), 1)
     for s in range(num_hashes):
-        # rolling fold over the item's feature tokens (Alg. 1 hash(item))
-        h = jnp.zeros((blk,), jnp.uint32)
+        # rolling fold over the item's feature tokens (Alg. 1 hash(item)),
+        # kept (blk, 1) so every op stays a 2-D vector op
+        h = jnp.zeros((blk, 1), jnp.uint32)
         for j in range(f):
-            h = _mix32(h * jnp.uint32(31) + items[:, j], s + j)
-        idx = _mix32(h, 101 + s) % jnp.uint32(m)          # (blk,)
+            h = _mix32(h * jnp.uint32(31) + items[:, j:j + 1], s + j)
+        h = _mix32(h, 101 + s)
+        idx = h & jnp.uint32(m - 1) if m & (m - 1) == 0 else h % m
         word = (idx >> 5).astype(jnp.int32)
-        bit = (idx & jnp.uint32(31))
-        wid = jax.lax.broadcasted_iota(jnp.int32, (blk, words), 1)
-        vals = jnp.where(word[:, None] == wid,
-                         (jnp.uint32(1) << bit)[:, None],
+        bit = idx & jnp.uint32(31)
+        vals = jnp.where(word == wid, jnp.uint32(1) << bit,
                          jnp.uint32(0))                   # (blk, W)
-        bm_scr[s, :] = bm_scr[s, :] | _or_reduce_items(vals)
-
-    @pl.when(step == nsteps - 1)
-    def _finish():
-        out_ref[...] = bm_scr[...]
+        part = vals[:SUBLANES]
+        for t in range(SUBLANES, blk, SUBLANES):
+            part = part | vals[t:t + SUBLANES]
+        out_ref[s] = out_ref[s] | part
 
 
 def cnd_bitmaps(items: jax.Array, num_hashes: int = 3, m: int = 8192,
@@ -65,24 +62,29 @@ def cnd_bitmaps(items: jax.Array, num_hashes: int = 3, m: int = 8192,
                 interpret: bool = False) -> jax.Array:
     """items: (n, f) int32 feature tokens -> (num_hashes, m//32) uint32.
 
-    n is padded to a multiple of block_items by repeating row 0 (idempotent
-    for a bitmap: duplicates OR the same bit)."""
+    n is padded to a multiple of the item block (itself a multiple of 8)
+    by repeating row 0 (idempotent for a bitmap: duplicates OR the same
+    bit)."""
+    assert m % 32 == 0 and block_items % SUBLANES == 0, (m, block_items)
     n, f = items.shape
-    blk = min(block_items, max(8, n))
+    blk = min(block_items, -(-n // SUBLANES) * SUBLANES)
     pad = (-n) % blk
     if pad:
         items = jnp.concatenate(
             [items, jnp.broadcast_to(items[:1], (pad, f))], axis=0)
-    grid = (items.shape[0] // blk,)
-    return pl.pallas_call(
+    partial_or = pl.pallas_call(
         functools.partial(_kernel, num_hashes=num_hashes, m=m),
-        grid=grid,
+        grid=(items.shape[0] // blk,),
         in_specs=[pl.BlockSpec((blk, f), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((num_hashes, m // 32), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_hashes, m // 32), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((num_hashes, m // 32), jnp.uint32)],
+        out_specs=pl.BlockSpec((num_hashes, SUBLANES, m // 32),
+                               lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((num_hashes, SUBLANES, m // 32),
+                                       jnp.uint32),
         interpret=interpret,
+        name="cnd_bitmaps",
     )(items)
+    return jax.lax.reduce(partial_or, jnp.uint32(0), jax.lax.bitwise_or,
+                          (1,))
 
 
 # --- popcount kernel (cardinality readout) ---------------------------------
@@ -105,5 +107,6 @@ def cnd_popcount(bitmaps: jax.Array, *, interpret: bool = False) -> jax.Array:
         out_specs=pl.BlockSpec((h, 1), lambda: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((h, 1), jnp.int32),
         interpret=interpret,
+        name="cnd_popcount",
     )(bitmaps)
     return out[:, 0]

@@ -38,55 +38,69 @@ def _flat_kernel(a_ref, buf_ref, out_ref):
         a, buf, preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
-def _flat_mix_kernel(scal_ref, eta_ref, master_ref, wire_ref, out_ref):
+def _flat_mix_kernel(scal_ref, eta_ref, master_ref, wire_ref, *rest):
     # scal_ref: (1, 1) gamma. eta_ref: (K, K) neighbor weights.
     # master_ref: (K, block_cols) f32 master slab; wire_ref: the slab as it
-    # traveled the wire (f32 or bf16). Delta form in one VMEM pass:
-    #     out = master + gamma * (eta @ wire - rowsum(eta) * wire)
+    # traveled the wire (f32 or bf16); rest: [wself_ref,] out_ref — a
+    # separate self payload only when it differs from the wire (fault
+    # injection). Delta form in one VMEM pass:
+    #     out = master + gamma * (eta @ wire - rowsum(eta) * wself)
     # so a bf16 wire perturbs only the *difference* terms (which vanish at
     # consensus), never the f32 master copy.
+    *wself_ref, out_ref = rest
     eta = eta_ref[...].astype(jnp.float32)
     w = wire_ref[...].astype(jnp.float32)
+    ws = wself_ref[0][...].astype(jnp.float32) if wself_ref else w
     m = master_ref[...].astype(jnp.float32)
     g = scal_ref[0, 0]
     row = eta.sum(axis=1)[:, None]
-    mixed = jnp.dot(eta, w, preferred_element_type=jnp.float32)
-    out_ref[...] = (m + g * (mixed - row * w)).astype(out_ref.dtype)
+    # full f32 contraction: a one-pass bf16 MXU dot would leave a ~1e-3
+    # relative floor under ``mixed - row * ws``, which must cancel at
+    # consensus (the XLA form at paper-scale K is an exact f32
+    # broadcast-sum, see flatten.matmul_nodes)
+    mixed = jnp.dot(eta, w, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
+    out_ref[...] = (m + g * (mixed - row * ws)).astype(out_ref.dtype)
 
 
 def flat_mix(eta: jax.Array, master: jax.Array, wire: jax.Array,
-             gamma: jax.Array, *, block_cols: int = 512,
-             interpret: bool = False) -> jax.Array:
+             gamma: jax.Array, wire_self: jax.Array | None = None, *,
+             block_cols: int = 512, interpret: bool = False) -> jax.Array:
     """Fused paper-eq.5 delta mix over the flat (K, P) buffer:
 
-        OUT = MASTER + gamma * (ETA @ WIRE - rowsum(ETA) * WIRE)
+        OUT = MASTER + gamma * (ETA @ WIRE - rowsum(ETA) * WIRE_SELF)
 
     One kernel launch streams the master slab and the wire slab through
     VMEM once — the matmul, row-sum rescale, and master add that were
     previously separate XLA ops all fuse here. ``wire`` is the exchanged
     representation of the buffer (``master`` itself, a bf16 cast of it,
     or a stale gossip snapshot); a bf16 wire halves the neighbor-read
-    bytes while the accumulation stays f32.
+    bytes while the accumulation stays f32. ``wire_self`` (default
+    ``wire``) is each node's own payload for the self-cancellation term:
+    under fault injection the neighbor frames diverge from it.
     """
     k, p = master.shape
     assert eta.shape == (k, k), (eta.shape, k)
     assert wire.shape == (k, p), (wire.shape, master.shape)
     assert p % block_cols == 0, (p, block_cols)
     scal = jnp.asarray(gamma, jnp.float32).reshape(1, 1)
-    grid = (p // block_cols,)
+    slab = pl.BlockSpec((k, block_cols), lambda c: (0, c))
+    operands = [scal, eta, master, wire]
+    if wire_self is not None:
+        assert wire_self.shape == (k, p), (wire_self.shape, master.shape)
+        operands.append(wire_self)
     return pl.pallas_call(
         _flat_mix_kernel,
-        grid=grid,
+        grid=(p // block_cols,),
         in_specs=[
             pl.BlockSpec((1, 1), lambda c: (0, 0)),           # gamma
             pl.BlockSpec((k, k), lambda c: (0, 0)),           # eta
-            pl.BlockSpec((k, block_cols), lambda c: (0, c)),  # master slab
-            pl.BlockSpec((k, block_cols), lambda c: (0, c)),  # wire slab
-        ],
-        out_specs=pl.BlockSpec((k, block_cols), lambda c: (0, c)),
+        ] + [slab] * (len(operands) - 2),   # master, wire[, wire_self]
+        out_specs=slab,
         out_shape=jax.ShapeDtypeStruct((k, p), master.dtype),
         interpret=interpret,
-    )(scal, eta, master, wire)
+        name="flat_mix",
+    )(*operands)
 
 
 def flat_consensus(matrix: jax.Array, buf: jax.Array, *,

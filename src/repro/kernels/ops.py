@@ -12,6 +12,7 @@ Higher layers call these, never pallas_call directly.
 """
 from __future__ import annotations
 
+import re
 from functools import partial
 
 import jax
@@ -22,7 +23,6 @@ from repro.kernels import cnd_sketch as _cs
 from repro.kernels import flash_attention as _fa
 from repro.kernels import robust_agg as _ra
 from repro.kernels import rwkv6_scan as _rs
-from repro.kernels import cluster_mix as _clm
 from repro.kernels import sparse_mix as _sm
 
 
@@ -35,6 +35,20 @@ def use_pallas() -> bool:
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+# a Pallas TPU kernel in compiled HLO text: its pallas_call ``name``,
+# numbered by XLA, on a custom call to the TPU kernel target
+_KERNEL_CALL = re.compile(
+    r"%([A-Za-z_][A-Za-z_0-9]*?)(?:\.\d+)* = [^\n]*"
+    r'custom_call_target="tpu_custom_call"')
+
+
+def pallas_kernels(hlo_text: str) -> set:
+    """Names of the Pallas kernels a program compiled for the TPU calls
+    (``jax.jit(f).lower(...).compile().as_text()``): what shows that a
+    kernel, and not an XLA fallback, runs."""
+    return set(_KERNEL_CALL.findall(hlo_text))
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k"))
@@ -96,38 +110,41 @@ def flat_consensus(matrix, buf, force_kernel: bool = False):
 
 
 @partial(jax.jit, static_argnames=("force_kernel",))
-def flat_mix(eta, master, wire, gamma, force_kernel: bool = False):
+def flat_mix(eta, master, wire, gamma, wire_self=None,
+             force_kernel: bool = False):
     """Fused eq.5 delta mix on the flat buffer (one kernel launch):
-    OUT = MASTER + gamma * (ETA @ WIRE - rowsum(ETA) * WIRE). ``wire`` is
-    the exchanged representation (master, a bf16 cast, or a stale gossip
-    snapshot); accumulation is always f32. Off TPU this is the
-    equivalent XLA delta form, not the interpreted kernel."""
+    OUT = MASTER + gamma * (ETA @ WIRE - rowsum(ETA) * WIRE_SELF).
+    ``wire`` is the exchanged representation (master, a bf16 cast, or a
+    stale gossip snapshot); ``wire_self`` (default ``wire``) the self
+    payload; accumulation is always f32. Off TPU this is the equivalent
+    XLA delta form, not the interpreted kernel."""
     if use_pallas() or force_kernel:
         block_cols = 512 if master.shape[1] % 512 == 0 else 128
-        return _cm.flat_mix(eta, master, wire, gamma,
+        return _cm.flat_mix(eta, master, wire, gamma, wire_self,
                             block_cols=block_cols, interpret=_interpret())
     # one source of truth for the XLA delta form: flatten.mix_flat
     from repro.core import flatten
     return flatten.mix_flat(master, eta, gamma, use_kernel=False,
-                            wire=wire)
+                            wire=wire, wire_self=wire_self)
 
 
 @partial(jax.jit, static_argnames=("force_kernel",))
-def sparse_mix(idx, val, master, wire, gamma, force_kernel: bool = False):
+def sparse_mix(idx, val, master, wire, gamma, wire_self=None,
+               force_kernel: bool = False):
     """Top-D sparse eq.5 delta mix on the flat buffer (one gather-mix
     kernel launch): OUT = MASTER + gamma * (gather-sum(VAL, WIRE[IDX])
-    - rowsum(VAL) * WIRE). O(K*D*P) instead of the dense O(K^2*P). Off
-    TPU this is the XLA ``take`` + ``einsum`` delta form, not the
-    interpreted kernel."""
+    - rowsum(VAL) * WIRE_SELF), ``wire_self`` defaulting to ``wire``.
+    O(K*D*P) instead of the dense O(K^2*P). Off TPU this is the XLA
+    ``take`` + ``einsum`` delta form, not the interpreted kernel."""
+    wself = wire if wire_self is None else wire_self
     if use_pallas() or force_kernel:
-        block_cols = 512 if master.shape[1] % 512 == 0 else 128
-        return _sm.sparse_mix(idx, val, master, wire, gamma,
-                              block_cols=block_cols,
+        return _sm.sparse_mix(idx, val, master, wself, wire, gamma,
                               interpret=_interpret())
     # one source of truth for the XLA form: flatten.sparse_mix_flat
     from repro.core import flatten
     return flatten.sparse_mix_flat(master, idx, val, gamma,
-                                   use_kernel=False, wire=wire)
+                                   use_kernel=False, wire=wire,
+                                   wire_self=wself)
 
 
 @partial(jax.jit, static_argnames=("force_kernel",))
@@ -140,10 +157,8 @@ def cluster_mix(idx, val, master, wself, wire, gamma_node,
     at its own stability bound. Off TPU this is the XLA gather-axpy
     delta form, not the interpreted kernel."""
     if use_pallas() or force_kernel:
-        block_cols = 512 if master.shape[1] % 512 == 0 else 128
-        return _clm.cluster_mix(idx, val, master, wself, wire, gamma_node,
-                                block_cols=block_cols,
-                                interpret=_interpret())
+        return _sm.cluster_mix(idx, val, master, wself, wire, gamma_node,
+                               interpret=_interpret())
     # one source of truth for the XLA form: flatten.cluster_mix_flat
     from repro.core import flatten
     return flatten.cluster_mix_flat(master, idx, val, gamma_node,

@@ -65,13 +65,30 @@ def _candidates(mask, buf, sent, k: int):
 def _robust_kernel(w_ref, mask_ref, buf_ref, sent_ref, out_ref, *, k: int):
     # w_ref/mask_ref: (K, K) position weights / aggregation support;
     # buf_ref/sent_ref: (K, block_cols) slabs of the flat buffer and the
-    # wire payloads. One VMEM pass: build candidates, sort, weighted sum.
+    # wire payloads. Everything stays 2-D (Mosaic cannot relayout the
+    # 3-D broadcasts of ``_candidates``): slot i of every receiver's
+    # candidate list is one (K, block_cols) array — row k holds what
+    # receiver k sees from sender i — and the sort network runs over
+    # the Python list of K such arrays.
     buf = buf_ref[...].astype(jnp.float32)
     sent = sent_ref[...].astype(jnp.float32)
-    v = _sort_net(_candidates(mask_ref[...], buf, sent, k), k)
-    v = jnp.where(jnp.isfinite(v), v, 0.0)
+    mask = mask_ref[...]
     w = w_ref[...].astype(jnp.float32)
-    out_ref[...] = jnp.sum(w[:, :, None] * v, axis=1).astype(out_ref.dtype)
+    recv = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 0)
+    slots = []
+    for i in range(k):
+        own = jnp.where(recv == i, buf[i:i + 1, :], sent[i:i + 1, :])
+        slots.append(jnp.where(mask[:, i:i + 1] > 0, own, jnp.inf))
+    for step in range(k):                 # odd-even transposition sort
+        for j in range(step % 2, k - 1, 2):
+            lo = jnp.minimum(slots[j], slots[j + 1])
+            slots[j + 1] = jnp.maximum(slots[j], slots[j + 1])
+            slots[j] = lo
+    acc = jnp.zeros_like(buf)
+    for j in range(k):
+        acc += w[:, j:j + 1] * jnp.where(jnp.isfinite(slots[j]),
+                                         slots[j], 0.0)
+    out_ref[...] = acc.astype(out_ref.dtype)
 
 
 def robust_agg(weights: jax.Array, mask: jax.Array, buf: jax.Array,
@@ -99,7 +116,8 @@ def robust_agg(weights: jax.Array, mask: jax.Array, buf: jax.Array,
         out_specs=pl.BlockSpec((k, block_cols), lambda c: (0, c)),
         out_shape=jax.ShapeDtypeStruct((k, p), buf.dtype),
         interpret=interpret,
-    )(weights, mask, buf, sent)
+        name="robust_agg",
+    )(weights, mask.astype(jnp.float32), buf, sent)
 
 
 def robust_agg_xla(weights: jax.Array, mask: jax.Array, buf: jax.Array,

@@ -1,4 +1,2 @@
 # Launch layer: meshes, sharding rules, step builders, dry-run, drivers.
-# NOTE: repro.launch.dryrun sets XLA_FLAGS at import — import it only in
-# dedicated processes, never from tests or benchmarks.
 from repro.launch import mesh, roofline, sharding  # noqa: F401

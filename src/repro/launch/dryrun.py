@@ -12,13 +12,9 @@ Usage:
 """
 from __future__ import annotations
 
-# The placeholder-device flag must be set before jax initializes devices —
-# i.e. before ANY jax import. These are the first executable lines.
-import os  # noqa: E402
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -32,6 +28,10 @@ from repro.launch import mesh as meshlib
 from repro.launch import roofline, sharding, steps
 
 # --- per-arch dry-run policy -------------------------------------------------
+
+# the chip the production meshes model: one 16x16 TPU v5e pod per pod
+TARGET_DEVICE_KIND = "TPU v5 lite"
+PLACEHOLDER_DEVICES_FLAG = "--xla_force_host_platform_device_count=512"
 
 # federated nodes (paper: 4 base stations). dbrx's optimizer state needs
 # dp=8 FSDP shards per node to fit HBM -> 2 nodes on a single pod.
@@ -137,8 +137,6 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     compile_s = time.time() - t0
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # older jax: one dict per device
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     colls = roofline.parse_collectives(hlo)
     n_dev = mesh_used.devices.size
@@ -149,6 +147,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         wire_bytes=colls.wire_bytes,
         collectives=colls,
         model_flops=mf,
+        device_kind=TARGET_DEVICE_KIND,
     )
     consensus_bytes = 0.0
     if fed_layout is not None:
@@ -200,6 +199,11 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main() -> None:
+    # the placeholder devices must exist before jax initializes its
+    # backends, which happens at the first device query below
+    os.environ["XLA_FLAGS"] = " ".join(
+        f for f in (os.environ.get("XLA_FLAGS"), PLACEHOLDER_DEVICES_FLAG)
+        if f)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default=None)
     ap.add_argument("--shape", choices=sorted(INPUT_SHAPES), default=None)

@@ -7,8 +7,9 @@
 cost_analysis() is post-SPMD, i.e. per-device; collective bytes are not in
 cost_analysis, so we parse the compiled HLO text and sum the result-shape
 bytes of every collective op, weighted by a wire factor (ring all-reduce
-moves ~2x the buffer; the others ~1x). Hardware: TPU v5e —
-197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+moves ~2x the buffer; the others ~1x). The per-chip peaks come from
+:data:`PEAKS`, keyed by the device's ``device_kind``; a kind without
+published peaks is an error, never a default.
 
 The CONSENSUS share of the collective term is transport-aware: the
 compiled fed train step always lowers the dense f32 ring roll, but the
@@ -25,9 +26,33 @@ import re
 from dataclasses import dataclass, field
 from dataclasses import replace as dataclass_replace
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link (effective, one link assumed)
+
+@dataclass(frozen=True)
+class Peaks:
+    flops: float                 # bf16 FLOP/s per chip
+    hbm_bw: float                # HBM bytes/s per chip
+    ici_bw: float                # interconnect bytes/s per link
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" (TPU v5e) — Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect
+# over 4 links (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The published peaks of one chip of ``device_kind``; raises for a
+    kind that is not in :data:`PEAKS`."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {', '.join(sorted(PEAKS))})") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2,
@@ -109,6 +134,7 @@ class Roofline:
     wire_bytes: float            # per device
     collectives: CollectiveStats
     model_flops: float           # analytic useful flops per device
+    device_kind: str             # key into PEAKS
 
     def with_consensus(self, transport, layout, adj,
                        devices_per_node: int) -> "Roofline":
@@ -129,15 +155,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / peaks(self.device_kind).flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / peaks(self.device_kind).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.wire_bytes / ICI_BW
+        return self.wire_bytes / peaks(self.device_kind).ici_bw
 
     @property
     def bottleneck(self) -> str:

@@ -101,7 +101,12 @@ def _parse_sweep(spec: str) -> dict:
     return axes
 
 
-def main() -> None:
+def main(argv=None):
+    """Parse ``argv`` (default ``sys.argv[1:]``) and run; callable
+    in-process, e.g. ``main(["--quick", "--rounds", "2"])``. Returns the
+    ``RunResult`` of a ``--driver scan`` run (None otherwise)."""
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     registry.ensure_plugins()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="qwen3-1.7b")
@@ -232,7 +237,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="tiny model + corpus for CI smoke runs")
     ap.add_argument("--checkpoint", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.sweep is not None:
         if args.driver != "scan":
@@ -344,7 +349,7 @@ def main() -> None:
 
     if args.sweep is not None:
         _run_sweep(args, run_cfg, data, batcher_items.node_items())
-        return
+        return None
 
     # the Experiment derives the token-LM loss/init from RunConfig.model
     session = Experiment(run_cfg).compile(data, batcher_items.node_items())
@@ -430,6 +435,7 @@ def main() -> None:
     if args.checkpoint:
         save(args.checkpoint, state.params, step=args.rounds)
         print("saved params to", args.checkpoint)
+    return result if args.driver == "scan" else None
 
 
 def _run_sweep(args, run_cfg, data, node_items) -> None:

@@ -2,6 +2,8 @@
 seed per-leaf oracle (kernels.ref), bf16 wire drift bounds, bounded-delay
 gossip semantics, single-node pack round-trips, and the end-to-end
 round-trip of every backend through Trainer.run_rounds."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -389,11 +391,30 @@ def test_roofline_collective_term_reads_transport_wire_bytes():
         count_by_op={"collective-permute": 2, "all-reduce": 1})
     rl = roofline.Roofline(flops=1.0, hbm_bytes=1.0,
                            wire_bytes=stats.wire_bytes, collectives=stats,
-                           model_flops=1.0)
+                           model_flops=1.0, device_kind="TPU v5 lite")
     rl2 = rl.with_consensus(transport.RingShardTransport(wire_dtype="bf16"),
                             layout, ring, devices_per_node=64)
     # non-consensus collectives (the 2x-weighted all-reduce) untouched
     assert rl2.wire_bytes == pytest.approx(2000.0 - 1000.0 + b16 / 64)
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    """The roofline terms divide by the peaks of the device kind the
+    program was compiled for; a kind without published peaks raises
+    instead of silently borrowing another chip's."""
+    from repro.launch import roofline
+    v5e = roofline.peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    stats = roofline.CollectiveStats(bytes_by_op={}, count_by_op={})
+    rl = roofline.Roofline(flops=197e12, hbm_bytes=819e9 / 2, wire_bytes=0.0,
+                           collectives=stats, model_flops=1.0,
+                           device_kind="TPU v5 lite")
+    assert rl.t_compute == pytest.approx(1.0)
+    assert rl.t_memory == pytest.approx(0.5)
+    assert rl.bottleneck == "compute"
+    unknown = dataclasses.replace(rl, device_kind="cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        unknown.t_compute
 
 
 def test_fed_ring_perms_matches_axis_derived():
