@@ -74,6 +74,7 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro import registry
 from repro.configs.base import FedConfig, HierarchyConfig, TrainConfig
@@ -246,7 +247,9 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
 
     @jax.jit
     def _ingest_gather(data, src_node, src_slot):
-        return jax.tree.map(lambda a: a[src_node, src_slot], data)
+        # the device half of the ingest plan, named like its host span
+        with jax.named_scope("cdfl.ingest_plan"):
+            return jax.tree.map(lambda a: a[src_node, src_slot], data)
 
     if ingest_on and (ingest_cfg.reweight_mixing or ingest_cfg.drift_on):
         # both the redundancy reweight and the drift-detection column
@@ -342,16 +345,20 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         vector: the forward/backward reads pytree slice VIEWS of the
         buffer, the gradient pytree is flattened ONCE, and the fused
         flat-Adam pass updates vector and moments in place."""
-        p = flatten.unflatten_one(vec, layout)
-        loss, grads = jax.value_and_grad(loss_fn)(p, batch)
-        gvec = flatten.pack_node(grads, layout)
-        vec, ost = fopt.update(gvec, ost, vec, lr=lr)
+        with jax.named_scope("cdfl.grad"):
+            p = flatten.unflatten_one(vec, layout)
+            loss, grads = jax.value_and_grad(loss_fn)(p, batch)
+            gvec = flatten.pack_node(grads, layout)
+        with jax.named_scope("cdfl.adam"):
+            vec, ost = fopt.update(gvec, ost, vec, lr=lr)
         return vec, ost, loss
 
     def _leaf_local_step(p, o, batch, lr=None):
         """One leaf-space local Adam step (pytree params/moments)."""
-        loss, grads = jax.value_and_grad(loss_fn)(p, batch)
-        p, o = opt.update(grads, o, p, lr=lr)
+        with jax.named_scope("cdfl.grad"):
+            loss, grads = jax.value_and_grad(loss_fn)(p, batch)
+        with jax.named_scope("cdfl.adam"):
+            p, o = opt.update(grads, o, p, lr=lr)
         return p, o, loss
 
     # ONE loop scaffold serves both representations and both batch
@@ -653,6 +660,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         eta, gamma = _mixing(state)
         return round_body(state, batches, eta, gamma)
 
+    @partial(annotate_function, name="cdfl.mixing_stack")
     def mixing_stack(state: FedState, num_rounds: int, start: int = 0,
                      *, mobility="config",
                      gamma_cap: Optional[float] = None):
@@ -719,30 +727,37 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
     def _scan_rounds_impl(state: FedState, data, round_keys: jax.Array,
                           num_rounds: int, max_items: int, node_sizes,
                           etas, gammas, fault_xs, slot_hashes, lr=None):
+        # Each phase runs under a ``cdfl.<phase>`` named scope, which
+        # reaches the compiled program only as op metadata: the device
+        # trace can then charge every operation to its phase.
+        #
         # (R, K, S, B) minibatch indices for ALL rounds, sampled on
         # device from per-round keys folded on the ABSOLUTE round index
         # (run_rounds derives them) — segmenting a run cannot change
         # which batches any round sees.
-        shape = (fed.num_nodes, fed.local_steps, train.batch_size)
-        if ingest_on and ingest_cfg.correct_sampling:
-            # multiplicity-corrected sampling: pre-sample UNIFORMS with
-            # the same absolute-round keying and transform them inside
-            # the body through the CURRENT sketch's inverse-multiplicity
-            # CDF (the weights evolve with the stream, so the transform
-            # cannot be hoisted out of the scan)
-            idx = jax.vmap(
-                lambda k: jax.random.uniform(k, shape))(round_keys)
-        elif node_sizes is None:
-            idx = jax.vmap(
-                lambda k: jax.random.randint(k, shape, 0, max_items)
-            )(round_keys)
-        else:
-            # ragged per-node datasets (padded to a common N): uniform
-            # over each node's true item count
-            u = jax.vmap(lambda k: jax.random.uniform(k, shape))(round_keys)
-            idx = jnp.minimum(
-                (u * node_sizes[None, :, None, None]).astype(jnp.int32),
-                node_sizes.astype(jnp.int32)[None, :, None, None] - 1)
+        with jax.named_scope("cdfl.sample"):
+            shape = (fed.num_nodes, fed.local_steps, train.batch_size)
+            if ingest_on and ingest_cfg.correct_sampling:
+                # multiplicity-corrected sampling: pre-sample UNIFORMS
+                # with the same absolute-round keying and transform them
+                # inside the body through the CURRENT sketch's
+                # inverse-multiplicity CDF (the weights evolve with the
+                # stream, so the transform cannot be hoisted out of the
+                # scan)
+                idx = jax.vmap(
+                    lambda k: jax.random.uniform(k, shape))(round_keys)
+            elif node_sizes is None:
+                idx = jax.vmap(
+                    lambda k: jax.random.randint(k, shape, 0, max_items)
+                )(round_keys)
+            else:
+                # ragged per-node datasets (padded to a common N):
+                # uniform over each node's true item count
+                u = jax.vmap(
+                    lambda k: jax.random.uniform(k, shape))(round_keys)
+                idx = jnp.minimum(
+                    (u * node_sizes[None, :, None, None]).astype(jnp.int32),
+                    node_sizes.astype(jnp.int32)[None, :, None, None] - 1)
         # The mixing weights ride the scan as PER-ROUND inputs: slice r
         # of the (R, K, K) eta stack (and (R,) gamma) is consumed by
         # round r's exchange. A constant stack (static topology) is
@@ -759,10 +774,11 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         # backward reads pytree slice views; the CPU lowering instead
         # carries the moments in leaf space (see build_trainer) —
         # converted here ONCE at the scan boundary, never per round.
-        layout = flatten.make_layout(state.params)
-        buf0, _ = flatten.flatten(state.params, layout)
-        opt0 = (state.opt if flat_local
-                else _leaf_opt_state(state.opt, layout))
+        with jax.named_scope("cdfl.pack"):
+            layout = flatten.make_layout(state.params)
+            buf0, _ = flatten.flatten(state.params, layout)
+            opt0 = (state.opt if flat_local
+                    else _leaf_opt_state(state.opt, layout))
         # ``fault_xs`` is () on the fault-free path (the scan carry and
         # body then trace to exactly the pre-fault graph) or the
         # per-round (health, byz, corrupt, straggle) stacks — the
@@ -784,110 +800,125 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
             est = ()
             novelty = ()
             if ingest_on:
-                mult = None
-                if ingest_cfg.correct_sampling:
-                    # weights from the ENTRY sketch (round 0: empty
-                    # counters -> uniform), then fold this round's
-                    # samples in — no same-round feedback loop
-                    mult = ingest_sketches.multiplicity(
-                        ist.cm, slot_hashes.buckets)
-                    w = ingest_weighting.sampling_weights(
-                        mult, node_sizes, max_items)
-                    idx_r = ingest_weighting.weighted_indices(idx_r, w)
-                if ingest_cfg.drift_on:
-                    # drift signal: fraction of the FINAL sampled slots
-                    # the ENTRY (decayed) sketch has never seen. Gated
-                    # on the sketch having streamed anything, so the
-                    # empty round-0 counters don't read as a regime
-                    # change on every node at once.
-                    if mult is None:
+                with jax.named_scope("cdfl.ingest"):
+                    mult = None
+                    if ingest_cfg.correct_sampling:
+                        # weights from the ENTRY sketch (round 0: empty
+                        # counters -> uniform), then fold this round's
+                        # samples in — no same-round feedback loop
                         mult = ingest_sketches.multiplicity(
                             ist.cm, slot_hashes.buckets)
-                    novelty = jnp.where(
-                        ist.seen > 0,
-                        ingest_weighting.drift_novelty(mult, idx_r),
-                        0.0)
-                ist = ingest_sketches.update(ist, slot_hashes, idx_r,
-                                             decay=ingest_cfg.decay)
-                est = ingest_sketches.hll_cardinality(ist.hll)
-                if ingest_cfg.reweight_mixing:
-                    eta_r = ingest_weighting.reweight_eta(
-                        eta_r, est, ingest_cfg.spread_gate)
-                if ingest_cfg.drift_on:
-                    # drifted nodes' columns are discounted/zeroed with
-                    # mass-preserving renorm; untriggered rounds pass
-                    # eta through bit-exactly
-                    disc = (0.0 if ingest_cfg.drift_mode == "reset"
-                            else ingest_cfg.drift_discount)
-                    scale = jnp.where(
-                        novelty > ingest_cfg.drift_threshold, disc, 1.0)
-                    eta_r = ingest_weighting.scale_eta_columns(
-                        eta_r, scale)
+                        w = ingest_weighting.sampling_weights(
+                            mult, node_sizes, max_items)
+                        idx_r = ingest_weighting.weighted_indices(idx_r, w)
+                    if ingest_cfg.drift_on:
+                        # drift signal: fraction of the FINAL sampled
+                        # slots the ENTRY (decayed) sketch has never
+                        # seen. Gated on the sketch having streamed
+                        # anything, so the empty round-0 counters don't
+                        # read as a regime change on every node at once.
+                        if mult is None:
+                            mult = ingest_sketches.multiplicity(
+                                ist.cm, slot_hashes.buckets)
+                        novelty = jnp.where(
+                            ist.seen > 0,
+                            ingest_weighting.drift_novelty(mult, idx_r),
+                            0.0)
+                    ist = ingest_sketches.update(ist, slot_hashes, idx_r,
+                                                 decay=ingest_cfg.decay)
+                    est = ingest_sketches.hll_cardinality(ist.hll)
+                    if ingest_cfg.reweight_mixing:
+                        eta_r = ingest_weighting.reweight_eta(
+                            eta_r, est, ingest_cfg.spread_gate)
+                    if ingest_cfg.drift_on:
+                        # drifted nodes' columns are discounted/zeroed
+                        # with mass-preserving renorm; untriggered rounds
+                        # pass eta through bit-exactly
+                        disc = (0.0 if ingest_cfg.drift_mode == "reset"
+                                else ingest_cfg.drift_discount)
+                        scale = jnp.where(
+                            novelty > ingest_cfg.drift_threshold, disc,
+                            1.0)
+                        eta_r = ingest_weighting.scale_eta_columns(
+                            eta_r, scale)
             sent = None
             if use_faults:
                 health_r, byz_r, corrupt_r, straggle_r = f_r
-                # what each node puts on the wire this round: its fresh
-                # buffer, a straggler's stale replay, an attacker's
-                # flipped/scaled version, a corrupted frame — in that
-                # order (an attacker corrupts what it would have sent)
-                sent = buf
-                if has_straggle:
-                    sent = jnp.where(straggle_r[:, None] > 0, prev, sent)
-                if has_byz:
-                    sent = sent * byz_r[:, None]
-                if has_corrupt:
-                    sent = faults_lib.corrupt_rows(
-                        sent, corrupt_r, fed.faults.corrupt_mode)
-                # receive-side self-healing: drop non-finite / blown-up
-                # payloads (zero the sender's eta column, partition-safe
-                # renorm, scrub the rows) before anything mixes
-                sent, eta_r, quarantined = faults_lib.wire_guard(
-                    sent, buf, eta_r, fed.faults.guard_threshold)
+                with jax.named_scope("cdfl.wire"):
+                    # what each node puts on the wire this round: its
+                    # fresh buffer, a straggler's stale replay, an
+                    # attacker's flipped/scaled version, a corrupted
+                    # frame — in that order (an attacker corrupts what
+                    # it would have sent)
+                    sent = buf
+                    if has_straggle:
+                        sent = jnp.where(straggle_r[:, None] > 0, prev,
+                                         sent)
+                    if has_byz:
+                        sent = sent * byz_r[:, None]
+                    if has_corrupt:
+                        sent = faults_lib.corrupt_rows(
+                            sent, corrupt_r, fed.faults.corrupt_mode)
+                    # receive-side self-healing: drop non-finite /
+                    # blown-up payloads (zero the sender's eta column,
+                    # partition-safe renorm, scrub the rows) before
+                    # anything mixes
+                    sent, eta_r, quarantined = faults_lib.wire_guard(
+                        sent, buf, eta_r, fed.faults.guard_threshold)
             if fed.algorithm == "dpsgd":
                 # no once-per-round exchange: the gossip runs INSIDE the
-                # step loop (dpsgd is fault-incapable, so sent is None)
-                buf, opt_state, loss = dpsgd_updates_from_idx(
-                    buf, opt_state, layout, eta_r, gamma_r, data, idx_r,
-                    lr=lr)
-            elif flat_local:
-                mixed, tstate = mix_buf(buf, state.sizes, eta_r, gamma_r,
-                                        layout, tstate, rnd, sent=sent)
-                buf, opt_state, loss = flat_local_updates_from_idx(
-                    mixed, opt_state, layout, data, idx_r, lr=lr)
+                # step loop (dpsgd is fault-incapable, so sent is None),
+                # so under dpsgd the mix is part of ``cdfl.local``
+                with jax.named_scope("cdfl.local"):
+                    buf, opt_state, loss = dpsgd_updates_from_idx(
+                        buf, opt_state, layout, eta_r, gamma_r, data,
+                        idx_r, lr=lr)
             else:
-                mixed, tstate = mix_buf(buf, state.sizes, eta_r, gamma_r,
-                                        layout, tstate, rnd, sent=sent)
-                params, opt_state, loss = leaf_local_updates_from_idx(
-                    flatten.unflatten(mixed, layout), opt_state,
-                    data, idx_r, lr=lr)
-                buf = flatten.flatten(params, layout)[0]
-            metrics = _flat_metrics(buf, layout, loss, gamma_r)
-            if hier_fmt:
-                # intra-tier telemetry: the gamma metric already carries
-                # the inter-tier step, this one shows what the clusters
-                # actually ran at (the gamma-decoupling the format buys)
-                metrics["gamma_intra"] = eta_r.gamma_node.mean()
-                metrics["clusters"] = (
-                    jnp.zeros((fed.num_nodes,), jnp.float32)
-                    .at[eta_r.cluster].set(1.0).sum())
-            if ingest_on:
-                metrics["est_distinct"] = est
-                if ingest_cfg.drift_on:
-                    metrics["drift"] = novelty
+                with jax.named_scope("cdfl.mix"):
+                    mixed, tstate = mix_buf(buf, state.sizes, eta_r,
+                                            gamma_r, layout, tstate, rnd,
+                                            sent=sent)
+                with jax.named_scope("cdfl.local"):
+                    if flat_local:
+                        buf, opt_state, loss = flat_local_updates_from_idx(
+                            mixed, opt_state, layout, data, idx_r, lr=lr)
+                    else:
+                        params, opt_state, loss = (
+                            leaf_local_updates_from_idx(
+                                flatten.unflatten(mixed, layout),
+                                opt_state, data, idx_r, lr=lr))
+                        buf = flatten.flatten(params, layout)[0]
+            with jax.named_scope("cdfl.metrics"):
+                metrics = _flat_metrics(buf, layout, loss, gamma_r)
+                if hier_fmt:
+                    # intra-tier telemetry: the gamma metric already
+                    # carries the inter-tier step, this one shows what
+                    # the clusters actually ran at (the gamma-decoupling
+                    # the format buys)
+                    metrics["gamma_intra"] = eta_r.gamma_node.mean()
+                    metrics["clusters"] = (
+                        jnp.zeros((fed.num_nodes,), jnp.float32)
+                        .at[eta_r.cluster].set(1.0).sum())
+                if ingest_on:
+                    metrics["est_distinct"] = est
+                    if ingest_cfg.drift_on:
+                        metrics["drift"] = novelty
             if use_faults:
-                # post-round self-healing: crashed nodes freeze for the
-                # outage (their eta row/column was already zeroed at
-                # compile time, so the mix was a bit-exact self-update);
-                # nodes whose buffer went non-finite (local divergence
-                # on a poisoned mix) roll back to last-good values
-                finite = jnp.isfinite(buf).all(axis=1)
-                keep = (health_r > 0) & finite
-                buf = jnp.where(keep[:, None], buf, entry_buf)
-                opt_state = _freeze_rows(opt_state, entry_opt, keep)
-                metrics["health"] = health_r
-                metrics["quarantined"] = quarantined
-                metrics["frozen"] = ((health_r > 0) & ~finite).astype(
-                    jnp.float32)
+                with jax.named_scope("cdfl.heal"):
+                    # post-round self-healing: crashed nodes freeze for
+                    # the outage (their eta row/column was already
+                    # zeroed at compile time, so the mix was a bit-exact
+                    # self-update); nodes whose buffer went non-finite
+                    # (local divergence on a poisoned mix) roll back to
+                    # last-good values
+                    finite = jnp.isfinite(buf).all(axis=1)
+                    keep = (health_r > 0) & finite
+                    buf = jnp.where(keep[:, None], buf, entry_buf)
+                    opt_state = _freeze_rows(opt_state, entry_opt, keep)
+                    metrics["health"] = health_r
+                    metrics["quarantined"] = quarantined
+                    metrics["frozen"] = ((health_r > 0) & ~finite).astype(
+                        jnp.float32)
                 if has_straggle:
                     # next round's stale replay is THIS round's entry
                     # buffer (what the node broadcast this round)
@@ -897,11 +928,12 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         (buf, opt_state, rnd, tstate, prev, ist), metrics = jax.lax.scan(
             body, (buf0, opt0, state.round, state.tstate, prev0, ing0),
             (idx, etas, gammas, fault_xs))
-        if not flat_local:
-            opt_state = _flat_opt_state(opt_state, layout)
-        final = FedState(flatten.unflatten(buf, layout), opt_state,
-                         state.ratios, state.sizes, rnd, tstate, prev,
-                         ist)
+        with jax.named_scope("cdfl.pack"):
+            if not flat_local:
+                opt_state = _flat_opt_state(opt_state, layout)
+            final = FedState(flatten.unflatten(buf, layout), opt_state,
+                             state.ratios, state.sizes, rnd, tstate, prev,
+                             ist)
         return final, metrics
 
     # single-run scan: the exact pre-batching entry point (lr defaults
@@ -910,6 +942,58 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
     _scan_rounds = partial(jax.jit,
                            static_argnames=("num_rounds", "max_items"),
                            donate_argnums=(0,))(_scan_rounds_impl)
+
+    @partial(annotate_function, name="cdfl.ingest_plan")
+    def _ingest_inputs(data, max_items: int):
+        """``data`` as the redundancy scenario streams it, and the slot
+        hashes the in-scan sketch update reads. The scenario compiles
+        into the round-invariant slot -> item map and every slot's
+        sketch coordinates are pre-hashed (the in-scan update then does
+        zero hashing). Both are deterministic in (cfg, K, N) — resumed
+        segments rebuild the SAME streams — so they are cached on the
+        trainer: repeated segments pay only the jitted data gather, not
+        the host-side plan compile + hashing."""
+        if max_items not in ingest_plans:
+            plan = ingest_scenarios.compile_plan(ingest_cfg, fed.num_nodes,
+                                                 max_items)
+            ingest_plans[max_items] = (
+                jnp.asarray(plan.src_node), jnp.asarray(plan.src_slot),
+                ingest_sketches.slot_hashes(jnp.asarray(plan.item_ids),
+                                            ingest_cfg))
+        src_node, src_slot, slot_hashes = ingest_plans[max_items]
+        return _ingest_gather(data, src_node, src_slot), slot_hashes
+
+    @partial(annotate_function, name="cdfl.fault_plan")
+    def _fault_inputs(etas, num_rounds: int, start: int,
+                      per_variant: bool = False):
+        """Compile the fault schedules for the absolute rounds
+        ``[start, start + num_rounds)`` (same slicing invariant as the
+        kinematic trace) and fold the surviving-link mask into the eta
+        stack host-side — into each variant's stack when
+        ``per_variant``. Rows only ever lose mass, so the gamma
+        stability bound computed on the unmasked stack stays valid.
+        Returns ``(etas, fault_xs)``."""
+        from repro.mobility import mixing as mobility_mixing
+        plan = faults_lib.compile_plan(fed.faults, num_rounds,
+                                       fed.num_nodes, start=start)
+        mask = jnp.asarray(plan.link_mask)
+
+        def fold(e):
+            if isinstance(e, hier_lib.HierEta):
+                # the link mask edits BOTH tiers' kept idx/val pairs — a
+                # crashed leader's cluster skips inter mixing
+                return hier_lib.masked_hier_stack(e, mask)
+            if isinstance(e, topology.SparseEta):
+                # the (R, K, K) link mask compiles to per-edge edits of
+                # the kept idx/val pairs — the dense mask matrix never
+                # meets the mixing math
+                return mobility_mixing.masked_sparse_stack(e, mask)
+            return mobility_mixing.masked_eta_stack(e, mask)
+
+        etas = jax.vmap(fold)(etas) if per_variant else fold(etas)
+        return etas, (jnp.asarray(plan.health), jnp.asarray(plan.byz),
+                      jnp.asarray(plan.corrupt),
+                      jnp.asarray(plan.straggle))
 
     # batched (vmapped) scan drivers, built lazily per sharing mode:
     # variant-invariant inputs (the resident datasets, fault schedules,
@@ -934,6 +1018,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                                           donate_argnums=(0,))
         return _batched_cache[key]
 
+    @partial(annotate_function, name="cdfl.prepare")
     def _batch_call(states: FedState, data, num_rounds: int, *,
                     rngs: Optional[jax.Array] = None,
                     n_items: Optional[jax.Array] = None,
@@ -994,17 +1079,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         max_items = jax.tree.leaves(data)[0].shape[1]
         slot_hashes = ()
         if ingest_on:
-            if max_items not in ingest_plans:
-                plan = ingest_scenarios.compile_plan(ingest_cfg,
-                                                     fed.num_nodes,
-                                                     max_items)
-                ingest_plans[max_items] = (
-                    jnp.asarray(plan.src_node),
-                    jnp.asarray(plan.src_slot),
-                    ingest_sketches.slot_hashes(
-                        jnp.asarray(plan.item_ids), ingest_cfg))
-            src_node, src_slot, slot_hashes = ingest_plans[max_items]
-            data = _ingest_gather(data, src_node, src_slot)
+            data, slot_hashes = _ingest_inputs(data, max_items)
         if n_items is not None:
             n_items = jnp.asarray(n_items)
         if rngs is None:
@@ -1074,22 +1149,9 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         fault_xs = ()
         if faulty:
             # ONE fault plan shared by every variant (the schedule is
-            # config-keyed); the surviving-link mask folds into each
-            # variant's eta stack host-side, exactly as run_rounds does
-            plan = faults_lib.compile_plan(fed.faults, num_rounds, k,
-                                           start=start)
-            mask = jnp.asarray(plan.link_mask)
-            if isinstance(etas, topology.SparseEta):
-                fold = lambda e: mobility_mixing.masked_sparse_stack(
-                    e, mask)
-            else:
-                fold = lambda e: mobility_mixing.masked_eta_stack(
-                    e, mask)
-            etas = fold(etas) if shared else jax.vmap(fold)(etas)
-            fault_xs = (jnp.asarray(plan.health),
-                        jnp.asarray(plan.byz),
-                        jnp.asarray(plan.corrupt),
-                        jnp.asarray(plan.straggle))
+            # config-keyed), folded into each variant's eta stack
+            etas, fault_xs = _fault_inputs(etas, num_rounds, start,
+                                           per_variant=not shared)
         fn = _batched_scan(shared, lrs is not None, num_rounds,
                            max_items)
         return fn, (states, data, round_keys, n_items, etas, gammas,
@@ -1097,7 +1159,8 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
 
     def run_rounds_batch(states: FedState, data, num_rounds: int, **kw):
         fn, args = _batch_call(states, data, num_rounds, **kw)
-        return fn(*args)
+        with TraceAnnotation("cdfl.dispatch"):
+            return fn(*args)
 
     run_rounds_batch.__doc__ = _batch_call.__doc__
 
@@ -1108,6 +1171,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         fn, args = _batch_call(states, data, num_rounds, **kw)
         return fn.lower(*args)
 
+    @partial(annotate_function, name="cdfl.prepare")
     def _rounds_args(state: FedState, data, num_rounds: int,
                      rng: Optional[jax.Array] = None,
                      n_items: Optional[jax.Array] = None,
@@ -1154,23 +1218,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         max_items = jax.tree.leaves(data)[0].shape[1]
         slot_hashes = ()
         if ingest_on:
-            # compile the redundancy scenario into the round-invariant
-            # slot -> item map and pre-hash every slot's sketch
-            # coordinates (the in-scan update then does zero hashing).
-            # Both are deterministic in (cfg, K, N) — resumed segments
-            # rebuild the SAME streams — so they are cached on the
-            # trainer: repeated run_rounds segments pay only the jitted
-            # data gather, not the host-side plan compile + hashing.
-            if max_items not in ingest_plans:
-                plan = ingest_scenarios.compile_plan(ingest_cfg,
-                                                     fed.num_nodes,
-                                                     max_items)
-                ingest_plans[max_items] = (
-                    jnp.asarray(plan.src_node), jnp.asarray(plan.src_slot),
-                    ingest_sketches.slot_hashes(jnp.asarray(plan.item_ids),
-                                                ingest_cfg))
-            src_node, src_slot, slot_hashes = ingest_plans[max_items]
-            data = _ingest_gather(data, src_node, src_slot)
+            data, slot_hashes = _ingest_inputs(data, max_items)
         if n_items is not None:
             n_items = jnp.asarray(n_items)
         start = int(state.round)
@@ -1236,38 +1284,14 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                              f"{(num_rounds,)}")
         fault_xs = ()
         if faulty:
-            from repro.mobility import mixing as mobility_mixing
-            # compile the fault schedules for THIS segment's absolute
-            # rounds (same slicing invariant as the kinematic trace) and
-            # fold the surviving-link mask into the eta stack host-side;
-            # rows only ever lose mass, so the gamma stability bound
-            # computed on the unmasked stack stays valid
-            plan = faults_lib.compile_plan(fed.faults, num_rounds, k,
-                                           start=start)
-            if isinstance(etas, hier_lib.HierEta):
-                # the link mask edits BOTH tiers' kept idx/val pairs —
-                # a crashed leader's cluster skips inter mixing
-                etas = hier_lib.masked_hier_stack(
-                    etas, jnp.asarray(plan.link_mask))
-            elif isinstance(etas, topology.SparseEta):
-                # the (R, K, K) link mask compiles to per-edge edits of
-                # the kept idx/val pairs — the dense mask matrix never
-                # meets the mixing math
-                etas = mobility_mixing.masked_sparse_stack(
-                    etas, jnp.asarray(plan.link_mask))
-            else:
-                etas = mobility_mixing.masked_eta_stack(etas,
-                                                        plan.link_mask)
-            fault_xs = (jnp.asarray(plan.health),
-                        jnp.asarray(plan.byz),
-                        jnp.asarray(plan.corrupt),
-                        jnp.asarray(plan.straggle))
+            etas, fault_xs = _fault_inputs(etas, num_rounds, start)
         return (state, data, round_keys, num_rounds, max_items, n_items,
                 etas, gammas, fault_xs, slot_hashes)
 
     def run_rounds(state: FedState, data, num_rounds: int, *args, **kw):
-        return _scan_rounds(*_rounds_args(state, data, num_rounds, *args,
-                                          **kw))
+        call = _rounds_args(state, data, num_rounds, *args, **kw)
+        with TraceAnnotation("cdfl.dispatch"):
+            return _scan_rounds(*call)
 
     run_rounds.__doc__ = _rounds_args.__doc__
 

@@ -43,11 +43,13 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from functools import partial
 from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro import registry
 from repro.checkpointing import restore as _ckpt_restore
@@ -237,6 +239,15 @@ class IngestCallback(Callback):
         self.print_fn(
             f"ingest: rounds={result.rounds} nodes={est.shape[0]} "
             f"est_distinct=[{vals}] spread={spread:.2f}")
+
+
+def _eval_fn(callbacks: Sequence[Callback]) -> Optional[Callable]:
+    """The device-side eval function a run's callbacks declare, if any
+    (at most one may)."""
+    eval_fns = [cb.eval_fn for cb in callbacks if cb.eval_fn is not None]
+    if len(eval_fns) > 1:
+        raise ValueError("at most one EvalCallback per run")
+    return eval_fns[0] if eval_fns else None
 
 
 # --------------------------------------------------------------------------
@@ -583,53 +594,65 @@ class Session:
         per-round dispatch. Periodic callbacks split the run into
         boundary-aligned scan segments; metrics are re-stacked across
         segments so the result is indistinguishable from one scan.
+
+        A run is the profiler span ``cdfl.run`` (argument
+        ``start_round``, the absolute round it starts at), holding
+        ``cdfl.callbacks`` (the hooks), the trainer's ``cdfl.prepare``
+        and ``cdfl.dispatch`` per segment, and ``cdfl.wait`` (until the
+        device is done).
         """
         if rounds <= 0:
             raise ValueError(f"rounds must be positive, got {rounds}")
-        callbacks = list(callbacks)
-        eval_fns = [cb.eval_fn for cb in callbacks
-                    if cb.eval_fn is not None]
-        if len(eval_fns) > 1:
-            raise ValueError("at most one EvalCallback per run")
-        trainer = self.experiment.trainer(
-            self.data, eval_fn=eval_fns[0] if eval_fns else None)
-        rng = self._rng if rng is None else rng
-
-        marks = {rounds}
-        for cb in callbacks:
-            if cb.every:
-                marks.update(range(cb.every, rounds + 1, cb.every))
-        for cb in callbacks:
-            cb.on_run_start(self, rounds)
-
-        t0 = time.time()
         start = self.rounds_completed
-        parts = []
-        prev = 0
-        for mark in sorted(marks):
-            self._state, metrics = trainer.run_rounds(
-                self._state, self.data, mark - prev, rng=rng,
-                n_items=self._n_items)
-            parts.append(metrics)
-            prev = mark
+        with TraceAnnotation("cdfl.run", start_round=start):
+            callbacks = list(callbacks)
+            trainer = self.experiment.trainer(
+                self.data, eval_fn=_eval_fn(callbacks))
+            rng = self._rng if rng is None else rng
+
+            marks = {rounds}
             for cb in callbacks:
-                if cb.every and mark % cb.every == 0 and mark < rounds:
-                    cb.on_rounds(self, start + mark)
-        metrics = (parts[0] if len(parts) == 1 else jax.tree.map(
-            lambda *xs: jnp.concatenate(xs, axis=0), *parts))
-        jax.block_until_ready(self._state.params)
-        result = RunResult(state=self._state, metrics=metrics,
-                           rounds=rounds, wall_time_s=time.time() - t0)
-        for cb in callbacks:
-            cb.on_run_end(self, result)
+                if cb.every:
+                    marks.update(range(cb.every, rounds + 1, cb.every))
+            with TraceAnnotation("cdfl.callbacks"):
+                for cb in callbacks:
+                    cb.on_run_start(self, rounds)
+
+            t0 = time.perf_counter()
+            parts = []
+            prev = 0
+            for mark in sorted(marks):
+                self._state, metrics = trainer.run_rounds(
+                    self._state, self.data, mark - prev, rng=rng,
+                    n_items=self._n_items)
+                parts.append(metrics)
+                prev = mark
+                if mark < rounds:
+                    with TraceAnnotation("cdfl.callbacks"):
+                        for cb in callbacks:
+                            if cb.every and mark % cb.every == 0:
+                                cb.on_rounds(self, start + mark)
+            metrics = (parts[0] if len(parts) == 1 else jax.tree.map(
+                lambda *xs: jnp.concatenate(xs, axis=0), *parts))
+            with TraceAnnotation("cdfl.wait"):
+                jax.block_until_ready(self._state.params)
+            result = RunResult(state=self._state, metrics=metrics,
+                               rounds=rounds,
+                               wall_time_s=time.perf_counter() - t0)
+            with TraceAnnotation("cdfl.callbacks"):
+                for cb in callbacks:
+                    cb.on_run_end(self, result)
         return result
 
-    def lower(self, rounds: int, rng: Optional[jax.Array] = None):
-        """The scan one callback-free ``run(rounds)`` dispatches, lowered
-        and not run: ``.compile()`` gives the device program (its
-        ``as_text()`` shows which kernels a round runs). The live state
-        is not donated."""
-        trainer = self.experiment.trainer(self.data)
+    def lower(self, rounds: int, callbacks: Sequence[Callback] = (),
+              rng: Optional[jax.Array] = None):
+        """The scan ``run(rounds, callbacks)`` dispatches when no
+        callback is periodic (with an :class:`EvalCallback`, the program
+        that evaluates every round), lowered and not run: ``.compile()``
+        gives the device program (its ``as_text()`` shows which kernels a
+        round runs). The live state is not donated."""
+        trainer = self.experiment.trainer(self.data,
+                                          eval_fn=_eval_fn(callbacks))
         return trainer.lower_rounds(self._state, self.data, rounds,
                                     rng=self._rng if rng is None else rng,
                                     n_items=self._n_items)
@@ -721,35 +744,38 @@ class BatchedSession:
                     f"host-side scan segmentation — unsupported on "
                     f"batched runs; use a plain Session per variant "
                     f"for periodic callbacks")
-        eval_fns = [cb.eval_fn for cb in callbacks
-                    if cb.eval_fn is not None]
-        if len(eval_fns) > 1:
-            raise ValueError("at most one EvalCallback per run")
-        trainer = self.experiment.trainer(
-            self.data, eval_fn=eval_fns[0] if eval_fns else None)
-        for cb in callbacks:
-            cb.on_run_start(self, rounds)
-        t0 = time.time()
-        self._states, metrics = trainer.run_rounds_batch(
-            self._states, self.data, rounds,
-            **self._batch_inputs(trainer, rounds))
-        jax.block_until_ready(self._states.params)
-        result = BatchResult(state=self._states, metrics=metrics,
-                             rounds=rounds,
-                             wall_time_s=time.time() - t0,
-                             variants=self.variants)
-        for cb in callbacks:
-            cb.on_run_end(self, result)
+        # the same profiler spans as Session.run
+        with TraceAnnotation("cdfl.run", start_round=self.rounds_completed):
+            trainer = self.experiment.trainer(
+                self.data, eval_fn=_eval_fn(callbacks))
+            with TraceAnnotation("cdfl.callbacks"):
+                for cb in callbacks:
+                    cb.on_run_start(self, rounds)
+            t0 = time.perf_counter()
+            self._states, metrics = trainer.run_rounds_batch(
+                self._states, self.data, rounds,
+                **self._batch_inputs(trainer, rounds))
+            with TraceAnnotation("cdfl.wait"):
+                jax.block_until_ready(self._states.params)
+            result = BatchResult(state=self._states, metrics=metrics,
+                                 rounds=rounds,
+                                 wall_time_s=time.perf_counter() - t0,
+                                 variants=self.variants)
+            with TraceAnnotation("cdfl.callbacks"):
+                for cb in callbacks:
+                    cb.on_run_end(self, result)
         return result
 
-    def lower(self, rounds: int):
-        """The vmapped scan one callback-free ``run_batch(rounds)``
+    def lower(self, rounds: int, callbacks: Sequence[Callback] = ()):
+        """The vmapped scan one ``run_batch(rounds, callbacks)``
         dispatches, lowered and not run (see :meth:`Session.lower`)."""
-        trainer = self.experiment.trainer(self.data)
+        trainer = self.experiment.trainer(self.data,
+                                          eval_fn=_eval_fn(callbacks))
         return trainer.lower_rounds_batch(
             self._states, self.data, rounds,
             **self._batch_inputs(trainer, rounds))
 
+    @partial(annotate_function, name="cdfl.prepare")
     def _batch_inputs(self, trainer: Trainer, rounds: int) -> dict:
         """The per-variant keyword inputs of the batched scan: sampling
         keys, item counts, and — when mobility or gamma is swept — the

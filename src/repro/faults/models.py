@@ -24,6 +24,7 @@ from typing import NamedTuple
 import jax.lax as lax
 import jax.numpy as jnp
 import numpy as np
+from jax import monitoring
 
 from repro.registry import fault_models
 
@@ -174,9 +175,13 @@ def compile_plan(cfg, rounds: int, k: int, start: int = 0) -> FaultPlan:
 
     Schedules are always generated from round 0 and sliced, so a
     resumed segment sees the same faults as the equivalent stretch of an
-    unbroken run (the mobility-trace segmentation invariant).
+    unbroken run (the mobility-trace segmentation invariant). The rounds
+    generated and the rounds kept are reported as the ``jax.monitoring``
+    scalars ``/cdfl/faults/rounds_built`` and ``/cdfl/faults/rounds_used``.
     """
     total = int(start) + int(rounds)
+    monitoring.record_scalar("/cdfl/faults/rounds_built", total)
+    monitoring.record_scalar("/cdfl/faults/rounds_used", int(rounds))
     plan = {
         "link_mask": np.ones((total, k, k), dtype=np.float32),
         "health": np.ones((total, k), dtype=np.float32),

@@ -224,10 +224,8 @@ def hier_scenario_stacks(mob, rounds: int, k: int, *, rule: str,
     are computed from round 0 and sliced at ``start`` (hysteresis and
     re-merge flags chain round to round), so a resumed segment sees the
     same clusters an unsegmented run would."""
-    from repro.mobility import links, traces
-    pos = traces.trace(mob.kind, start + rounds, k, speed=mob.speed,
-                       speed_jitter=mob.speed_jitter, area=mob.area,
-                       dt=mob.dt, seed=mob.seed)
+    from repro.mobility import links, trace_from_zero
+    pos = trace_from_zero(mob, rounds, k, start)
     adj = links.radio_adjacency(pos, mob.radio_range,
                                 link_quality=mob.link_quality,
                                 min_quality=mob.min_quality)

@@ -424,13 +424,14 @@ def main(argv=None):
     else:
         trainer = session.experiment.trainer(data)
         for r in range(args.rounds):
-            t0 = time.time()
+            t0 = time.perf_counter()
             batch = pipeline.lm_batches(nodes, args.batch, args.local_steps,
                                         seed=1000 + r)
             batch = jax.tree.map(jnp.asarray, batch)
             state, metrics = trainer.round(state, batch)
             _print_round(r, np.asarray(metrics["loss"]),
-                         float(metrics["disagreement"]), time.time() - t0)
+                         float(metrics["disagreement"]),
+                         time.perf_counter() - t0)
 
     if args.checkpoint:
         save(args.checkpoint, state.params, step=args.rounds)

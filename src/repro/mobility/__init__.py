@@ -25,6 +25,7 @@ phantom non-ring links appear that the transport could never carry.
 from __future__ import annotations
 
 import numpy as np
+from jax import monitoring
 
 from repro.configs.base import MobilityConfig
 from repro.mobility import links, mixing, traces
@@ -40,13 +41,31 @@ from repro.mobility.traces import trace
 
 __all__ = [
     "MobilityConfig", "adjacency_stack", "scenario_stacks",
-    "sparse_scenario_stacks", "trace", "radio_adjacency",
+    "sparse_scenario_stacks", "trace", "trace_from_zero", "radio_adjacency",
     "sparse_radio_stack", "handover_stats", "degree_stats",
     "num_components", "eta_stack", "gamma_stack", "sparse_eta_stack",
     "sparse_gamma_stack", "constant_stacks", "constant_sparse_stacks",
     "masked_sparse_stack", "stack_variant_stacks", "links", "mixing",
     "traces",
 ]
+
+
+def trace_from_zero(mob: MobilityConfig, rounds: int, k: int,
+                    start: int = 0) -> np.ndarray:
+    """(start + rounds, K, 2) positions of rounds ``[0, start + rounds)``.
+
+    A trace is deterministic per seed and keeps no state to resume from,
+    so a window starting at ``start`` is regenerated from t=0 and the
+    caller slices it. The rounds generated and the rounds the window
+    uses are reported as the ``jax.monitoring`` scalars
+    ``/cdfl/mobility/rounds_built`` and ``/cdfl/mobility/rounds_used``.
+    """
+    monitoring.record_scalar("/cdfl/mobility/rounds_built",
+                             int(start) + int(rounds))
+    monitoring.record_scalar("/cdfl/mobility/rounds_used", int(rounds))
+    return trace(mob.kind, start + rounds, k,
+                 speed=mob.speed, speed_jitter=mob.speed_jitter,
+                 area=mob.area, dt=mob.dt, seed=mob.seed)
 
 
 def adjacency_stack(mob: MobilityConfig, rounds: int, k: int,
@@ -57,12 +76,10 @@ def adjacency_stack(mob: MobilityConfig, rounds: int, k: int,
     ``mask``: optional static 0/1 adjacency intersected with every
     round's radio graph (the ring-transport physical constraint).
     ``start``: first round of the window — the trace is regenerated
-    from t=0 (deterministic per seed) and sliced, so a resumed run
+    from t=0 (:func:`trace_from_zero`) and sliced, so a resumed run
     continues the same trajectory it left.
     """
-    positions = trace(mob.kind, start + rounds, k,
-                      speed=mob.speed, speed_jitter=mob.speed_jitter,
-                      area=mob.area, dt=mob.dt, seed=mob.seed)[start:]
+    positions = trace_from_zero(mob, rounds, k, start)[start:]
     adj = radio_adjacency(positions, mob.radio_range,
                           link_quality=mob.link_quality,
                           min_quality=mob.min_quality)
@@ -97,9 +114,7 @@ def sparse_scenario_stacks(mob: MobilityConfig, rounds: int, k: int, *,
     host). Returns ``(SparseEta (R, K, D), gammas (R,))`` ready to ride
     the ``run_rounds`` scan at O(R·K·D) memory.
     """
-    positions = trace(mob.kind, start + rounds, k,
-                      speed=mob.speed, speed_jitter=mob.speed_jitter,
-                      area=mob.area, dt=mob.dt, seed=mob.seed)[start:]
+    positions = trace_from_zero(mob, rounds, k, start)[start:]
     idx, val = sparse_radio_stack(positions, mob.radio_range, degree,
                                   link_quality=mob.link_quality,
                                   min_quality=mob.min_quality, mask=mask)
