@@ -74,6 +74,8 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import monitoring
 from jax.profiler import TraceAnnotation, annotate_function
 
 from repro import registry
@@ -126,6 +128,10 @@ class Trainer(NamedTuple):
     # inspection: which kernels a round runs); None on hand-built stubs
     lower_rounds: Callable = None
     lower_rounds_batch: Callable = None
+    # (rng, start, ratios, sizes, num_rounds=, static_graph=) ->
+    # (round keys, static stacks | None): the one compiled program that
+    # builds both drivers' round-invariant inputs; None on stubs
+    prepare: Callable = None
 
 
 def _node_sketches(node_items, fed: FedConfig):
@@ -619,20 +625,21 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                              state.istate)
         return new_state, metrics
 
-    def _mixing(state: FedState, cap: Optional[float] = None):
+    def _mixing(ratios, sizes, cap: Optional[float] = None):
         cap = fed.gamma if cap is None else cap
         if hier_fmt:
             # the index geometry depends only on the concrete static
             # adjacency (a trace constant), so this is jit-traceable in
             # the CND ratios like the dense rule
             return hier_lib.hier_static_stacks(
-                adj, rule=hier_rule, ratios=state.ratios,
-                sizes=state.sizes, gamma_cap=cap,
+                adj, rule=hier_rule, ratios=ratios, sizes=sizes,
+                gamma_cap=cap,
                 max_cluster_size=hier_cfg.max_cluster_size,
                 leader_policy=hier_cfg.leader_policy,
                 inter_degree=hier_cfg.inter_degree,
                 hysteresis=hier_cfg.hysteresis)
-        eta = eta_fn(state)
+        eta = topology.mixing_weights(adj, mix_rule, ratios=ratios,
+                                      sizes=sizes)
         gamma = topology.stable_gamma(eta, cap)
         if sparse_fmt:
             # sparsify AFTER the stability bound: the top-D renorm
@@ -640,6 +647,18 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
             # matrix is the bound of the sparse one
             return topology.sparsify_eta(eta, fed.degree), gamma
         return eta, gamma
+
+    def _static_stacks(ratios, sizes, num_rounds: int, cap: float):
+        """The static graph's eta and gamma broadcast to ``num_rounds``
+        rounds, in the trainer's mixing format."""
+        from repro import mobility as mobility_lib
+        eta, gamma = _mixing(ratios, sizes, cap)
+        if hier_fmt:
+            return hier_lib.constant_hier_stacks(eta, gamma, num_rounds)
+        if sparse_fmt:
+            return mobility_lib.constant_sparse_stacks(eta, gamma,
+                                                       num_rounds)
+        return mobility_lib.constant_stacks(eta, gamma, num_rounds)
 
     def round_fn(state: FedState, batches):
         if mobile:
@@ -657,7 +676,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                 "FedConfig.ingest is set but Trainer.round drives one "
                 "round at a time — the streaming-redundancy sketches "
                 "ride the run_rounds scan")
-        eta, gamma = _mixing(state)
+        eta, gamma = _mixing(state.ratios, state.sizes)
         return round_body(state, batches, eta, gamma)
 
     @partial(annotate_function, name="cdfl.mixing_stack")
@@ -684,14 +703,8 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         mob = fed.mobility if mobility == "config" else mobility
         cap = fed.gamma if gamma_cap is None else float(gamma_cap)
         if mob is None or mob.kind == "static":
-            eta, gamma = _mixing(state, cap)
-            if hier_fmt:
-                return hier_lib.constant_hier_stacks(eta, gamma,
-                                                     num_rounds)
-            if sparse_fmt:
-                return mobility_lib.constant_sparse_stacks(
-                    eta, gamma, num_rounds)
-            return mobility_lib.constant_stacks(eta, gamma, num_rounds)
+            return _static_stacks(state.ratios, state.sizes, num_rounds,
+                                  cap)
         if hier_fmt:
             return hier_lib.hier_scenario_stacks(
                 mob, num_rounds, fed.num_nodes, rule=hier_rule,
@@ -1018,6 +1031,46 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                                           donate_argnums=(0,))
         return _batched_cache[key]
 
+    @partial(jax.jit, static_argnames=("num_rounds", "static_graph"))
+    def _prepare(rng, start, ratios, sizes, num_rounds: int,
+                 static_graph: bool):
+        """A call's round-invariant device inputs, built by one program.
+
+        Returns the round keys folded from ``rng`` on the absolute
+        rounds ``[start, start + num_rounds)`` — ``(R, 2)`` keys, or
+        ``(V, R, 2)`` for a ``(V, 2)`` stack of keys, whose ``(V, K)``
+        ratios and sizes then come from variant 0 — and, when
+        ``static_graph``, the static graph's eta/gamma stacks (else
+        None). ``start`` is traced, so a continuing run reuses the
+        program instead of compiling one per start round."""
+        with jax.named_scope("cdfl.prepare"):
+            rounds = start + jnp.arange(num_rounds, dtype=jnp.int32)
+
+            def fold(key):
+                return jax.vmap(lambda r: jax.random.fold_in(key, r))(
+                    rounds)
+
+            if rng.ndim > 1:
+                round_keys = jax.vmap(fold)(rng)
+                ratios, sizes = ratios[0], sizes[0]
+            else:
+                round_keys = fold(rng)
+            if not static_graph:
+                return round_keys, None
+            return round_keys, _static_stacks(ratios, sizes, num_rounds,
+                                              fed.gamma)
+
+    def _prepare_call(rng, start: int, ratios, sizes, num_rounds: int,
+                      static_graph: bool):
+        """:func:`_prepare` for one call; a static graph's rounds are
+        counted as ``/cdfl/prepare/rounds_compiled``."""
+        out = _prepare(rng, np.int32(start), ratios, sizes,
+                       num_rounds=num_rounds, static_graph=static_graph)
+        if static_graph:
+            monitoring.record_scalar("/cdfl/prepare/rounds_compiled",
+                                     num_rounds)
+        return out
+
     @partial(annotate_function, name="cdfl.prepare")
     def _batch_call(states: FedState, data, num_rounds: int, *,
                     rngs: Optional[jax.Array] = None,
@@ -1090,12 +1143,15 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         if rngs.shape[0] != v:
             raise ValueError(f"rngs leading dim {rngs.shape[0]} != "
                              f"V={v} variants")
-        rr = jnp.arange(start, start + num_rounds)
-        round_keys = jax.vmap(
-            lambda key: jax.vmap(
-                lambda r: jax.random.fold_in(key, r))(rr))(rngs)
+        static_graph = eta_stacks is None and not mobile
+        round_keys, stacks = _prepare_call(rngs, start, states.ratios,
+                                           states.sizes, num_rounds,
+                                           static_graph)
         # -- mixing stacks: shared (in_axes=None) or per-variant --------
-        if eta_stacks is None:
+        if static_graph:
+            etas, gammas = stacks
+            shared = True
+        elif eta_stacks is None:
             state0 = jax.tree.map(lambda a: a[0], states)
             etas, gammas = mixing_stack(state0, num_rounds, start=start)
             shared = True
@@ -1222,10 +1278,13 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         if n_items is not None:
             n_items = jnp.asarray(n_items)
         start = int(state.round)
-        round_keys = jax.vmap(lambda r: jax.random.fold_in(rng, r))(
-            jnp.arange(start, start + num_rounds))
+        static_graph = eta_stack is None and not mobile
+        round_keys, stacks = _prepare_call(rng, start, state.ratios,
+                                           state.sizes, num_rounds,
+                                           static_graph)
         if eta_stack is None:
-            etas, gammas = mixing_stack(state, num_rounds, start=start)
+            etas, gammas = (stacks if static_graph else
+                            mixing_stack(state, num_rounds, start=start))
             if gamma_stack is not None:
                 gammas = jnp.asarray(gamma_stack, jnp.float32)
         else:
@@ -1306,4 +1365,5 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                    run_rounds=run_rounds, mixing_stack=mixing_stack,
                    run_rounds_batch=run_rounds_batch,
                    lower_rounds=lower_rounds,
-                   lower_rounds_batch=lower_rounds_batch)
+                   lower_rounds_batch=lower_rounds_batch,
+                   prepare=_prepare)

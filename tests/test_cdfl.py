@@ -315,3 +315,105 @@ def test_flat_and_leaf_local_representations_agree():
                                atol=1e-6)
     np.testing.assert_array_equal(np.asarray(a.opt.step),
                                   np.asarray(b.opt.step))
+
+
+# --- the compiled prepare program -------------------------------------------
+
+COMPILE_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+
+def _static_fleet(fmt):
+    """A static-graph trainer for eight nodes in ``fmt`` and its state,
+    with CND ratios that differ across nodes."""
+    k = 8
+
+    def loss_fn(params, batch):
+        return jnp.mean((batch["x"] @ params["w"] - batch["y"]) ** 2)
+
+    fed = FedConfig(num_nodes=k, local_steps=2, mixing_format=fmt,
+                    degree=4 if fmt == "sparse" else None)
+    tr = build_trainer(loss_fn, fed, TrainConfig(learning_rate=0.05,
+                                                 batch_size=4))
+    # node i draws its item tokens from 3 + i values: more duplicates,
+    # a lower CND ratio, on the lower nodes
+    items = jax.random.randint(jax.random.PRNGKey(1), (k, 48, 2), 0, 60)
+    items = items % (3 + jnp.arange(k))[:, None, None]
+    state = tr.init(jax.random.PRNGKey(0),
+                    lambda r: {"w": jax.random.normal(r, (6,))}, items)
+    return tr, state
+
+
+@pytest.mark.parametrize("fmt,start,variants", [
+    ("dense", 0, None), ("dense", 60, None), ("sparse", 0, None),
+    ("sparse", 60, None), ("hierarchical", 0, None),
+    ("hierarchical", 60, None), ("dense", 60, 3), ("sparse", 60, 3)])
+def test_prepare_matches_eager_formulas(fmt, start, variants):
+    """The compiled prepare gives the round keys the eager
+    ``vmap(fold_in)`` gave and the static stacks that the eager
+    ``mixing_stack`` gives, bit for bit: on XLA:CPU no rewrite of the
+    compiled program (a division turned into a multiplication by a
+    reciprocal, say) moves a float of the stacks, so no ulp of slack is
+    allowed."""
+    rounds = 60
+    tr, state = _static_fleet(fmt)
+    rng = jax.random.PRNGKey(7)
+    ratios, sizes = state.ratios, state.sizes
+    expect_keys = jax.vmap(lambda r: jax.random.fold_in(rng, r))(
+        jnp.arange(start, start + rounds))
+    if variants:
+        rng = jax.random.split(rng, variants)
+        expect_keys = jax.vmap(lambda key: jax.vmap(
+            lambda r: jax.random.fold_in(key, r))(
+                jnp.arange(start, start + rounds)))(rng)
+        # a (V,)-stacked state: the stacks come from variant 0
+        ratios = jnp.stack([ratios, ratios[::-1], ratios])
+        sizes = jnp.stack([sizes, sizes[::-1], sizes])
+    keys, stacks = tr.prepare(rng, np.int32(start), ratios, sizes,
+                              num_rounds=rounds, static_graph=True)
+    np.testing.assert_array_equal(np.asarray(keys),
+                                  np.asarray(expect_keys))
+    expect = tr.mixing_stack(state, rounds, start=start)
+    got_leaves, treedef = jax.tree.flatten(stacks)
+    assert treedef == jax.tree.structure(expect)
+    for got, want in zip(got_leaves, jax.tree.leaves(expect)):
+        got, want = np.asarray(got), np.asarray(want)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+    assert tr.prepare(rng, np.int32(start), ratios, sizes,
+                      num_rounds=rounds, static_graph=False)[1] is None
+
+
+def test_continuing_static_session_lowers_nothing_after_second_call():
+    """Three continuing 60-round calls of a static-graph session: the
+    first two may lower programs, the third lowers none (the prepare
+    program takes its start round as an argument)."""
+    from repro.experiment import EvalCallback, Experiment
+
+    def loss_fn(params, batch):
+        return jnp.mean((batch["x"] @ params["w"] - batch["y"]) ** 2)
+
+    rng = np.random.default_rng(0)
+    data = {"x": jnp.asarray(rng.normal(size=(4, 40, 6)), jnp.float32),
+            "y": jnp.asarray(rng.normal(size=(4, 40)), jnp.float32)}
+    items = jnp.asarray(rng.integers(0, 60, (4, 40, 4)))
+    session = Experiment.from_parts(
+        loss_fn, lambda r: {"w": jax.random.normal(r, (6,))},
+        fed=FedConfig(num_nodes=4, local_steps=2),
+        train=TrainConfig(learning_rate=0.05, batch_size=4),
+    ).compile(data, items)
+    evaluate = EvalCallback(lambda p: jnp.sum(p["w"] ** 2))
+    lowered = []
+
+    def count(event, duration_secs=0.0, **kw):
+        if event == COMPILE_EVENT:
+            lowered[-1] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(count)
+    try:
+        for _ in range(3):
+            lowered.append(0)
+            session.run(60, callbacks=[evaluate])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count)
+    assert lowered[0] > 0 and lowered[2] == 0, lowered
+    assert session.rounds_completed == 180

@@ -165,3 +165,33 @@ def test_lower_takes_the_run_callbacks():
     _, with_eval = session.lower(ROUNDS, callbacks=[evaluate]).out_info
     assert "eval" not in plain
     assert with_eval["eval"].shape == (ROUNDS, K)
+
+
+def test_prepare_counts_rounds_of_compiled_stacks(profiled):
+    """``/cdfl/prepare/rounds_compiled`` counts the rounds whose mixing
+    stack came out of the compiled prepare program: every round of a
+    paper study's call (four stations on a static ring, 60 rounds with
+    eval), none of a mobility run."""
+    name = "/cdfl/prepare/rounds_compiled"
+    _, mobile_counters = profiled
+    assert sum(v for event, v in mobile_counters if event == name) == 0
+    data, items = _inputs()
+    data = jax.tree.map(lambda a: a[:4], data)
+    session = Experiment.from_parts(
+        _loss, _initp, fed=FedConfig(num_nodes=4, local_steps=2),
+        train=TrainConfig(learning_rate=0.05, batch_size=4),
+    ).compile(data, items[:4])
+    counters = []
+
+    def listen(event, value, **kw):
+        if event.startswith("/cdfl/"):
+            counters.append((event, value))
+
+    jax.monitoring.register_scalar_listener(listen)
+    try:
+        for _ in range(2):
+            session.run(60, callbacks=[EvalCallback(
+                lambda p: jnp.sum(p["w"] ** 2))])
+    finally:
+        jax.monitoring.unregister_scalar_listener(listen)
+    assert counters == [(name, 60), (name, 60)]
