@@ -7,23 +7,17 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
-def experiment(config: dict, desc: dict, traffic: dict):
+def experiment(family, config: dict, desc: dict, traffic: dict):
     """The cell's ``Experiment``, with every setting from the benchmark's
-    files."""
+    files and the model from the configuration's ``family``."""
     from repro.configs.base import (FaultConfig, FedConfig, IngestConfig,
                                     MobilityConfig, TrainConfig)
-    from repro.configs.paper_models import MLPConfig
     from repro.experiment import Experiment
-    from repro.models import simple
 
-    model, train, fleet = config["model"], config["train"], config["fleet"]
-    mlp = MLPConfig(input_dim=model["input_dim"], hidden=model["hidden"],
-                    num_classes=model["num_classes"],
-                    learning_rate=train["learning_rate"],
-                    beta1=train["beta1"], beta2=train["beta2"],
-                    eps=train["eps"], batch_size=train["batch_size"])
+    train, fleet = config["train"], config["fleet"]
     mob = desc["variants"][0]["mobility"]
     if traffic.get("variants") is not None:
         mob = None                      # swept per variant, not configured
@@ -44,20 +38,8 @@ def experiment(config: dict, desc: dict, traffic: dict):
                      beta1=train["beta1"], beta2=train["beta2"],
                      eps=train["eps"], batch_size=train["batch_size"],
                      seed=desc["variants"][0]["init_seed"])
-    exp = Experiment.from_parts(simple.make_mlp_loss(mlp),
-                                lambda r: simple.mlp_init(r, mlp),
-                                fed=fed, train=tc)
-    return exp
-
-
-def eval_fn(inputs: dict):
-    """Accuracy of one node's parameters on the fleet's shared test set."""
-    from repro.models import simple
-    xt, yt = inputs["xt"], inputs["yt"]
-
-    def fn(params):
-        return simple.accuracy(simple.mlp_forward(params, xt), yt)
-    return fn
+    loss_fn, init_params = family.model(config)
+    return Experiment.from_parts(loss_fn, init_params, fed=fed, train=tc)
 
 
 def mobility_config(mob: dict | None):
@@ -65,12 +47,11 @@ def mobility_config(mob: dict | None):
     return None if mob is None else MobilityConfig(**mob)
 
 
-def leaf_norms(tree, leaves) -> jax.Array:
-    """(V, K, leaves) Euclidean norm of every node's every leaf; ``tree``
-    holds leaves with leading (V, K) axes."""
-    return jnp.stack([jnp.sqrt(jnp.sum(jnp.square(
-        tree[name].astype(jnp.float32)).reshape(tree[name].shape[:2] + (-1,)),
-        axis=-1)) for name in leaves], axis=-1)
+def leaf_norms(leaves: list) -> np.ndarray:
+    """(V, K, leaves) Euclidean norm of every node's every leaf, on the
+    host; each leaf a NumPy array with leading (V, K) axes."""
+    return np.stack([np.sqrt(np.sum(np.square(a.astype(np.float64)).reshape(
+        a.shape[:2] + (-1,)), axis=-1)) for a in leaves], axis=-1)
 
 
 def flat_leaf_norms(buf, sizes) -> jax.Array:

@@ -45,19 +45,20 @@ def numbers(prog: dict, ref: dict) -> dict:
     }
 
 
-def reference_inputs(run: dict) -> dict:
-    inputs, desc = run["inputs"], run["desc"]
-    return {"x": inputs["x"], "y": inputs["y"],
-            "items": np.asarray(inputs["items"]),
-            "variants": desc["variants"], "faults": desc["faults"],
-            "ingest": desc["ingest"]}
+def reference_inputs(parts: dict, run: dict) -> dict:
+    """The model family's data for the reference, and the seeds of the
+    run's variants, fault plan and redundancy plan."""
+    desc = run["desc"]
+    return dict(parts["family"].reference_inputs(run["inputs"]),
+                variants=desc["variants"], faults=desc["faults"],
+                ingest=desc["ingest"])
 
 
 def compare(parts: dict, run: dict) -> dict:
     """``{number: {"value", "limit"}}`` for the cell; a number that is not
     finite (the program produced NaN or Inf) reads ``"not finite"``."""
     ref = parts["reference"].run(parts["config"], parts["traffic"],
-                                 reference_inputs(run),
+                                 reference_inputs(parts, run),
                                  calls=1)
     got = numbers(run["readings"], ref)
     limits = parts["limits"]

@@ -1,58 +1,12 @@
-"""Fleet inputs made from the run's seed, on the device, in one call.
-
-The arithmetic is that of class-template synthetic MNIST (fixed class
-templates, each item its class template plus Gaussian noise), vectorized
-over vehicles so that a fleet of 1,024 vehicles costs one jitted call
-rather than a loop over them. The CND feature tokens of each item are
-its pixels pooled into ``features`` buckets and quantized to 12 bits
-over the vehicle's own range, as a vehicle would tokenize its records.
-"""
+"""What a run draws from its seed besides the fleet's data, which its
+model family makes (``families/<family>.py``)."""
 from __future__ import annotations
-
-from functools import partial
-
-import jax
-import jax.numpy as jnp
-
-TEMPLATE_SEED = 1234          # class templates are shared by every run
 
 
 def base_seed(seed: int) -> int:
     """The run's seed folded into what ``jax.random.PRNGKey`` keeps (31
     bits), with room above for the sweep's consecutive variant seeds."""
     return int(seed) % (2 ** 31 - 4096)
-
-
-@partial(jax.jit, static_argnames=("k", "n", "n_test", "dim", "classes",
-                                   "features"))
-def _make(key, noise, *, k, n, n_test, dim, classes, features):
-    templates = jax.random.normal(jax.random.PRNGKey(TEMPLATE_SEED),
-                                  (classes, dim))
-    ky, kx, kty, ktx = jax.random.split(key, 4)
-    y = jax.random.randint(ky, (k, n), 0, classes)
-    x = templates[y] + noise * jax.random.normal(kx, (k, n, dim))
-    pooled = x.reshape(k, n, features, dim // features).mean(-1)
-    lo = pooled.min(axis=(1, 2), keepdims=True)
-    hi = pooled.max(axis=(1, 2), keepdims=True)
-    items = ((pooled - lo) / (hi - lo + 1e-9) * 4095).astype(jnp.int32)
-    yt = jax.random.randint(kty, (n_test,), 0, classes)
-    xt = templates[yt] + noise * jax.random.normal(ktx, (n_test, dim))
-    return x, y, items, xt, yt
-
-
-def fleet(config: dict, seed: int) -> dict:
-    """``x`` (K, N, D), ``y`` (K, N), ``items`` (K, N, F) and the shared
-    test set ``xt``, ``yt`` of K x test_per_node items."""
-    model, data = config["model"], config["data"]
-    k = config["fleet"]["num_nodes"]
-    key = jax.random.fold_in(jax.random.PRNGKey(base_seed(seed)), 7)
-    x, y, items, xt, yt = _make(
-        key, jnp.float32(data["noise"]), k=k, n=data["train_per_node"],
-        n_test=max(1, k * data["test_per_node"]), dim=model["input_dim"],
-        classes=model["num_classes"], features=data["features"])
-    out = {"x": x, "y": y, "items": items, "xt": xt, "yt": yt}
-    jax.block_until_ready(out)
-    return out
 
 
 def describe(config: dict, traffic: dict, seed: int) -> dict:

@@ -6,8 +6,9 @@ A run, in order:
    and metric readers by name (``spec``);
 2. refuses to go on without as many TPU chips as the cell asks for;
 3. turns on the persistent compilation cache at its fixed path;
-4. makes the fleet data on the device from ``--seed`` and builds the
-   entry (``Experiment.compile`` or ``compile_batch``);
+4. makes the fleet data on the device from ``--seed`` through the
+   configuration's model family and builds the entry
+   (``Experiment.compile`` or ``compile_batch``);
 5. makes the traffic's fixed number of warm-up calls, the first of
    which the reference follows later; this is set-up;
 6. drives the entry back to back for ``--seconds``, counting the
@@ -108,37 +109,29 @@ def host_spans(names):
             setattr(mod, fn_name, fn)
 
 
-def leaf_sizes(config: dict) -> list:
-    """Element counts of the MLP's leaves in packed (sorted-name) order:
-    b1, b2, w1, w2."""
-    m = config["model"]
-    return [m["hidden"], m["num_classes"], m["input_dim"] * m["hidden"],
-            m["hidden"] * m["num_classes"]]
-
-
 def set_up(parts: dict, seed: int) -> dict:
     """Data, entry and the traffic's warm-up calls; the program's
     readings of the first call, which the reference follows. The first
     call compiles the entry's programs; the second compiles what only a
     call that continues a run uses (a continuing ``Session.run`` lowers
-    one more small program)."""
+    one more small program). The parameters before the first call are
+    kept on the host, so that the device holds no second copy of them."""
     import jax
-    import jax.numpy as jnp
     from benchlib import build
     config, traffic = parts["config"], parts["traffic"]
+    family = parts["family"]
     desc = data.describe(config, traffic, seed)
-    inputs = data.fleet(config, seed)
-    entry = parts["entry"].Entry(config, traffic, inputs, desc)
-    p0 = jax.tree.map(lambda a: jnp.array(a, copy=True), entry.params())
+    inputs = family.fleet(config, seed)
+    entry = parts["entry"].Entry(family, config, traffic, inputs, desc)
+    p0 = jax.tree.leaves(entry.params())
     result = entry.call()
-    change = jax.tree.map(lambda a, b: a - b, entry.params(), p0)
+    p1 = jax.tree.leaves(entry.params())
     readings = {
         "loss": [entry.loss(result)],
-        "m": np.asarray(build.flat_leaf_norms(entry.first_moment(),
-                                              leaf_sizes(config))),
-        "change": np.asarray(build.leaf_norms(
-            change, parts["reference"].LEAVES))}
-    del p0, change, result
+        "m": np.asarray(build.flat_leaf_norms(
+            entry.first_moment(), [n for _, n in family.leaves(config)])),
+        "change": build.leaf_norms([b - a for a, b in zip(p0, p1)])}
+    del p0, p1, result
     for _ in range(traffic["warmup_calls"] - 1):
         entry.call()
     return {"entry": entry, "inputs": inputs, "desc": desc,
@@ -234,8 +227,8 @@ def execute(parts: dict, seed: int, seconds: float, traced: bool,
     calls = window(entry, seconds, tracer)
     peak = memory_peak()
     ctx = metrics_lib.Context(
-        config=config, traffic=traffic, cell=cell, device=device,
-        calls=calls, setup_s=setup_s,
+        config=config, family=parts["family"], traffic=traffic, cell=cell,
+        device=device, calls=calls, setup_s=setup_s,
         compiles=counter.between(calls[0][0], calls[-1][1]),
         trace=tracer and tracer.load())
     del entry, run["entry"]

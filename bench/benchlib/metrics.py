@@ -1,11 +1,13 @@
-"""What a metric reader is given: the run's calls, set-up, compile count
-and (in a traced run) its trace, with the arithmetic several readers
-share. A reader is ``bench/metrics/<name>.py`` with ``read(ctx)``; it
-returns ``None`` where it finds nothing to read."""
+"""What a metric reader is given: the run's configuration and model
+family, calls, set-up, compile count and (in a traced run) its trace,
+with the arithmetic several readers share. A reader is
+``bench/metrics/<name>.py`` with ``read(ctx)``; it returns ``None`` where
+it finds nothing to read."""
 from __future__ import annotations
 
 import dataclasses
 import re
+import types
 
 from benchlib import spec
 from benchlib import trace as trace_lib
@@ -33,6 +35,7 @@ def padded_width(config: dict) -> int:
 @dataclasses.dataclass
 class Context:
     config: dict
+    family: types.ModuleType    # families/<family>.py of the configuration
     traffic: dict
     cell: dict
     device: dict

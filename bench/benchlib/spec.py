@@ -3,7 +3,10 @@
 ``BENCHMARK.json`` names every cell, configuration and metric; each piece
 lives in a file of its own under ``bench/``, found by that name:
 
-- ``configs/<config>.json``: the configuration's sizes and guarantees;
+- ``configs/<config>.json``: the configuration's sizes and guarantees,
+  with the names of its model ``family`` and plain ``reference``;
+- ``families/<family>.py``: everything of a cell that depends on the
+  model (data, loss and init, evaluation, leaves, FLOPs of a sample);
 - ``workloads/<traffic>.json``: the traffic mix (entry kind, rounds per
   call, callbacks, mobility, faults, variants, warm-up and traced calls);
 - ``limits/<cell>.json``: the limits of the numbers ``correct`` compares;
@@ -12,8 +15,9 @@ lives in a file of its own under ``bench/``, found by that name:
 - ``roofline/<kernel>.py``: a kernel's operations and bytes from shapes;
 - ``reference/<name>.py``: the plain reference a configuration names.
 
-Adding a cell, a configuration, an entry kind or a metric is adding
-files and ``BENCHMARK.json`` entries; nothing here changes.
+Adding a cell, a configuration, a model family, an entry kind or a
+metric is adding files and ``BENCHMARK.json`` entries; nothing here
+changes.
 """
 from __future__ import annotations
 
@@ -88,6 +92,7 @@ def resolve(bench: dict, cell_name: str) -> dict:
         "cell": w, "config": cfg, "traffic": tr,
         "limits": limits(cell_name),
         "entry": module("entries", tr["entry"]),
+        "family": module("families", cfg["family"]),
         "reference": module("reference", cfg["reference"]),
         "metrics": {m["name"]: module("metrics", m["name"])
                     for traced in (False, True)

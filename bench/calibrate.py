@@ -3,13 +3,17 @@
     python3 bench/calibrate.py --workload <cell> --seeds <n> [<n> ...] [--faults <n>]
 
 For every seed, in one process: the program's set-up as a run makes it
-(its first call), then the plain reference over the same calls
-and the numbers the check compares (the lower reading); then the
-control, the reference computed in bfloat16 in the program's place
-(the upper reading); and, for the first ``--faults`` seeds, the
-reference with a fault planted in it (half of each minibatch left out,
-the consensus exchange left out). One JSON line per seed. The benchmark's
-own runs do not run this; it needs the chip the cell asks for.
+(its first call, on the data of the configuration's model family), then
+the plain reference over the same calls and the numbers the check
+compares (the lower reading); then the control, the reference computed
+in bfloat16 in the program's place (the upper reading); and, for the
+first ``--faults`` seeds, the reference with a fault planted in it (half
+of each minibatch left out, the consensus exchange left out). The
+reference, its control and its faults are the shared rounds of
+``reference/cdfl_rounds.py`` with the model the configuration's
+``reference`` binds, so any family is calibrated alike. One JSON line
+per seed. The benchmark's own runs do not run this; it needs the chip
+the cell asks for.
 """
 import time
 
@@ -34,7 +38,7 @@ def readings(parts: dict, seed: int, faults: bool) -> dict:
     run = harness.set_up(parts, seed)
     del run["entry"]
     gc.collect()
-    ref_in = check.reference_inputs(run)
+    ref_in = check.reference_inputs(parts, run)
     calls = 1
     ref = parts["reference"].run(parts["config"], parts["traffic"], ref_in,
                                  calls=calls)

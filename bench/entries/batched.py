@@ -10,19 +10,19 @@ from benchlib import build
 
 
 class Entry:
-    def __init__(self, config: dict, traffic: dict, inputs: dict,
+    def __init__(self, family, config: dict, traffic: dict, inputs: dict,
                  desc: dict):
         from repro.experiment import EvalCallback, SweepAxes
-        exp = build.experiment(config, desc, traffic)
+        exp = build.experiment(family, config, desc, traffic)
         sweep = traffic["variants"]
         seeds = sorted({v["init_seed"] for v in desc["variants"]})
         mobs = [build.mobility_config(v["mobility"])
                 for v in desc["variants"][:len(sweep["mobility"])]]
         self.session = exp.compile_batch(
-            {"x": inputs["x"], "y": inputs["y"]}, inputs["items"],
+            family.batch(inputs), family.items(inputs),
             SweepAxes(seeds=seeds, mobility=mobs))
         self.rounds = traffic["rounds_per_call"]
-        self.callbacks = ([EvalCallback(build.eval_fn(inputs))]
+        self.callbacks = ([EvalCallback(family.eval_fn(inputs))]
                           if traffic.get("eval") else [])
 
     def call(self):
@@ -36,8 +36,10 @@ class Entry:
         """(V, R, K) per-round, per-node training loss of every variant."""
         return np.asarray(result.metrics["loss"])
 
-    def params(self) -> dict:
-        return self.session.states.params
+    def params(self):
+        """(V, K, ...) parameters of every variant: a copy on the host,
+        which a later call's donation cannot touch."""
+        return jax.tree.map(np.array, self.session.states.params)
 
     def first_moment(self) -> jax.Array:
         """Adam's first moment as a (V, K, P) buffer."""

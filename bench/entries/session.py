@@ -10,17 +10,18 @@ from benchlib import build
 
 
 class Entry:
-    def __init__(self, config: dict, traffic: dict, inputs: dict,
+    def __init__(self, family, config: dict, traffic: dict, inputs: dict,
                  desc: dict):
         from repro.experiment import EvalCallback
-        exp = build.experiment(config, desc, traffic)
+        exp = build.experiment(family, config, desc, traffic)
         var = desc["variants"][0]
-        self.session = exp.compile({"x": inputs["x"], "y": inputs["y"]}, inputs["items"],
+        self.session = exp.compile(family.batch(inputs),
+                                   family.items(inputs),
                                    rng=jax.random.PRNGKey(var["init_seed"]),
                                    sample_rng=jax.random.PRNGKey(
                                        var["sample_seed"]))
         self.rounds = traffic["rounds_per_call"]
-        self.callbacks = ([EvalCallback(build.eval_fn(inputs))]
+        self.callbacks = ([EvalCallback(family.eval_fn(inputs))]
                           if traffic.get("eval") else [])
 
     def call(self):
@@ -33,9 +34,11 @@ class Entry:
         """(V=1, R, K) per-round, per-node training loss."""
         return np.asarray(result.metrics["loss"])[None]
 
-    def params(self) -> dict:
-        """Node-stacked parameters with a leading variant axis of 1."""
-        return jax.tree.map(lambda a: a[None], self.session.state.params)
+    def params(self):
+        """Node-stacked parameters with a leading variant axis of 1: a
+        copy on the host, which a later call's donation cannot touch."""
+        return jax.tree.map(lambda a: np.array(a)[None],
+                            self.session.state.params)
 
     def first_moment(self) -> jax.Array:
         """Adam's first moment as a (1, K, P) buffer."""

@@ -38,7 +38,8 @@ def test_listener_hears_record_scalar():
 
 
 def _context(p, calls):
-    return metrics.Context(config=p["config"], traffic=p["traffic"],
+    return metrics.Context(config=p["config"], family=p["family"],
+                           traffic=p["traffic"],
                            cell=p["cell"], device=bench_tiny.CPU,
                            calls=calls, setup_s=0.0, compiles=0)
 

@@ -7,6 +7,7 @@ import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
+import bench_tiny  # noqa: E402
 from benchlib import metrics, spec  # noqa: E402
 from benchlib import trace as tr  # noqa: E402
 
@@ -35,17 +36,17 @@ def test_sparse_mix_counts():
 
 def test_padded_width_and_paper_mlp():
     cfg = spec.config("paper-mlp-k4-dense")
-    m = cfg["model"]
-    assert cfg["params"] == (m["input_dim"] * m["hidden"] + m["hidden"]
-                             + m["hidden"] * m["num_classes"]
-                             + m["num_classes"]) == 23860
+    family = spec.module("families", cfg["family"])
+    assert sum(n for _, n in family.leaves(cfg)) == cfg["params"] == 23860
+    assert family.flops_per_sample(cfg) == 6 * 23860
     assert metrics.padded_width(cfg) == 23936
 
 
 def _ctx(cell, calls, trace=None):
     p = spec.resolve(spec.benchmark(), cell)
     return metrics.Context(
-        config=p["config"], traffic=p["traffic"], cell=p["cell"],
+        config=p["config"], family=p["family"], traffic=p["traffic"],
+        cell=p["cell"],
         device={"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
         calls=calls, setup_s=2.5, compiles=0, trace=trace)
 
@@ -73,6 +74,26 @@ def test_mfu_and_roofline_by_hand():
     least = (8 * 1024 * p + 4 * 1024 * 8 * p + 8 * 1024 * 8) / 819e9
     assert spec.module("metrics", "sparse_mix_roofline").read(ctx) == \
         pytest.approx(100 * least * 10 / 2e-3)
+
+
+@pytest.mark.parametrize("family", bench_tiny.FAMILIES)
+def test_mfu_counts_samples_by_the_family(family):
+    """``mfu`` is the samples of the traced calls x the family's FLOPs of
+    one sample over the window x the bf16 peak: an image in the MLP, a
+    sequence of tokens in the token family."""
+    p = bench_tiny.parts("paper.study60", family)
+    trace = tr.Trace(device={"/device:TPU:0": [("fusion", 0, 4_000_000)]},
+                     spans=[("bench.call", 0, 10_000_000)])
+    ctx = metrics.Context(
+        config=p["config"], family=p["family"], traffic=p["traffic"],
+        cell=p["cell"],
+        device={"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+        calls=[(0.0, 0.01)], setup_s=1.0, compiles=0, trace=trace)
+    samples = 4 * 2 * 32 * 3       # nodes x local steps x batch x rounds
+    per_sample = {"mlp": 6 * (64 * 8 + 8 + 8 * 10 + 10),
+                  "tiny_tokens": 6 * 16 * 32 * 8}[family]
+    assert spec.module("metrics", "mfu").read(ctx) == \
+        pytest.approx(100 * samples * per_sample / (0.01 * 197e12))
 
 
 def test_unknown_device_kind_is_an_error():

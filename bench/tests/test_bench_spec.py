@@ -1,6 +1,7 @@
-"""Every cell of ``BENCHMARK.json`` finds its configuration, traffic,
-limits, entry kind, reference and metric readers by name, and the file
-keeps the benchmark contract's shape."""
+"""Every cell of ``BENCHMARK.json`` finds its configuration, model
+family, traffic, limits, entry kind, reference and metric readers by
+name, the file keeps the benchmark contract's shape, and nothing of a
+model is named outside its family and its reference."""
 import json
 import pathlib
 import re
@@ -16,6 +17,8 @@ BENCH = spec.benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 CELLS = [w["name"] for w in BENCH["workloads"]]
+FAMILY_API = ("fleet", "model", "batch", "items", "eval_fn", "leaves",
+              "flops_per_sample", "reference_inputs", "tiny")
 
 
 def test_top_level_shape():
@@ -71,6 +74,12 @@ def test_cell_resolves_by_name(cell):
     parts = spec.resolve(BENCH, cell)
     assert callable(parts["entry"].Entry)
     assert callable(parts["reference"].run)
+    for name in FAMILY_API:
+        assert callable(getattr(parts["family"], name)), name
+    cfg = parts["config"]
+    leaves = parts["family"].leaves(cfg)
+    assert tuple(n for n, _ in leaves) == parts["reference"].LEAVES
+    assert sum(n for _, n in leaves) == cfg["params"]
     assert set(parts["limits"]) == {"loss", "moment", "change"}
     for name, mod in parts["metrics"].items():
         assert callable(mod.read), name
@@ -92,3 +101,16 @@ def test_config_files_are_the_configs():
         assert cfg["name"] == c["name"]
         assert cfg["reduced"] == c["reduced"]
         assert (spec.BENCH / "reference" / f"{cfg['reference']}.py").exists()
+        assert (spec.BENCH / "families" / f"{cfg['family']}.py").exists()
+
+
+def test_harness_names_no_model():
+    """The harness, the entries and the readers reach a model only
+    through its family, so a configuration of another family is added by
+    files alone."""
+    model_names = re.compile(r"input_dim|num_classes|MLPConfig|make_mlp_loss"
+                             r"|mlp_forward|leaf_sizes")
+    for kind in ("benchlib", "entries", "metrics"):
+        for path in sorted((spec.BENCH / kind).glob("*.py")):
+            found = model_names.findall(path.read_text())
+            assert not found, (path.name, found)

@@ -57,7 +57,8 @@ def test_idle_named_by_innermost_span():
 def _context(trace):
     p = spec.resolve(spec.benchmark(), "paper.study60")
     return metrics.Context(
-        config=p["config"], traffic=p["traffic"], cell=p["cell"],
+        config=p["config"], family=p["family"], traffic=p["traffic"],
+        cell=p["cell"],
         device={"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
         calls=[(0.0, 0.03), (0.035, 0.05)], setup_s=1.0, compiles=0,
         trace=trace)
