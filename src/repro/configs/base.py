@@ -12,6 +12,34 @@ from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
+class RopeConfig:
+    """Rotary embedding of one attention kind: ``kind="default"`` rotates
+    by ``theta ** (-2i / head_dim)``; ``kind="yarn"`` blends those
+    frequencies with their ``factor``-interpolated ones over the dims
+    between ``beta_fast`` and ``beta_slow`` rotations within
+    ``original_max_positions``, and scales cos and sin by
+    ``attention_factor`` (arXiv:2309.00071; HF ``_compute_yarn_parameters``)."""
+
+    theta: float = 10_000.0
+    kind: str = "default"            # default | yarn
+    factor: float = 1.0
+    original_max_positions: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
+class AttentionKind:
+    """One kind of attention layer: its window (keys with
+    ``q - window < k <= q``; None: causal over the whole sequence) and
+    its rotary embedding."""
+
+    window: Optional[int] = None
+    rope: RopeConfig = RopeConfig()
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters (one instance per assigned arch)."""
 
@@ -38,6 +66,19 @@ class ModelConfig:
     ssm_heads: int = 0               # rwkv / mamba head count (0 -> num_heads)
     # per-layer block kinds; empty -> homogeneous from family
     block_pattern: Tuple[str, ...] = ()    # entries: attn|mamba|rwkv|shared_attn
+    #                                        or a name in attention_kinds
+    # named attention kinds a block_pattern entry may give, each with its
+    # own window and rotary embedding (sliding and full layers in one stack)
+    attention_kinds: Tuple[Tuple[str, AttentionKind], ...] = ()
+    # --- this chip's share of a deployment (0: the whole layer) -------------
+    # experts_held > 0 selects the dropless expert layer: the router scores
+    # all num_experts, and only the routes to experts
+    # [expert_offset, expert_offset + experts_held) are computed here
+    experts_held: int = 0
+    expert_offset: int = 0
+    heads_held: int = 0              # query heads held (0: all of them)
+    vocab_held: int = 0              # vocabulary rows [vocab_offset, +held)
+    vocab_offset: int = 0
     # --- modality frontends (stubs per spec) --------------------------------
     modality: str = "text"           # text | vision | audio
     num_patches: int = 1024          # vlm: patch embeddings per image
@@ -46,12 +87,39 @@ class ModelConfig:
     act: str = "swiglu"              # swiglu | gelu
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
+    # precision of the forward's matmuls (and so of their gradients), a
+    # name jax.default_matmul_precision takes; "" keeps the backend's
+    matmul_precision: str = ""
     source: str = ""                 # citation
 
     def resolved_head_dim(self) -> int:
         if self.head_dim:
             return self.head_dim
         return self.d_model // max(self.num_heads, 1)
+
+    def held_heads(self) -> int:
+        return self.heads_held or self.num_heads
+
+    def held_kv_heads(self) -> int:
+        """Key/value heads that serve the held query heads."""
+        if not self.heads_held:
+            return self.num_kv_heads
+        return max(1, self.heads_held * self.num_kv_heads // self.num_heads)
+
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    def held_vocab(self) -> int:
+        return self.vocab_held or self.vocab_size
+
+    def attention_kind(self, kind: str) -> Optional[AttentionKind]:
+        """The named attention kind of a block, or None for a block kind
+        that is not one."""
+        return dict(self.attention_kinds).get(kind)
+
+    def is_attention(self, kind: str) -> bool:
+        return (kind in ("attn", "shared_attn")
+                or self.attention_kind(kind) is not None)
 
     def blocks(self) -> Tuple[str, ...]:
         if self.block_pattern:
@@ -62,16 +130,17 @@ class ModelConfig:
 
     def param_count(self) -> int:
         """Analytic parameter count (used for roofline MODEL_FLOPS)."""
-        d, f, v = self.d_model, self.d_ff, self.vocab_size
+        d, f, v = self.d_model, self.d_ff, self.held_vocab()
         hd = self.resolved_head_dim()
         total = v * d                                   # embed
         if not self.tie_embeddings:
             total += v * d                              # lm head
+        total += d                                      # final norm
         for kind in self.blocks():
-            if kind in ("attn", "shared_attn"):
-                q = d * self.num_heads * hd
-                kv = 2 * d * self.num_kv_heads * hd
-                o = self.num_heads * hd * d
+            if self.is_attention(kind):
+                q = d * self.held_heads() * hd
+                kv = 2 * d * self.held_kv_heads() * hd
+                o = self.held_heads() * hd * d
                 total += q + kv + o
             elif kind == "rwkv":
                 # r,k,v,g,o projections + data-dependent decay lora
@@ -82,7 +151,7 @@ class ModelConfig:
                 total += d_inner * (2 * self.ssm_state)      # B,C
                 total += d_inner                              # dt, A diag
             if self.num_experts:
-                total += self.num_experts * 3 * d * f       # swiglu experts
+                total += self.held_experts() * 3 * d * f    # swiglu experts
                 total += d * self.num_experts               # router
             else:
                 mult = 3 if self.act == "swiglu" else 2
@@ -95,7 +164,7 @@ class ModelConfig:
         if not self.num_experts:
             return self.param_count()
         d, f = self.d_model, self.d_ff
-        dense_experts = self.num_layers * self.num_experts * 3 * d * f
+        dense_experts = self.num_layers * self.held_experts() * 3 * d * f
         active_experts = self.num_layers * self.experts_per_token * 3 * d * f
         return self.param_count() - dense_experts + active_experts
 
@@ -456,17 +525,48 @@ class RunConfig:
         return dataclasses.replace(self, **kw)
 
 
+def chip_share(model: ModelConfig, chips: int, chip: int,
+               layers: int) -> ModelConfig:
+    """What chip ``chip`` of a deployment that divides every layer over
+    ``chips`` chips holds of one pipeline stage of ``layers`` layers (the
+    rest lie on further stages): its ``1/chips`` of the experts (the
+    router still scores all of them), of the query heads with their
+    key/value heads, and of the vocabulary rows. No width changes."""
+    experts = model.num_experts // chips
+    heads = model.num_heads // chips
+    vocab = model.vocab_size // chips
+    return dataclasses.replace(
+        model, name=f"{model.name}-ep{chips}-chip{chip}",
+        num_layers=layers, block_pattern=model.blocks()[:layers],
+        experts_held=experts, expert_offset=chip * experts,
+        heads_held=heads,
+        vocab_held=vocab, vocab_offset=chip * vocab)
+
+
 def reduced(model: ModelConfig, *, layers: int = 2, d_model: int = 256,
             d_ff: int = 512, vocab: int = 512, experts: int = 0) -> ModelConfig:
     """Reduced same-family variant for CPU smoke tests (spec: 2 layers,
-    d_model<=512, <=4 experts)."""
+    d_model<=512, <=4 experts). A model with named attention kinds keeps
+    one whole period of its layer pattern (every kind in its published
+    ratio) whatever ``layers`` says, and a model that holds a share of
+    its experts keeps holding the same fraction of the fewer experts."""
     heads = max(1, min(model.num_heads, d_model // 64)) if model.num_heads else 0
     kv = max(1, min(model.num_kv_heads, heads)) if heads else 0
     n_exp = min(model.num_experts, experts or 4) if model.num_experts else 0
     top_k = min(model.experts_per_token, n_exp) if n_exp else 0
+    held = 0
+    if model.experts_held:
+        held = max(1, n_exp * model.experts_held // model.num_experts)
     pattern = ()
     if model.block_pattern:
         pattern = model.block_pattern[:layers]
+        if model.attention_kinds:
+            layers = _period(model.block_pattern)
+            pattern = model.block_pattern[:layers]
+    kinds = tuple(
+        (name, dataclasses.replace(
+            k, window=None if k.window is None else min(k.window, 128)))
+        for name, k in model.attention_kinds)
     return dataclasses.replace(
         model,
         name=model.name + "-smoke",
@@ -479,9 +579,24 @@ def reduced(model: ModelConfig, *, layers: int = 2, d_model: int = 256,
         vocab_size=vocab,
         num_experts=n_exp,
         experts_per_token=top_k,
+        experts_held=held,
+        expert_offset=0,
+        heads_held=0,
+        vocab_held=0,
+        vocab_offset=0,
         ssm_state=min(model.ssm_state, 16) if model.ssm_state else 0,
         block_pattern=pattern,
+        attention_kinds=kinds,
         sliding_window=min(model.sliding_window, 128) if model.sliding_window else None,
         num_patches=16,
         dtype="float32",
     )
+
+
+def _period(pattern: Tuple[str, ...]) -> int:
+    """Length of the shortest prefix whose repetition is ``pattern``."""
+    n = len(pattern)
+    for p in range(1, n + 1):
+        if n % p == 0 and pattern == pattern[:p] * (n // p):
+            return p
+    return n

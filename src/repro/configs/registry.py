@@ -4,6 +4,7 @@ from __future__ import annotations
 from repro.configs import (
     granite_8b, granite_3_8b, rwkv6_7b, mixtral_8x7b, internvl2_26b,
     zamba2_1_2b, qwen3_1_7b, codeqwen15_7b, dbrx_132b, musicgen_medium,
+    mellum2_12b_a2_5b,
 )
 from repro.configs.base import ModelConfig, reduced
 
@@ -18,6 +19,7 @@ ARCHS: dict[str, ModelConfig] = {
     "codeqwen1.5-7b": codeqwen15_7b.CONFIG,
     "dbrx-132b": dbrx_132b.CONFIG,
     "musicgen-medium": musicgen_medium.CONFIG,
+    "mellum2-12b-a2.5b": mellum2_12b_a2_5b.CONFIG,
 }
 
 

@@ -7,9 +7,12 @@ One federated **round** =
   3. ``local_steps`` Adam updates on local minibatches (eq. 8, ModelUpdate).
 
 The trainer is generic over the model: it takes ``loss_fn(params, batch)``
-and a per-node initializer. Node-stacked pytrees (leading K dim) make the
-same code run vmapped on one host (simulation / tests / paper repro) or
-under shard_map on a mesh (see repro.launch.train).
+and a per-node initializer. A loss returns a scalar, or ``(loss, aux)``
+with ``aux`` a dict of counters that the scan sums over local steps and
+nodes into each round's ``metrics["counters"]``. Node-stacked pytrees
+(leading K dim) make the same code run vmapped on one host (simulation /
+tests / paper repro) or under shard_map on a mesh (see
+repro.launch.train).
 
 Two drivers:
   * ``Trainer.round`` — one jit'd round on host-fed batches (seed path);
@@ -91,6 +94,11 @@ from repro.ingest import weighting as ingest_weighting
 from repro.optim import FlatAdamState, adam, flat_adam
 
 
+# fleets whose parameters (all nodes) take more bytes than this run their
+# local steps without unrolling (see ``build_trainer``)
+UNROLL_BYTES = 256 * 2 ** 20
+
+
 class FedState(NamedTuple):
     params: object            # pytree, leaves (K, ...)
     opt: object               # FlatAdamState with (K, P) moment buffers
@@ -151,8 +159,11 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                   eval_fn: Optional[Callable] = None,
                   transport: Any = None,
                   flat_local: Optional[bool] = None) -> Trainer:
-    """loss_fn(params, batch) -> scalar loss. batch leaves have no K dim
-    (the trainer vmaps over nodes).
+    """loss_fn(params, batch) -> scalar loss, or (loss, aux) with aux a
+    dict of counters (summed over local steps and nodes into each round's
+    ``metrics["counters"]``; a scalar loss traces the same program as
+    ``(loss, {})``). batch leaves have no K dim (the trainer vmaps over
+    nodes).
 
     The non-deprecated trainer constructor — what the algorithm plugins
     (``repro.core.baselines``) and the ``repro.experiment`` façade call.
@@ -300,8 +311,17 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
     # Partially unrolling the local-step scan lets XLA build larger fusion
     # clusters (fewer per-op dispatches) without decode-time blowup;
     # unroll 4 measures ~10% over unroll 2 on the flat-resident loop
-    # (the slice-view/grad-pack ops of adjacent steps fuse).
+    # (the slice-view/grad-pack ops of adjacent steps fuse). A fleet
+    # whose parameters pass UNROLL_BYTES steps one at a time: its steps
+    # are long, and each unrolled step keeps parameter-sized gradients
+    # and packs alive (3.8 GB more at K = 2 x 267M parameters, compiled
+    # for a v5e).
     local_unroll = max(1, min(4, fed.local_steps))
+
+    def unroll_for(params) -> int:
+        size = sum(leaf.size * leaf.dtype.itemsize
+                   for leaf in jax.tree.leaves(params))
+        return local_unroll if size <= UNROLL_BYTES else 1
 
     def eta_fn(state: FedState) -> jax.Array:
         return topology.mixing_weights(adj, mix_rule,
@@ -341,6 +361,12 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         return FedState(params, opt_state, ratios, sizes,
                         jnp.zeros((), jnp.int32), tstate, fstate, istate)
 
+    def _loss_aux(p, batch):
+        out = loss_fn(p, batch)
+        return out if isinstance(out, tuple) else (out, {})
+
+    value_and_grad = jax.value_and_grad(_loss_aux, has_aux=True)
+
     # ``lr=None`` throughout the step machinery keeps the TrainConfig
     # rate baked in at trace time (the single-run path — bit-identical
     # to previous builds); a traced scalar overrides it at runtime so
@@ -353,33 +379,35 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         flat-Adam pass updates vector and moments in place."""
         with jax.named_scope("cdfl.grad"):
             p = flatten.unflatten_one(vec, layout)
-            loss, grads = jax.value_and_grad(loss_fn)(p, batch)
+            (loss, aux), grads = value_and_grad(p, batch)
             gvec = flatten.pack_node(grads, layout)
         with jax.named_scope("cdfl.adam"):
             vec, ost = fopt.update(gvec, ost, vec, lr=lr)
-        return vec, ost, loss
+        return vec, ost, loss, aux
 
     def _leaf_local_step(p, o, batch, lr=None):
         """One leaf-space local Adam step (pytree params/moments)."""
         with jax.named_scope("cdfl.grad"):
-            loss, grads = jax.value_and_grad(loss_fn)(p, batch)
+            (loss, aux), grads = value_and_grad(p, batch)
         with jax.named_scope("cdfl.adam"):
             p, o = opt.update(grads, o, p, lr=lr)
-        return p, o, loss
+        return p, o, loss, aux
 
     # ONE loop scaffold serves both representations and both batch
-    # sources: step3(params_repr, opt_repr, batch) -> (..., loss).
+    # sources: step3(params_repr, opt_repr, batch) -> (..., loss, aux);
+    # the loop returns each node's mean loss and its aux summed over steps.
 
     def _run_local_steps(step3, p0, o0, batches):
         """vmap over nodes of a scan over local steps.
         batches: pytree, leaves (K, S, B, ...)."""
         def one_node(p, o, bs):
             def step(carry, batch):
-                p, o, loss = step3(*carry, batch)
-                return (p, o), loss
-            (p, o), losses = jax.lax.scan(step, (p, o), bs,
-                                          unroll=local_unroll)
-            return p, o, losses.mean()
+                p, o, loss, aux = step3(*carry, batch)
+                return (p, o), (loss, aux)
+            (p, o), (losses, aux) = jax.lax.scan(step, (p, o), bs,
+                                                 unroll=unroll_for(p0))
+            return p, o, losses.mean(), jax.tree.map(
+                lambda a: a.sum(0), aux)
         return jax.vmap(one_node)(p0, o0, batches)
 
     def _run_local_steps_from_idx(step3, p0, o0, data, idx):
@@ -390,11 +418,12 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         def one_node(p, o, nd, ni):
             def step(carry, i):
                 batch = jax.tree.map(lambda a: a[i], nd)
-                p, o, loss = step3(*carry, batch)
-                return (p, o), loss
-            (p, o), losses = jax.lax.scan(step, (p, o), ni,
-                                          unroll=local_unroll)
-            return p, o, losses.mean()
+                p, o, loss, aux = step3(*carry, batch)
+                return (p, o), (loss, aux)
+            (p, o), (losses, aux) = jax.lax.scan(step, (p, o), ni,
+                                                 unroll=unroll_for(p0))
+            return p, o, losses.mean(), jax.tree.map(
+                lambda a: a.sum(0), aux)
         return jax.vmap(one_node)(p0, o0, data, idx)
 
     def flat_local_updates(buf, opt_state, layout, batches):
@@ -463,12 +492,12 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
             p, o, loss = step_all(*carry, x)
             return (p, o), loss
         (p, o), losses = jax.lax.scan(step, (p0, o0), xs,
-                                      unroll=local_unroll)
+                                      unroll=unroll_for(p0))
         return p, o, losses.mean() * jnp.ones((fed.num_nodes,))
 
     def _dpsgd_flat_step(buf, ost, batch, eta, gamma, layout, lr=None):
         buf = _dpsgd_mix(buf, eta, gamma)
-        buf, ost, losses = jax.vmap(
+        buf, ost, losses, _ = jax.vmap(
             lambda v, o, b: _flat_local_step(v, o, b, layout, lr=lr)
         )(buf, ost, batch)
         return buf, ost, losses.mean()
@@ -478,7 +507,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
             flat = leaf.reshape(leaf.shape[0], -1)
             return _dpsgd_mix(flat, eta, gamma).reshape(leaf.shape)
         p = jax.tree.map(mix_leaf, p)
-        losses, grads = jax.vmap(jax.value_and_grad(loss_fn))(p, batch)
+        (losses, _), grads = jax.vmap(value_and_grad)(p, batch)
         p, o = jax.vmap(lambda g, o_, p_: opt.update(g, o_, p_, lr=lr)
                         )(grads, o, p)
         return p, o, losses.mean()
@@ -571,15 +600,18 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         # cdfl, cfa, metropolis — eq. (5)
         return transport.exchange(buf, eta, gamma, tstate, rnd, sent=sent)
 
-    def _flat_metrics(buf, layout, loss, gamma):
+    def _flat_metrics(buf, layout, loss, gamma, aux=None):
         """Round metrics straight off the resident buffer — the
         disagreement is one pass over (K, P), and eval reads the params
-        through slice views (no materialized unpack)."""
+        through slice views (no materialized unpack). The loss's
+        counters (``aux``, per node) are summed over the nodes."""
         metrics = {
             "loss": loss,
             "disagreement": flatten.disagreement_flat(buf, layout.total),
             "gamma": gamma,
         }
+        if aux:
+            metrics["counters"] = jax.tree.map(lambda a: a.sum(0), aux)
         if eval_fn is not None:
             metrics["eval"] = jax.vmap(eval_fn)(
                 flatten.unflatten_views(buf, layout))
@@ -599,6 +631,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         # unpack into the returned FedState
         layout = flatten.make_layout(state.params)
         buf, _ = flatten.flatten(state.params, layout)
+        aux = {}
         if fed.algorithm == "dpsgd":
             tstate = state.tstate
             o0 = (state.opt if flat_local
@@ -610,15 +643,15 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
             mixed, tstate = mix_buf(buf, state.sizes, eta, gamma, layout,
                                     state.tstate, state.round)
             if flat_local:
-                buf, opt_state, loss = flat_local_updates(
+                buf, opt_state, loss, aux = flat_local_updates(
                     mixed, state.opt, layout, batches)
             else:
-                params, o, loss = leaf_local_updates(
+                params, o, loss, aux = leaf_local_updates(
                     flatten.unflatten(mixed, layout),
                     _leaf_opt_state(state.opt, layout), batches)
                 buf = flatten.flatten(params, layout)[0]
                 opt_state = _flat_opt_state(o, layout)
-        metrics = _flat_metrics(buf, layout, loss, gamma)
+        metrics = _flat_metrics(buf, layout, loss, gamma, aux)
         new_state = FedState(flatten.unflatten(buf, layout), opt_state,
                              state.ratios, state.sizes,
                              state.round + 1, tstate, state.fstate,
@@ -878,6 +911,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                     # anything mixes
                     sent, eta_r, quarantined = faults_lib.wire_guard(
                         sent, buf, eta_r, fed.faults.guard_threshold)
+            aux = {}
             if fed.algorithm == "dpsgd":
                 # no once-per-round exchange: the gossip runs INSIDE the
                 # step loop (dpsgd is fault-incapable, so sent is None),
@@ -893,16 +927,18 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                                             sent=sent)
                 with jax.named_scope("cdfl.local"):
                     if flat_local:
-                        buf, opt_state, loss = flat_local_updates_from_idx(
-                            mixed, opt_state, layout, data, idx_r, lr=lr)
+                        buf, opt_state, loss, aux = (
+                            flat_local_updates_from_idx(
+                                mixed, opt_state, layout, data, idx_r,
+                                lr=lr))
                     else:
-                        params, opt_state, loss = (
+                        params, opt_state, loss, aux = (
                             leaf_local_updates_from_idx(
                                 flatten.unflatten(mixed, layout),
                                 opt_state, data, idx_r, lr=lr))
                         buf = flatten.flatten(params, layout)[0]
             with jax.named_scope("cdfl.metrics"):
-                metrics = _flat_metrics(buf, layout, loss, gamma_r)
+                metrics = _flat_metrics(buf, layout, loss, gamma_r, aux)
                 if hier_fmt:
                     # intra-tier telemetry: the gamma metric already
                     # carries the inter-tier step, this one shows what

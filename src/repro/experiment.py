@@ -49,6 +49,7 @@ from typing import Any, Callable, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import monitoring
 from jax.profiler import TraceAnnotation, annotate_function
 
 from repro import registry
@@ -449,8 +450,8 @@ class Experiment:
         group = self.train.batch_size * seq
 
         def loss_fn(params, batch):
-            return transformer.loss_fn(params, cfg, batch,
-                                       group_size=group)
+            return transformer.loss_and_aux(params, cfg, batch,
+                                            group_size=group)
 
         return loss_fn, (lambda r: transformer.init_params(r, cfg))
 
@@ -599,7 +600,9 @@ class Session:
         ``start_round``, the absolute round it starts at), holding
         ``cdfl.callbacks`` (the hooks), the trainer's ``cdfl.prepare``
         and ``cdfl.dispatch`` per segment, and ``cdfl.wait`` (until the
-        device is done).
+        device is done). A loss that returns counters has each recorded
+        once per run as ``/cdfl/<name>`` (``jax.monitoring``), summed over
+        nodes, local steps and rounds.
         """
         if rounds <= 0:
             raise ValueError(f"rounds must be positive, got {rounds}")
@@ -636,6 +639,10 @@ class Session:
                 lambda *xs: jnp.concatenate(xs, axis=0), *parts))
             with TraceAnnotation("cdfl.wait"):
                 jax.block_until_ready(self._state.params)
+            # the loss's counters, summed over the run's rounds
+            for name, value in metrics.get("counters", {}).items():
+                monitoring.record_scalar(f"/cdfl/{name}",
+                                         float(jnp.sum(value)))
             result = RunResult(state=self._state, metrics=metrics,
                                rounds=rounds,
                                wall_time_s=time.perf_counter() - t0)

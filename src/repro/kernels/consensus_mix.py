@@ -77,12 +77,13 @@ def flat_mix(eta: jax.Array, master: jax.Array, wire: jax.Array,
     or a stale gossip snapshot); a bf16 wire halves the neighbor-read
     bytes while the accumulation stays f32. ``wire_self`` (default
     ``wire``) is each node's own payload for the self-cancellation term:
-    under fault injection the neighbor frames diverge from it.
+    under fault injection the neighbor frames diverge from it. Where
+    ``block_cols`` does not divide P the last slab is partial: its
+    columns past P are neither read into the others nor written.
     """
     k, p = master.shape
     assert eta.shape == (k, k), (eta.shape, k)
     assert wire.shape == (k, p), (wire.shape, master.shape)
-    assert p % block_cols == 0, (p, block_cols)
     scal = jnp.asarray(gamma, jnp.float32).reshape(1, 1)
     slab = pl.BlockSpec((k, block_cols), lambda c: (0, c))
     operands = [scal, eta, master, wire]
@@ -91,7 +92,7 @@ def flat_mix(eta: jax.Array, master: jax.Array, wire: jax.Array,
         operands.append(wire_self)
     return pl.pallas_call(
         _flat_mix_kernel,
-        grid=(p // block_cols,),
+        grid=(pl.cdiv(p, block_cols),),
         in_specs=[
             pl.BlockSpec((1, 1), lambda c: (0, 0)),           # gamma
             pl.BlockSpec((k, k), lambda c: (0, 0)),           # eta
@@ -113,13 +114,14 @@ def flat_consensus(matrix: jax.Array, buf: jax.Array, *,
     streams a (K, block_cols) slab through VMEM once. A is any linear
     consensus operator (eq. 5 matrix, FedAvg weights, ...).
 
-    matrix: (K, K); buf: (K, P) with P a multiple of ``block_cols``
-    (repro.core.flatten pads P to a 128-lane multiple once, at pack time).
+    matrix: (K, K); buf: (K, P) with P a multiple of 128
+    (repro.core.flatten pads P to a 128-lane multiple once, at pack time);
+    where ``block_cols`` does not divide P the last slab is partial, its
+    columns past P neither read into the others nor written.
     """
     k, p = buf.shape
     assert matrix.shape == (k, k), (matrix.shape, k)
-    assert p % block_cols == 0, (p, block_cols)
-    grid = (p // block_cols,)
+    grid = (pl.cdiv(p, block_cols),)
     return pl.pallas_call(
         _flat_kernel,
         grid=grid,

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.kernels import consensus_mix as _cm
 from repro.kernels import cnd_sketch as _cs
+from repro.kernels import expert_gmm as _eg
 from repro.kernels import flash_attention as _fa
 from repro.kernels import robust_agg as _ra
 from repro.kernels import rwkv6_scan as _rs
@@ -49,6 +50,14 @@ def pallas_kernels(hlo_text: str) -> set:
     (``jax.jit(f).lower(...).compile().as_text()``): what shows that a
     kernel, and not an XLA fallback, runs."""
     return set(_KERNEL_CALL.findall(hlo_text))
+
+
+def grouped_matmul(lhs, rhs, sizes, precision=None):
+    """Grouped matmul over held experts (``kernels.expert_gmm``): the
+    Pallas kernels on the TPU, else the same arithmetic in XLA."""
+    if use_pallas():
+        return _eg.grouped_matmul(lhs, rhs, sizes, precision=precision)
+    return _eg.grouped_matmul_xla(lhs, rhs, sizes, precision)
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k"))
@@ -96,14 +105,30 @@ def consensus_mix(w, neighbors, eta, gamma, block_rows: int = 256,
     return ref.consensus_mix(w, neighbors, eta, gamma)
 
 
+# a flat mix whose grid would take more steps than this takes wider slabs
+_MIX_STEPS = 4096
+_MIX_COLS = 32768
+
+
+def _mix_cols(p: int) -> int:
+    """Columns of one slab of a flat (K, P) mix: 512, or 128 where P is
+    no multiple of 512, as tuned on the paper MLP's P = 23,936; a buffer
+    that would take more than ``_MIX_STEPS`` such steps takes
+    ``_MIX_COLS``, whatever P's factors (the kernel masks the last slab),
+    since every grid step costs a fixed overhead (P = 267,211,008 took
+    488 ms a mix on a v5e in 128-column slabs, 19.8 ms in these)."""
+    cols = 512 if p % 512 == 0 else 128
+    return cols if p // cols <= _MIX_STEPS else _MIX_COLS
+
+
 @partial(jax.jit, static_argnames=("force_kernel",))
 def flat_consensus(matrix, buf, force_kernel: bool = False):
     """A @ BUF over the flat (K, P) parameter buffer in one kernel launch
     (P is already lane-padded by repro.core.flatten); XLA matmul off
     TPU."""
     if use_pallas() or force_kernel:
-        block_cols = 512 if buf.shape[1] % 512 == 0 else 128
-        return _cm.flat_consensus(matrix, buf, block_cols=block_cols,
+        return _cm.flat_consensus(matrix, buf,
+                                  block_cols=_mix_cols(buf.shape[1]),
                                   interpret=_interpret())
     from repro.core import flatten
     return flatten.matmul_nodes(matrix, buf)
@@ -119,9 +144,9 @@ def flat_mix(eta, master, wire, gamma, wire_self=None,
     payload; accumulation is always f32. Off TPU this is the equivalent
     XLA delta form, not the interpreted kernel."""
     if use_pallas() or force_kernel:
-        block_cols = 512 if master.shape[1] % 512 == 0 else 128
         return _cm.flat_mix(eta, master, wire, gamma, wire_self,
-                            block_cols=block_cols, interpret=_interpret())
+                            block_cols=_mix_cols(master.shape[1]),
+                            interpret=_interpret())
     # one source of truth for the XLA delta form: flatten.mix_flat
     from repro.core import flatten
     return flatten.mix_flat(master, eta, gamma, use_kernel=False,

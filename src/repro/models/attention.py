@@ -23,17 +23,17 @@ class KVCache(NamedTuple):
 
 
 def init(rng, cfg: ModelConfig, dtype=jnp.float32):
+    """The held heads' projections: ``wq``/``wk``/``wv`` columns and
+    ``wo`` rows of this chip's query heads and their key/value heads (all
+    of them unless the config holds a share)."""
     hd = cfg.resolved_head_dim()
+    h, kv = cfg.held_heads(), cfg.held_kv_heads()
     r = jax.random.split(rng, 4)
     p = {
-        "wq": layers._dense_init(r[0], (cfg.d_model, cfg.num_heads * hd),
-                                 dtype=dtype),
-        "wk": layers._dense_init(r[1], (cfg.d_model, cfg.num_kv_heads * hd),
-                                 dtype=dtype),
-        "wv": layers._dense_init(r[2], (cfg.d_model, cfg.num_kv_heads * hd),
-                                 dtype=dtype),
-        "wo": layers._dense_init(r[3], (cfg.num_heads * hd, cfg.d_model),
-                                 dtype=dtype),
+        "wq": layers._dense_init(r[0], (cfg.d_model, h * hd), dtype=dtype),
+        "wk": layers._dense_init(r[1], (cfg.d_model, kv * hd), dtype=dtype),
+        "wv": layers._dense_init(r[2], (cfg.d_model, kv * hd), dtype=dtype),
+        "wo": layers._dense_init(r[3], (h * hd, cfg.d_model), dtype=dtype),
     }
     if cfg.qk_norm:
         p["q_norm"] = layers.rmsnorm_init(hd, dtype)
@@ -41,18 +41,26 @@ def init(rng, cfg: ModelConfig, dtype=jnp.float32):
     return p
 
 
-def _project_qkv(params, cfg: ModelConfig, x, positions):
+def _project_qkv(params, cfg: ModelConfig, x, positions, kind=None):
     b, s, _ = x.shape
     hd = cfg.resolved_head_dim()
-    q = (x @ params["wq"]).reshape(b, s, cfg.num_heads, hd)
-    k = (x @ params["wk"]).reshape(b, s, cfg.num_kv_heads, hd)
-    v = (x @ params["wv"]).reshape(b, s, cfg.num_kv_heads, hd)
+    q = (x @ params["wq"]).reshape(b, s, cfg.held_heads(), hd)
+    k = (x @ params["wk"]).reshape(b, s, cfg.held_kv_heads(), hd)
+    v = (x @ params["wv"]).reshape(b, s, cfg.held_kv_heads(), hd)
     if cfg.qk_norm:
         q = layers.rmsnorm(params["q_norm"], q)
         k = layers.rmsnorm(params["k_norm"], k)
-    q = layers.apply_rope(q, positions, cfg.rope_theta)
-    k = layers.apply_rope(k, positions, cfg.rope_theta)
+    freqs, scale = (None, 1.0) if kind is None else layers.rope_table(
+        hd, kind.rope)
+    q = layers.apply_rope(q, positions, cfg.rope_theta, freqs, scale)
+    k = layers.apply_rope(k, positions, cfg.rope_theta, freqs, scale)
     return q, k, v
+
+
+def _window(cfg: ModelConfig, kind, window_override):
+    if window_override is not None:
+        return window_override
+    return cfg.sliding_window if kind is None else kind.window
 
 
 def attend(q, k, v, *, causal: bool, window: Optional[int],
@@ -94,16 +102,19 @@ def attend(q, k, v, *, causal: bool, window: Optional[int],
 
 
 def forward(params, cfg: ModelConfig, x, positions=None,
-            window_override: Optional[int] = None):
-    """Training / prefill self-attention over the full sequence."""
+            window_override: Optional[int] = None, kind=None):
+    """Training / prefill self-attention over the full sequence, with the
+    window and rotary embedding of ``kind`` (a ``configs.base.
+    AttentionKind``; None: the model-wide ``sliding_window`` and
+    ``rope_theta``). Over held heads this is the chip's partial output:
+    its heads' rows of ``wo``, the other chips' heads left out."""
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    q, k, v = _project_qkv(params, cfg, x, positions)
-    window = window_override if window_override is not None \
-        else cfg.sliding_window
-    out = attend(q, k, v, causal=True, window=window)
-    return out.reshape(b, s, -1) @ params["wo"]
+    q, k, v = _project_qkv(params, cfg, x, positions, kind)
+    out = attend(q, k, v, causal=True,
+                 window=_window(cfg, kind, window_override))
+    return out.reshape(b, s, params["wo"].shape[0]) @ params["wo"]
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
@@ -111,13 +122,13 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     """window: cap the cache to the sliding window (ring buffer)."""
     eff = min(max_len, window) if window else max_len
     hd = cfg.resolved_head_dim()
-    shape = (batch, eff, cfg.num_kv_heads, hd)
+    shape = (batch, eff, cfg.held_kv_heads(), hd)
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
                    length=jnp.zeros((), jnp.int32))
 
 
 def decode_step(params, cfg: ModelConfig, x, cache: KVCache,
-                window_override: Optional[int] = None):
+                window_override: Optional[int] = None, kind=None):
     """One-token decode: x (B, 1, d_model); returns (out, new_cache).
 
     The cache is a ring buffer of size S_cache; with a sliding window the
@@ -126,12 +137,11 @@ def decode_step(params, cfg: ModelConfig, x, cache: KVCache,
     b = x.shape[0]
     s_cache = cache.k.shape[1]
     pos = jnp.broadcast_to(cache.length, (b, 1))
-    q, k, v = _project_qkv(params, cfg, x, pos)
+    q, k, v = _project_qkv(params, cfg, x, pos, kind)
     slot = cache.length % s_cache
     new_k = jax.lax.dynamic_update_slice_in_dim(cache.k, k, slot, axis=1)
     new_v = jax.lax.dynamic_update_slice_in_dim(cache.v, v, slot, axis=1)
-    window = window_override if window_override is not None \
-        else cfg.sliding_window
+    window = _window(cfg, kind, window_override)
 
     # attention over the valid region of the ring buffer.
     # Grouped-query einsums (NO kv repeat): the cache is usually
@@ -140,8 +150,8 @@ def decode_step(params, cfg: ModelConfig, x, cache: KVCache,
     # repeating kv heads made GSPMD all-gather the full 2GB cache per
     # layer (dry-run: 60GB/step on qwen3 decode_32k).
     hd = q.shape[-1]
-    kv = cfg.num_kv_heads
-    groups = cfg.num_heads // kv
+    kv = cfg.held_kv_heads()
+    groups = cfg.held_heads() // kv
     qg = q.reshape(b, 1, kv, groups, hd)
     # bf16 operands, f32 accumulation (MXU-native) — casting the cache to
     # f32 first would double the HBM bytes of the dominant decode read

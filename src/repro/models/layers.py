@@ -6,6 +6,8 @@ may be stored in bf16 (configs) or f32 (tests / paper repro).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -60,13 +62,50 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
                                        dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float):
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+def yarn_frequencies(head_dim: int, rope) -> jax.Array:
+    """YaRN's inverse frequencies (arXiv:2309.00071, as HF
+    ``_compute_yarn_parameters`` computes them): dims that turn more than
+    ``beta_fast`` times within ``original_max_positions`` keep their
+    frequency, dims that turn fewer than ``beta_slow`` times take it over
+    ``factor``, and a linear ramp blends the dims between."""
+    def correction_dim(rotations):
+        return (head_dim * math.log(rope.original_max_positions
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+    low = max(math.floor(correction_dim(rope.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rope.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extrapolated = rope_frequencies(head_dim, rope.theta)
+    interpolated = extrapolated / rope.factor
+    keep = 1.0 - ramp
+    return interpolated * (1.0 - keep) + extrapolated * keep
+
+
+def rope_table(head_dim: int, rope) -> tuple:
+    """(inverse frequencies (D/2,), cos/sin scale) of a ``RopeConfig``."""
+    if rope.kind == "default":
+        return rope_frequencies(head_dim, rope.theta), 1.0
+    if rope.kind == "yarn":
+        return yarn_frequencies(head_dim, rope), rope.attention_factor
+    raise ValueError(f"unknown rope kind {rope.kind!r}")
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: jax.Array | None = None, scale: float = 1.0):
+    """x: (..., S, H, D); positions: broadcastable to (..., S). ``freqs``
+    (D/2,) replaces the default frequencies of ``theta``; ``scale``
+    multiplies cos and sin (YaRN's attention factor)."""
     d = x.shape[-1]
-    freqs = rope_frequencies(d, theta)                      # (D/2,)
+    if freqs is None:
+        freqs = rope_frequencies(d, theta)                  # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     cos = jnp.cos(angles)[..., None, :]                     # (..., S, 1, D/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin,
                            x1 * sin + x2 * cos], axis=-1)

@@ -16,15 +16,22 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from repro.models import layers, pspec
+
+# what the held-expert layer counts (``held_forward``)
+COUNTERS = ("moe/routes", "moe/routes_held")
 
 
 def init(rng, cfg: ModelConfig, dtype=jnp.float32):
-    e, d, f = cfg.num_experts, cfg.d_model, cfg.d_ff
+    """The router over all experts, and the held experts' weights (all
+    of them unless the config holds a share)."""
+    e, d, f = cfg.held_experts(), cfg.d_model, cfg.d_ff
     r = jax.random.split(rng, 4)
     scale = d ** -0.5
     return {
-        "router": layers._dense_init(r[0], (d, e), dtype=jnp.float32),
+        "router": layers._dense_init(r[0], (d, cfg.num_experts),
+                                     dtype=jnp.float32),
         "w_gate": (jax.random.normal(r[1], (e, d, f)) * scale).astype(dtype),
         "w_up": (jax.random.normal(r[2], (e, d, f)) * scale).astype(dtype),
         "w_down": (jax.random.normal(r[3], (e, f, d))
@@ -130,3 +137,66 @@ def decode_forward(params, cfg: ModelConfig, x):
     eo = jnp.einsum("tef,efd->ted", h, params["w_down"])
     out = jnp.einsum("te,ted->td", w.astype(x.dtype), eo)
     return out.reshape(b, s, d), jnp.float32(0.0)
+
+
+def held_forward(params, cfg: ModelConfig, x):
+    """The expert layer of a chip that holds experts
+    ``[expert_offset, expert_offset + experts_held)``: x (B, S, d) ->
+    (this chip's part of the output (B, S, d), load-balance loss,
+    counters).
+
+    The router scores all ``num_experts`` in float32 at the highest
+    precision (near-ties in the top-k would otherwise pick other experts
+    than a float32 reference does), keeps each token's top
+    ``experts_per_token`` and renormalises their probabilities. Every
+    route to a held expert is computed, none dropped: the routes are
+    sorted by held expert (the others after them) and the SwiGLU
+    experts run as grouped matmuls over the held experts only
+    (``ops.grouped_matmul``, a Pallas kernel on the TPU, whose products
+    and their gradients take ``cfg.matmul_precision``). Each token
+    then sums its held experts' outputs weighted by their gates; what
+    the absent experts would add is the other chips' part. The
+    load-balance loss is the Switch term over all experts. Counters:
+    ``moe/routes`` the token-choice routes made, ``moe/routes_held``
+    those computed here."""
+    b, s, d = x.shape
+    e, k = cfg.num_experts, cfg.experts_per_token
+    g, first = cfg.held_experts(), cfg.expert_offset
+    tokens = x.reshape(-1, d)
+    t = tokens.shape[0]
+    with jax.named_scope("cdfl.moe.route"):
+        logits = jnp.dot(tokens.astype(jnp.float32),
+                         params["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, idx = jax.lax.top_k(probs, k)                   # (T, k)
+        gate = gate / gate.sum(-1, keepdims=True)
+        held = (idx >= first) & (idx < first + g)
+        # held routes by expert, then the absent ones (bucket g); a
+        # route's rank is its row in that order
+        key = jnp.where(held, idx - first, g).reshape(-1)     # (T k,)
+        onehot = jax.nn.one_hot(key, g + 1, dtype=jnp.int32)
+        sizes = onehot.sum(0)
+        starts = jnp.cumsum(sizes) - sizes
+        rank = (starts[key]
+                + (jnp.cumsum(onehot, axis=0) * onehot).sum(-1) - 1)
+        order = jnp.zeros((t * k,), jnp.int32).at[rank].set(
+            jnp.arange(t * k, dtype=jnp.int32))
+        routed = tokens[order // k]                           # (T k, d)
+    with jax.named_scope("cdfl.moe.experts"):
+        sizes = sizes[:g]
+        prec = (jax.lax.Precision(cfg.matmul_precision)
+                if cfg.matmul_precision else None)
+        h = jax.nn.silu(ops.grouped_matmul(routed, params["w_gate"], sizes,
+                                           prec))
+        h = h * ops.grouped_matmul(routed, params["w_up"], sizes, prec)
+        expert_out = ops.grouped_matmul(h, params["w_down"], sizes, prec)
+    with jax.named_scope("cdfl.moe.combine"):
+        per_route = expert_out[rank].reshape(t, k, d)
+        w = jnp.where(held, gate, 0.0).astype(x.dtype)
+        out = jnp.einsum("tk,tkd->td", w, per_route)
+    chosen = jax.nn.one_hot(idx, e, dtype=jnp.float32).sum(1)   # (T, E)
+    aux = e * (chosen.mean(0) * probs.mean(0)).sum()
+    counters = {"moe/routes": jnp.int32(t * k),
+                "moe/routes_held": sizes.sum()}
+    return out.reshape(b, s, d), aux, counters

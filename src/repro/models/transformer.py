@@ -11,11 +11,13 @@ API:
   init_params(rng, cfg)               -> params pytree
   forward(params, cfg, batch, ...)    -> (logits, aux_loss)
   loss_fn(params, cfg, batch)         -> scalar
+  loss_and_aux(params, cfg, batch)    -> (scalar, counters)
   init_decode(cfg, batch, max_len)    -> DecodeState
   decode_step(params, cfg, state, tk) -> (logits, DecodeState)
 """
 from __future__ import annotations
 
+import contextlib
 from typing import NamedTuple, Optional
 
 import jax
@@ -33,6 +35,12 @@ def _is_homogeneous(cfg: ModelConfig) -> bool:
     return len(set(cfg.blocks())) == 1
 
 
+def _precision(cfg: ModelConfig):
+    """The matmul precision the forward is traced under."""
+    return (jax.default_matmul_precision(cfg.matmul_precision)
+            if cfg.matmul_precision else contextlib.nullcontext())
+
+
 # --------------------------------------------------------------------------
 # Block init / apply
 # --------------------------------------------------------------------------
@@ -44,7 +52,7 @@ def _block_init(rng, cfg: ModelConfig, kind: str, dtype,
     p = {"norm1": norm_init(cfg.d_model, dtype),
          "norm2": norm_init(cfg.d_model, dtype)}
     if with_mix:
-        if kind == "attn":
+        if kind == "attn" or cfg.attention_kind(kind) is not None:
             p["mix"] = attention.init(r_mix, cfg, dtype)
         elif kind == "rwkv":
             p["mix"] = rwkv.init(r_mix, cfg, dtype)
@@ -59,29 +67,39 @@ def _block_init(rng, cfg: ModelConfig, kind: str, dtype,
 
 
 def _apply_ffn(p, cfg: ModelConfig, x, decode: bool, group_size: int):
+    """Returns (out, aux, counters); only the held-expert layer counts."""
+    if cfg.experts_held:
+        return moe.held_forward(p["ffn"], cfg, x)
     if cfg.num_experts:
         if decode:
-            return moe.decode_forward(p["ffn"], cfg, x)
-        return moe.forward(p["ffn"], cfg, x, group_size)
+            return moe.decode_forward(p["ffn"], cfg, x) + ({},)
+        return moe.forward(p["ffn"], cfg, x, group_size) + ({},)
     _, mlp_fn = layers.make_mlp(cfg.act)
-    return mlp_fn(p["ffn"], x), jnp.float32(0.0)
+    return mlp_fn(p["ffn"], x), jnp.float32(0.0), {}
+
+
+def _add(a: dict, b: dict) -> dict:
+    return {k: a.get(k, 0) + b.get(k, 0) for k in {**a, **b}}
 
 
 def _apply_block(p, cfg: ModelConfig, kind: str, x, *, shared=None,
                  state=None, decode: bool = False,
                  window_override=None, group_size: int = 2048):
-    """Returns (x, aux, new_state)."""
+    """Returns (x, aux, new_state, counters)."""
     _, norm_fn = layers.make_norm(cfg.norm)
     mix_params = shared if shared is not None else p["mix"]
     x = pspec.constrain(x, "batch", None, None)
     h = pspec.constrain(norm_fn(p["norm1"], x), "batch", None, None)
-    if kind in ("attn", "shared_attn"):
+    akind = cfg.attention_kind(kind)
+    if kind in ("attn", "shared_attn") or akind is not None:
         if decode:
             mix_out, new_state = attention.decode_step(
-                mix_params, cfg, h, state, window_override)
+                mix_params, cfg, h, state, window_override, kind=akind)
         else:
-            mix_out = attention.forward(mix_params, cfg, h,
-                                        window_override=window_override)
+            with jax.named_scope("cdfl.attn"):
+                mix_out = attention.forward(mix_params, cfg, h,
+                                            window_override=window_override,
+                                            kind=akind)
             new_state = state
     elif kind == "rwkv":
         mix_out, new_state = rwkv.forward(mix_params, cfg, h, state)
@@ -95,9 +113,9 @@ def _apply_block(p, cfg: ModelConfig, kind: str, x, *, shared=None,
     # 167GB/step for qwen3 before this constraint).
     x = x + pspec.constrain(mix_out, "batch", None, None)
     h = pspec.constrain(norm_fn(p["norm2"], x), "batch", None, None)
-    ffn_out, aux = _apply_ffn(p, cfg, h, decode, group_size)
+    ffn_out, aux, counters = _apply_ffn(p, cfg, h, decode, group_size)
     x = x + pspec.constrain(ffn_out, "batch", None, None)
-    return x, aux, new_state
+    return x, aux, new_state, counters
 
 
 # --------------------------------------------------------------------------
@@ -110,13 +128,13 @@ def init_params(rng, cfg: ModelConfig, dtype=None):
     r_embed, r_layers, r_shared, r_head = jax.random.split(rng, 4)
     norm_init, _ = layers.make_norm(cfg.norm)
     params = {
-        "embed": layers.embedding_init(r_embed, cfg.vocab_size, cfg.d_model,
-                                       dtype),
+        "embed": layers.embedding_init(r_embed, cfg.held_vocab(),
+                                       cfg.d_model, dtype),
         "final_norm": norm_init(cfg.d_model, dtype),
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = {
-            "table": layers._dense_init(r_head, (cfg.vocab_size,
+            "table": layers._dense_init(r_head, (cfg.held_vocab(),
                                                  cfg.d_model),
                                         scale=0.02, dtype=dtype)}
     if _is_homogeneous(cfg):
@@ -140,14 +158,28 @@ def init_params(rng, cfg: ModelConfig, dtype=None):
 # Forward (train / prefill)
 # --------------------------------------------------------------------------
 
-def forward(params, cfg: ModelConfig, batch: dict, *,
-            window_override=None, group_size: int = 2048,
-            remat: bool = False, last_only: bool = False,
-            unroll: bool = False):
+def _held_ids(cfg: ModelConfig, ids):
+    """Token ids as rows of the held vocabulary slice."""
+    return ids - cfg.vocab_offset if cfg.vocab_offset else ids
+
+
+def forward(params, cfg: ModelConfig, batch: dict, **kw):
     """batch: {"tokens": (B, S) int32} (+ "embeds": (B, P, d) for VLM).
     Returns (logits (B, S_out, V) f32, aux scalar). last_only: unembed only
-    the final position (prefill serving — avoids the (B,S,V) logits)."""
-    tokens = batch["tokens"]
+    the final position (prefill serving — avoids the (B,S,V) logits).
+    Over a held vocabulary slice the tokens are ids of the slice and the
+    logits are over the slice."""
+    with _precision(cfg):
+        logits, aux, _ = _forward(params, cfg, batch, **kw)
+    return logits, aux
+
+
+def _forward(params, cfg: ModelConfig, batch: dict, *,
+             window_override=None, group_size: int = 2048,
+             remat: bool = False, last_only: bool = False,
+             unroll: bool = False):
+    """``forward``, and the counters of its layers summed over them."""
+    tokens = _held_ids(cfg, batch["tokens"])
     x = layers.embed(params["embed"], tokens).astype(_dtype(cfg))
     x = pspec.constrain(x, "batch", None, None)
     n_text = tokens.shape[1]
@@ -159,35 +191,37 @@ def forward(params, cfg: ModelConfig, batch: dict, *,
         kind = kinds[0]
 
         def body(carry, layer_p):
-            h, aux = carry
-            h, a, _ = _apply_block(layer_p, cfg, kind, h,
-                                   window_override=window_override,
-                                   group_size=group_size)
-            return (h, aux + a), None
+            h, aux, counts = carry
+            h, a, _, c = _apply_block(layer_p, cfg, kind, h,
+                                      window_override=window_override,
+                                      group_size=group_size)
+            return (h, aux + a, _add(counts, c)), None
 
         if remat:
             body = jax.checkpoint(body)
+        counts0 = ({name: jnp.int32(0) for name in moe.COUNTERS}
+                   if cfg.experts_held else {})
         # unroll=True: straight-line HLO so cost_analysis counts every
         # layer (XLA while-loop bodies are costed ONCE) — dry-run only.
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)),
-                                   params["layers"],
-                                   unroll=cfg.num_layers if unroll else 1)
+        (x, aux, counts), _ = jax.lax.scan(
+            body, (x, jnp.float32(0.0), counts0), params["layers"],
+            unroll=cfg.num_layers if unroll else 1)
     else:
-        aux = jnp.float32(0.0)
+        aux, counts = jnp.float32(0.0), {}
         for i, kind in enumerate(kinds):
             shared = params.get("shared_attn") if kind == "shared_attn" \
                 else None
 
             def blk(p, h, sh, kind=kind):
-                out, a_, _ = _apply_block(
+                out, a_, _, c_ = _apply_block(
                     p, cfg, kind, h, shared=sh,
                     window_override=window_override,
                     group_size=group_size)
-                return out, a_
+                return out, a_, c_
 
             fn = jax.checkpoint(blk) if remat else blk
-            x, a = fn(params["layers_list"][i], x, shared)
-            aux = aux + a
+            x, a, c = fn(params["layers_list"][i], x, shared)
+            aux, counts = aux + a, _add(counts, c)
 
     _, norm_fn = layers.make_norm(cfg.norm)
     x = norm_fn(params["final_norm"], x)
@@ -198,19 +232,31 @@ def forward(params, cfg: ModelConfig, batch: dict, *,
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     logits = layers.unembed(head, x)
     logits = pspec.constrain(logits, "batch", None, "vocab")
-    return logits, aux
+    return logits, aux, counts
 
 
 def loss_fn(params, cfg: ModelConfig, batch: dict, **kw):
+    """Next-token cross entropy plus the router's load-balance term
+    (``loss_and_aux`` without its counters)."""
+    return loss_and_aux(params, cfg, batch, **kw)[0]
+
+
+def loss_and_aux(params, cfg: ModelConfig, batch: dict, **kw):
     """Next-token cross entropy (labels provided by the data pipeline).
 
     Formulated as logsumexp - selected-logit (one-hot contraction): both
     reduce over the vocab dim locally and combine with a tiny all-reduce,
     so vocab-sharded logits are never all-gathered (take_along_axis on the
     sharded dim would gather the full (B,S,V) logits — the dry-run showed
-    that costing ~400GB/step of wire traffic)."""
-    logits, aux = forward(params, cfg, batch, **kw)
-    labels = batch["labels"]
+    that costing ~400GB/step of wire traffic).
+
+    Returns (loss, counters): the layers' counters summed (the held
+    expert layer's ``moe/routes`` and ``moe/routes_held``; none for
+    other models). Over a held vocabulary slice the labels are ids of
+    the slice and the cross entropy is over it."""
+    with _precision(cfg):
+        logits, aux, counts = _forward(params, cfg, batch, **kw)
+    labels = _held_ids(cfg, batch["labels"])
     lse = jax.nn.logsumexp(logits, axis=-1)
     onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=logits.dtype)
     picked = jnp.sum(logits * onehot, axis=-1)
@@ -220,7 +266,7 @@ def loss_fn(params, cfg: ModelConfig, batch: dict, **kw):
         loss = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
     else:
         loss = nll.mean()
-    return loss + cfg.router_aux_coef * aux
+    return loss + cfg.router_aux_coef * aux, counts
 
 
 # --------------------------------------------------------------------------
@@ -236,6 +282,11 @@ def _layer_state(cfg: ModelConfig, kind: str, batch: int, max_len: int,
                  dtype, window):
     if kind in ("attn", "shared_attn"):
         return attention.init_cache(cfg, batch, max_len, dtype, window)
+    akind = cfg.attention_kind(kind)
+    if akind is not None:
+        return attention.init_cache(
+            cfg, batch, max_len, dtype,
+            akind.window if window is None else window)
     if kind == "rwkv":
         return rwkv.init_state(cfg, batch)
     if kind == "mamba":
@@ -249,6 +300,8 @@ def init_decode(cfg: ModelConfig, batch: int, max_len: int,
     window = window_override if window_override is not None \
         else cfg.sliding_window
     kinds = cfg.blocks()
+    if cfg.attention_kinds:
+        window = window_override       # each kind's own window otherwise
     if _is_homogeneous(cfg):
         one = _layer_state(cfg, kinds[0], batch, max_len, dtype, window)
         states = jax.tree.map(
@@ -265,7 +318,8 @@ def decode_step(params, cfg: ModelConfig, state: DecodeState,
                 unroll: bool = False):
     """tokens: (B,) int32 — one new token per sequence.
     Returns (logits (B, V) f32, new DecodeState)."""
-    x = layers.embed(params["embed"], tokens[:, None]).astype(_dtype(cfg))
+    x = layers.embed(params["embed"], _held_ids(cfg, tokens)[:, None]
+                     ).astype(_dtype(cfg))
     kinds = cfg.blocks()
 
     if _is_homogeneous(cfg):
@@ -273,7 +327,7 @@ def decode_step(params, cfg: ModelConfig, state: DecodeState,
 
         def body(h, xs):
             layer_p, st = xs
-            h, _, new_st = _apply_block(layer_p, cfg, kind, h, state=st,
+            h, _, new_st, _ = _apply_block(layer_p, cfg, kind, h, state=st,
                                         decode=True,
                                         window_override=window_override)
             return h, new_st
@@ -286,7 +340,8 @@ def decode_step(params, cfg: ModelConfig, state: DecodeState,
         for i, kind in enumerate(kinds):
             shared = params.get("shared_attn") if kind == "shared_attn" \
                 else None
-            x, _, st = _apply_block(params["layers_list"][i], cfg, kind, x,
+            x, _, st, _ = _apply_block(params["layers_list"][i], cfg, kind,
+                                       x,
                                     shared=shared, state=state.states[i],
                                     decode=True,
                                     window_override=window_override)

@@ -417,3 +417,40 @@ def test_continuing_static_session_lowers_nothing_after_second_call():
         jax.monitoring.unregister_event_duration_listener(count)
     assert lowered[0] > 0 and lowered[2] == 0, lowered
     assert session.rounds_completed == 180
+
+
+@pytest.mark.parametrize("flat_local", [False, True],
+                         ids=["leaf_local", "flat_local"])
+def test_scalar_loss_and_loss_with_empty_aux_lower_alike(flat_local):
+    """A loss may return ``(loss, aux)``; one that returns a scalar traces
+    the very program that the same loss returning ``(loss, {})`` does, so
+    models without counters run what they ran before."""
+    loss = simple.make_mlp_loss(MLP_CONFIG)
+    fed = FedConfig(num_nodes=4, local_steps=3)
+    train = TrainConfig(batch_size=8)
+    key = jax.random.PRNGKey(0)
+    data = {"x": jax.random.normal(key, (4, 32, 784)),
+            "y": jax.random.randint(key, (4, 32), 0, 10)}
+    items = jax.random.randint(key, (4, 32, 16), 0, 4096)
+    texts = []
+    for fn in (loss, lambda p, b: (loss(p, b), {})):
+        tr = build_trainer(fn, fed, train, flat_local=flat_local)
+        state = tr.init(key, lambda r: simple.mlp_init(r, MLP_CONFIG), items)
+        texts.append(tr.lower_rounds(state, data, 2, rng=key).as_text())
+    assert texts[0] == texts[1]
+
+
+def test_loss_counters_sum_into_round_metrics():
+    """The aux counters of a loss are summed over local steps and nodes
+    into each round's ``metrics["counters"]``."""
+    def loss(p, b):
+        return jnp.sum((p["w"] - b) ** 2), {"seen": jnp.int32(b.shape[0])}
+    fed = FedConfig(num_nodes=4, local_steps=3)
+    tr = build_trainer(loss, fed, TrainConfig(batch_size=2))
+    items = jax.random.randint(jax.random.PRNGKey(1), (4, 16, 4), 0, 40)
+    state = tr.init(jax.random.PRNGKey(0),
+                    lambda r: {"w": jax.random.normal(r, (3,))}, items)
+    data = jnp.ones((4, 16, 3))
+    _, metrics = tr.run_rounds(state, data, 5)
+    np.testing.assert_array_equal(metrics["counters"]["seen"],
+                                  np.full(5, 4 * 3 * 2))

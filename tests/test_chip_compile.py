@@ -1,4 +1,4 @@
-"""The mixing kernels compile for a TPU v5e at real widths.
+"""The mixing and expert kernels compile for a TPU v5e at real widths.
 
 Interpret mode runs a Pallas kernel body op by op and accepts block
 shapes, layouts and primitives that the chip's compiler refuses. These
@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and a worker that cannot
 describe the chip skips these tests instead of failing collection.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -62,6 +64,22 @@ def test_flat_mix_compiles_for_v5e(one_chip, width, wire_dtype):
     assert ops.pallas_kernels(hlo) == {"flat_mix"}
 
 
+@pytest.mark.parametrize("p", [267_211_008, 128 * 2_087_593])
+def test_flat_mix_compiles_for_v5e_at_a_large_width(one_chip, p):
+    """Two stations of Mellum2's share (P = 267,211,008), and a P that is
+    128 times a prime: a buffer this long takes the wide slab whatever
+    P's factors, and compiles with a partial last slab."""
+    k = 2
+    cols = ops._mix_cols(p)
+    assert cols == ops._MIX_COLS and p % cols
+    s = lambda shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.float32, sharding=one_chip)
+    hlo = _compile(
+        lambda e, m, g: consensus_mix.flat_mix(e, m, m, g, block_cols=cols),
+        s((k, k)), s((k, p)), s(()))
+    assert ops.pallas_kernels(hlo) == {"flat_mix"}
+
+
 @pytest.mark.parametrize("wire_dtype", [jnp.float32, jnp.bfloat16])
 def test_sparse_mix_compiles_for_v5e(one_chip, width, wire_dtype):
     k, d = 1024, 8
@@ -104,3 +122,36 @@ def test_cnd_sketch_kernels_compile_for_v5e(one_chip):
     hlo = _compile(lambda it: cnd_sketch.cnd_popcount(
         cnd_sketch.cnd_bitmaps(it, 3, 8192)), items)
     assert ops.pallas_kernels(hlo) == {"cnd_bitmaps", "cnd_popcount"}
+
+
+def _expert_step_hlo(one_chip, precision=None):
+    """The grouped expert matmuls at Mellum2's share: two stations, each
+    2,048 tokens x top-8 routes over 8 held experts, hidden 2,304, expert
+    width 896; forward and both gradients of the SwiGLU experts under the
+    trainer's vmap over stations."""
+    from repro.kernels import expert_gmm
+    s_, m, d, f, g = 2, 2048 * 8, 2304, 896, 8
+    s = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    gmm = functools.partial(expert_gmm.grouped_matmul, precision=precision)
+
+    def step(x, wg, wu, wd, sizes):
+        def node(x, wg, wu, wd, sizes):
+            def loss(x, wg, wu, wd):
+                h = (jax.nn.silu(gmm(x, wg, sizes)) * gmm(x, wu, sizes))
+                return gmm(h, wd, sizes).sum()
+            return jax.grad(loss, (0, 1, 2, 3))(x, wg, wu, wd)
+        return jax.vmap(node)(x, wg, wu, wd, sizes)
+    return _compile(step, s((s_, m, d)), s((s_, g, d, f)),
+                    s((s_, g, d, f)), s((s_, g, f, d)), s((s_, g), jnp.int32))
+
+
+def test_expert_kernels_compile_for_v5e(one_chip):
+    hlo = _expert_step_hlo(one_chip)
+    assert ops.pallas_kernels(hlo) == {"expert_gmm", "expert_tgmm"}
+
+
+def test_expert_kernels_compile_for_v5e_at_the_highest_precision(one_chip):
+    """Float32 contractions, as the Mellum2 cell runs them."""
+    hlo = _expert_step_hlo(one_chip, jax.lax.Precision.HIGHEST)
+    assert ops.pallas_kernels(hlo) == {"expert_gmm", "expert_tgmm"}

@@ -115,6 +115,7 @@ def test_consensus_mix_sweep(rows, n, dtype):
 @pytest.mark.parametrize("k,p,block,dtype", [
     (4, 1024, 128, jnp.float32), (4, 2048, 512, jnp.float32),
     (8, 512, 128, jnp.float32), (4, 1024, 128, jnp.bfloat16),
+    (4, 640, 512, jnp.float32),                     # a partial last slab
 ])
 def test_flat_consensus_kernel_sweep(k, p, block, dtype):
     from repro.kernels.consensus_mix import flat_consensus
@@ -127,6 +128,32 @@ def test_flat_consensus_kernel_sweep(k, p, block, dtype):
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("p,block", [(640, 512), (640, 1024), (1152, 512)])
+def test_flat_mix_kernel_partial_last_slab(p, block):
+    """A slab width that does not divide P: the last slab's columns past
+    P change nothing, and every column mixes as the XLA delta form."""
+    from repro.kernels.consensus_mix import flat_mix
+    k = 4
+    ks = jax.random.split(jax.random.PRNGKey(p + block), 2)
+    master = jax.random.normal(ks[0], (k, p))
+    eta = jax.nn.softmax(jax.random.normal(ks[1], (k, k)))
+    wire = master.astype(jnp.bfloat16)
+    out = flat_mix(eta, master, wire, jnp.float32(0.4), block_cols=block,
+                   interpret=True)
+    w = wire.astype(jnp.float32)
+    exp = master + 0.4 * (eta @ w - eta.sum(1)[:, None] * w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-6)
+
+
+def test_mix_cols_wide_slab_whatever_the_factors():
+    """Short buffers keep the slabs tuned on the paper MLP; a long one
+    takes the wide slab even where P is 128 times a prime."""
+    assert ops._mix_cols(23_936) == 128
+    assert ops._mix_cols(4096 * 512) == 512
+    for p in (267_211_008, 128 * 2_087_593):
+        assert ops._mix_cols(p) == ops._MIX_COLS
 
 
 def test_consensus_mix_pytree_wrapper():
@@ -243,3 +270,102 @@ def test_cnd_kernels_not_auto_selected_off_tpu(monkeypatch):
     assert bm.shape == (2, 2048 // 32)
     counts = ops.cnd_popcount(bm)
     assert counts.shape == (2,)
+
+
+# --- grouped matmuls over held experts ---------------------------------------
+
+@pytest.mark.parametrize("sizes", [
+    [[10, 0, 30, 5], [64, 0, 0, 0], [1, 2, 3, 4]],   # ragged, one-group, few
+    [[0, 0, 0, 0], [16, 16, 16, 16], [7, 9, 0, 40]],  # none held, all held
+], ids=["ragged", "empty_and_full"])
+def test_grouped_matmul_kernel_matches_xla_under_station_vmap(sizes):
+    """The expert kernels in interpret mode equal their XLA form,
+    forward and both gradients, under a vmap over 3 stations (each with
+    its own routes and weights), rows past the held routes reading 0."""
+    from repro.kernels import expert_gmm
+    m, k, n = 64, 32, 48
+    r = jax.random.split(jax.random.PRNGKey(11), 3)
+    lhs = jax.random.normal(r[0], (3, m, k))
+    rhs = jax.random.normal(r[1], (3, 4, k, n))
+    dout = jax.random.normal(r[2], (3, m, n))
+    sizes = jnp.asarray(sizes, jnp.int32)
+
+    def kernel(a, b, s):
+        return expert_gmm.grouped_matmul(a, b, s, tm=16, cap=128,
+                                         interpret=True)
+
+    def run(fn):
+        def loss(a, b):
+            return (jax.vmap(fn)(a, b, sizes) * dout).sum()
+        return jax.vmap(fn)(lhs, rhs, sizes), jax.grad(loss, (0, 1))(lhs, rhs)
+
+    with jax.default_matmul_precision("highest"):
+        got, (dl, dr) = run(kernel)
+        want, (wl, wr) = run(expert_gmm.grouped_matmul_xla)
+    # float32 sums over 32- and 64-long contractions in another order
+    for a, b in ((got, want), (dl, wl), (dr, wr)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-4)
+    held = np.asarray(sizes).sum(-1)
+    for st in range(3):
+        assert not np.any(np.asarray(got)[st, held[st]:])
+
+
+def test_grouped_matmul_vmap_folds_into_one_kernel_call():
+    from repro.kernels import expert_gmm
+    lhs = jnp.ones((2, 32, 16))
+    rhs = jnp.ones((2, 4, 16, 16))
+    sizes = jnp.full((2, 4), 4, jnp.int32)
+
+    def loss(a, b):
+        return jax.vmap(lambda x, w, s: expert_gmm.grouped_matmul(
+            x, w, s, tm=16, interpret=True))(a, b, sizes).sum()
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1)))(lhs, rhs))
+    # forward, input gradient, weight gradient: one call each
+    assert text.count("pallas_call") == 3
+
+
+def _kernel_dots(jaxpr, out: dict, kernel=None) -> dict:
+    """``{pallas_call name: [precision of each dot_general in it]}``."""
+    for eqn in jaxpr.eqns:
+        inside = kernel
+        if eqn.primitive.name == "pallas_call":
+            inside = eqn.params["name"]
+            out.setdefault(inside, [])
+        elif eqn.primitive.name == "dot_general" and kernel:
+            out[kernel].append(eqn.params["precision"])
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _kernel_dots(sub, out, inside)
+    return out
+
+
+@pytest.mark.parametrize("precision", [None, jax.lax.Precision.HIGHEST],
+                         ids=["default", "highest"])
+def test_grouped_matmul_kernels_multiply_at_the_precision_asked(precision):
+    """The forward, input-gradient and weight-gradient kernels all take
+    the precision asked for (a float32 product left at the TPU's default
+    is one bfloat16 pass)."""
+    from repro.kernels import expert_gmm
+    sizes = jnp.full((4,), 4, jnp.int32)
+
+    def loss(a, b):
+        return expert_gmm.grouped_matmul(a, b, sizes, precision=precision,
+                                         tm=16, interpret=True).sum()
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(
+        jnp.ones((32, 16)), jnp.ones((4, 16, 16))).jaxpr
+    want = None if precision is None else (precision, precision)
+    assert _kernel_dots(jaxpr, {}) == {"expert_gmm": [want, want],
+                                       "expert_tgmm": [want]}
+
+
+def test_grouped_matmul_not_auto_selected_off_tpu(monkeypatch):
+    from repro.kernels import expert_gmm
+    called = []
+    monkeypatch.setattr(expert_gmm, "grouped_matmul",
+                        lambda *a, **kw: called.append(1))
+    out = ops.grouped_matmul(jnp.ones((8, 4)), jnp.ones((2, 4, 3)),
+                             jnp.array([3, 2], jnp.int32))
+    assert not called and out.shape == (8, 3)
+    np.testing.assert_array_equal(out[5:], 0.0)

@@ -140,7 +140,8 @@ def test_unroll_equals_scan():
 
 
 def test_param_count_analytic_close_to_actual():
-    for arch in ["granite-8b", "qwen3-1.7b", "mixtral-8x7b"]:
+    for arch in ["granite-8b", "qwen3-1.7b", "mixtral-8x7b",
+                 "mellum2-12b-a2.5b"]:
         cfg = get_smoke_arch(arch)
         params = transformer.init_params(jax.random.PRNGKey(0), cfg)
         actual = sum(l.size for l in jax.tree.leaves(params))

@@ -195,3 +195,65 @@ def test_prepare_counts_rounds_of_compiled_stacks(profiled):
     finally:
         jax.monitoring.unregister_scalar_listener(listen)
     assert counters == [(name, 60), (name, 60)]
+
+
+# -- the held expert layer: scopes and route counters -------------------------
+
+MOE_SCOPES = ("cdfl.attn", "cdfl.moe.route", "cdfl.moe.experts",
+              "cdfl.moe.combine")
+
+
+@pytest.fixture(scope="module")
+def mellum_session():
+    """Mellum2's layer pattern at a small size, chip 1 of a 2-way split
+    (4 of 8 experts at top-2), on 2 stations with 2 local steps of one
+    16-token sequence."""
+    import dataclasses
+    from repro.configs import base
+    from repro.configs import mellum2_12b_a2_5b as mellum
+    from repro.models import transformer
+    whole = dataclasses.replace(
+        mellum.CONFIG, d_model=32, head_dim=8, d_ff=16, num_heads=4,
+        num_kv_heads=2, num_experts=8, experts_held=8, experts_per_token=2,
+        vocab_size=64)
+    cfg = base.chip_share(whole, 2, 1, 4)
+    fed = FedConfig(num_nodes=2, local_steps=2)
+    exp = Experiment.from_parts(
+        lambda p, b: transformer.loss_and_aux(p, cfg, b),
+        lambda r: transformer.init_params(r, cfg), fed=fed,
+        train=TrainConfig(batch_size=1))
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 4, 17), 32, 64)
+    data = {"tokens": tokens[..., :-1], "labels": tokens[..., 1:]}
+    return exp.compile(data, tokens[..., :4])
+
+
+def test_route_counters_recorded_once_per_run(mellum_session):
+    got = []
+
+    def listen(event, value, **kw):
+        if event.startswith("/cdfl/moe/"):
+            got.append((event, value))
+    jax.monitoring.register_scalar_listener(listen)
+    try:
+        results = [mellum_session.run(3) for _ in range(2)]
+    finally:
+        jax.monitoring.unregister_scalar_listener(listen)
+    # stations x local steps x layers x rounds x tokens x top-2
+    routes = 2 * 2 * 4 * 3 * 16 * 2
+    assert [e for e, _ in got] == ["/cdfl/moe/routes",
+                                   "/cdfl/moe/routes_held"] * 2
+    for i, result in enumerate(results):
+        counters = result.metrics["counters"]
+        assert got[2 * i][1] == routes
+        assert got[2 * i + 1][1] == int(counters["moe/routes_held"].sum())
+        assert 0 < got[2 * i + 1][1] < routes
+
+
+def test_compiled_scan_carries_the_expert_scopes(mellum_session):
+    text = mellum_session.lower(2).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in MOE_SCOPES:
+        # under autodiff a scope reads jvp(<scope>) or transpose(...)
+        inner = re.compile(rf"[/(]{re.escape(scope)}[)/]")
+        assert any("cdfl.local/" in n and "cdfl.grad/" in n
+                   and inner.search(n) for n in names), scope
