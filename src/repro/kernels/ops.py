@@ -105,20 +105,18 @@ def consensus_mix(w, neighbors, eta, gamma, block_rows: int = 256,
     return ref.consensus_mix(w, neighbors, eta, gamma)
 
 
-# a flat mix whose grid would take more steps than this takes wider slabs
-_MIX_STEPS = 4096
-_MIX_COLS = 32768
-
-
-def _mix_cols(p: int) -> int:
-    """Columns of one slab of a flat (K, P) mix: 512, or 128 where P is
-    no multiple of 512, as tuned on the paper MLP's P = 23,936; a buffer
-    that would take more than ``_MIX_STEPS`` such steps takes
-    ``_MIX_COLS``, whatever P's factors (the kernel masks the last slab),
-    since every grid step costs a fixed overhead (P = 267,211,008 took
-    488 ms a mix on a v5e in 128-column slabs, 19.8 ms in these)."""
-    cols = 512 if p % 512 == 0 else 128
-    return cols if p // cols <= _MIX_STEPS else _MIX_COLS
+def _mix_cols(k: int, p: int) -> int:
+    """Columns of one slab of a flat (K, P) mix: the widest multiple of
+    128 whose f32 block, its K rows padded to 8 sublanes, holds at most
+    1 MiB, and never more than P (a full-width block is legal; the
+    kernel masks a partial last slab). Every grid step costs a fixed
+    overhead, so the fewer the better: at K = 4 the paper MLP's
+    P = 23,936 mixes in one step, and a long buffer at K <= 8 in
+    32,768-column slabs (P = 267,211,008 took 488 ms a mix on a v5e in
+    128-column slabs, 19.8 ms in these). A bf16 wire block pads to 16
+    sublanes of 2 bytes, the same bytes."""
+    rows = -(-k // 8) * 8
+    return min(p, 128 * max(1, (1 << 20) // (4 * rows * 128)))
 
 
 @partial(jax.jit, static_argnames=("force_kernel",))
@@ -128,7 +126,7 @@ def flat_consensus(matrix, buf, force_kernel: bool = False):
     TPU."""
     if use_pallas() or force_kernel:
         return _cm.flat_consensus(matrix, buf,
-                                  block_cols=_mix_cols(buf.shape[1]),
+                                  block_cols=_mix_cols(*buf.shape),
                                   interpret=_interpret())
     from repro.core import flatten
     return flatten.matmul_nodes(matrix, buf)
@@ -145,7 +143,7 @@ def flat_mix(eta, master, wire, gamma, wire_self=None,
     XLA delta form, not the interpreted kernel."""
     if use_pallas() or force_kernel:
         return _cm.flat_mix(eta, master, wire, gamma, wire_self,
-                            block_cols=_mix_cols(master.shape[1]),
+                            block_cols=_mix_cols(*master.shape),
                             interpret=_interpret())
     # one source of truth for the XLA delta form: flatten.mix_flat
     from repro.core import flatten

@@ -53,31 +53,54 @@ def _compile(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
-@pytest.mark.parametrize("wire_dtype", [jnp.float32, jnp.bfloat16])
-def test_flat_mix_compiles_for_v5e(one_chip, width, wire_dtype):
+@pytest.mark.parametrize("wire_dtype,separate_self", [
+    (jnp.float32, False), (jnp.bfloat16, False), (jnp.float32, True),
+], ids=["f32", "bf16", "wire_self"])
+def test_flat_mix_compiles_for_v5e(one_chip, width, wire_dtype,
+                                   separate_self):
+    """Four stations of the paper MLP mix in one slab of the whole
+    width: a block equal to the array, one grid step."""
     k = 4
+    cols = ops._mix_cols(k, width)
+    assert cols == width
     s = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
+    wire = s((k, width), wire_dtype)
     hlo = _compile(
-        lambda e, m, w, g: consensus_mix.flat_mix(e, m, w, g, block_cols=128),
-        s((k, k)), s((k, width)), s((k, width), wire_dtype), s(()))
+        lambda e, m, w, g, *ws: consensus_mix.flat_mix(
+            e, m, w, g, *ws, block_cols=cols),
+        s((k, k)), s((k, width)), wire, s(()),
+        *([wire] if separate_self else []))
     assert ops.pallas_kernels(hlo) == {"flat_mix"}
 
 
 @pytest.mark.parametrize("p", [267_211_008, 128 * 2_087_593])
 def test_flat_mix_compiles_for_v5e_at_a_large_width(one_chip, p):
     """Two stations of Mellum2's share (P = 267,211,008), and a P that is
-    128 times a prime: a buffer this long takes the wide slab whatever
-    P's factors, and compiles with a partial last slab."""
+    128 times a prime: a buffer this long takes 32,768-column slabs
+    (1 MiB of padded f32 block) whatever P's factors, and compiles with
+    a partial last slab."""
     k = 2
-    cols = ops._mix_cols(p)
-    assert cols == ops._MIX_COLS and p % cols
+    cols = ops._mix_cols(k, p)
+    assert cols == 32_768 and p % cols
     s = lambda shape: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.float32, sharding=one_chip)
     hlo = _compile(
         lambda e, m, g: consensus_mix.flat_mix(e, m, m, g, block_cols=cols),
         s((k, k)), s((k, p)), s(()))
     assert ops.pallas_kernels(hlo) == {"flat_mix"}
+
+
+def test_flat_consensus_compiles_for_v5e(one_chip, width):
+    """The A @ BUF kernel in one slab of the paper MLP's width."""
+    k = 4
+    s = lambda shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.float32, sharding=one_chip)
+    hlo = _compile(
+        lambda a, b: consensus_mix.flat_consensus(
+            a, b, block_cols=ops._mix_cols(k, width)),
+        s((k, k)), s((k, width)))
+    assert len(ops.pallas_kernels(hlo)) == 1
 
 
 @pytest.mark.parametrize("wire_dtype", [jnp.float32, jnp.bfloat16])

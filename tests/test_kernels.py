@@ -147,13 +147,80 @@ def test_flat_mix_kernel_partial_last_slab(p, block):
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-6)
 
 
-def test_mix_cols_wide_slab_whatever_the_factors():
-    """Short buffers keep the slabs tuned on the paper MLP; a long one
-    takes the wide slab even where P is 128 times a prime."""
-    assert ops._mix_cols(23_936) == 128
-    assert ops._mix_cols(4096 * 512) == 512
-    for p in (267_211_008, 128 * 2_087_593):
-        assert ops._mix_cols(p) == ops._MIX_COLS
+@pytest.mark.parametrize("k,p,cols", [
+    (4, 23_936, 23_936),                 # the paper MLP: one slab
+    (2, 267_211_008, 32_768),            # two stations of Mellum2's share
+    (2, 128 * 2_087_593, 32_768),        # 128 times a prime
+    (16, 267_211_008, 16_384),
+    (64, 23_936, 4_096),
+])
+def test_mix_cols_wide_slab_whatever_the_factors(k, p, cols):
+    """A slab is as wide as 1 MiB of padded f32 block allows, whatever
+    P's factors, and never wider than P."""
+    got = ops._mix_cols(k, p)
+    assert got == cols
+    assert got <= p and (got % 128 == 0 or got == p)
+    assert 4 * (-(-k // 8) * 8) * got <= 1 << 20
+
+
+def _walk(jaxpr, kernel=None):
+    """Each equation of a jaxpr and of its sub-jaxprs, with the name of
+    the pallas_call it sits in (None outside any)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, kernel
+        inside = (eqn.params["name"] if eqn.primitive.name == "pallas_call"
+                  else kernel)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk(sub, inside)
+
+
+@pytest.mark.parametrize("wire_dtype,separate_self", [
+    (jnp.float32, False), (jnp.bfloat16, False), (jnp.float32, True),
+], ids=["f32", "bf16", "wire_self"])
+def test_flat_mix_paper_width_one_slab(wire_dtype, separate_self):
+    """At the paper MLP's (4, 23,936) the mix runs a one-step grid. Its
+    output is bitwise the 128-column kernel's, since each column depends
+    only on its own column of the inputs; that is checked on inputs
+    whose every product and sum is exact in f32, because XLA:CPU's own
+    f32 dot rounds differently at different widths. On random inputs it
+    matches the XLA delta form."""
+    from repro.core import flatten
+    from repro.kernels.consensus_mix import flat_mix
+    k, p = 4, 23_936
+    fn = lambda *a: ops.flat_mix(*a, force_kernel=True)  # noqa: E731
+
+    def operands(exact):
+        ks = jax.random.split(jax.random.PRNGKey(17), 4)
+        if exact:   # multiples of 1/64 and 1/16, of 7 and 4 bits
+            master = jax.random.randint(ks[0], (k, p), -64, 65) / 64
+            eta = jax.random.randint(ks[1], (k, k), 0, 9) / 16
+            nudge = jax.random.randint(ks[2], (k, p), -2, 3) / 64
+            g = jnp.float32(0.5)
+        else:
+            master = jax.random.normal(ks[0], (k, p))
+            eta = jax.nn.softmax(jax.random.normal(ks[1], (k, k)))
+            nudge = 1e-3 * jax.random.normal(ks[2], (k, p))
+            g = jnp.float32(0.4)
+        wself = ((master + nudge).astype(wire_dtype)
+                 if separate_self else None)
+        return eta, master, master.astype(wire_dtype), g, wself
+
+    args = operands(exact=True)
+    grids = {eqn.params["name"]: eqn.params["grid_mapping"].grid
+             for eqn, _ in _walk(jax.make_jaxpr(fn)(*args).jaxpr)
+             if eqn.primitive.name == "pallas_call"}
+    assert grids == {"flat_mix": (1,)}
+    np.testing.assert_array_equal(
+        np.asarray(fn(*args)),
+        np.asarray(flat_mix(*args, block_cols=128, interpret=True)))
+    eta, master, wire, g, wself = operands(exact=False)
+    exp = flatten.mix_flat(master, eta, g, use_kernel=False, wire=wire,
+                           wire_self=wself)
+    np.testing.assert_allclose(np.asarray(fn(eta, master, wire, g, wself)),
+                               np.asarray(exp), atol=1e-6)
 
 
 def test_consensus_mix_pytree_wrapper():
@@ -324,20 +391,14 @@ def test_grouped_matmul_vmap_folds_into_one_kernel_call():
     assert text.count("pallas_call") == 3
 
 
-def _kernel_dots(jaxpr, out: dict, kernel=None) -> dict:
+def _kernel_dots(jaxpr) -> dict:
     """``{pallas_call name: [precision of each dot_general in it]}``."""
-    for eqn in jaxpr.eqns:
-        inside = kernel
+    out = {}
+    for eqn, kernel in _walk(jaxpr):
         if eqn.primitive.name == "pallas_call":
-            inside = eqn.params["name"]
-            out.setdefault(inside, [])
+            out.setdefault(eqn.params["name"], [])
         elif eqn.primitive.name == "dot_general" and kernel:
             out[kernel].append(eqn.params["precision"])
-        for value in eqn.params.values():
-            for sub in value if isinstance(value, (list, tuple)) else [value]:
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    _kernel_dots(sub, out, inside)
     return out
 
 
@@ -356,7 +417,7 @@ def test_grouped_matmul_kernels_multiply_at_the_precision_asked(precision):
     jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(
         jnp.ones((32, 16)), jnp.ones((4, 16, 16))).jaxpr
     want = None if precision is None else (precision, precision)
-    assert _kernel_dots(jaxpr, {}) == {"expert_gmm": [want, want],
+    assert _kernel_dots(jaxpr) == {"expert_gmm": [want, want],
                                        "expert_tgmm": [want]}
 
 
