@@ -108,21 +108,6 @@ def main() -> None:
             pts = ";".join(f"{r}:{l:.3f}:{a:.3f}" for r, l, a in curve[::3])
             print(f"curve_vgg,{alg},{pts}")
 
-    # --- roofline table (reads the dry-run sweep output if present) --------
-    import os
-    for path in ("dryrun_singlepod.json", "dryrun_multipod.json"):
-        if os.path.exists(path):
-            with open(path) as f:
-                data = json.load(f)
-            print(f"\n# roofline terms from {path} "
-                  f"({len(data['records'])} records)")
-            print("arch,shape,t_compute_s,t_memory_s,t_collective_s,"
-                  "bottleneck,useful_ratio")
-            for r in data["records"]:
-                print(f"{r['arch']},{r['shape']},{r['t_compute_s']:.3e},"
-                      f"{r['t_memory_s']:.3e},{r['t_collective_s']:.3e},"
-                      f"{r['bottleneck']},{r['useful_flops_ratio']:.2f}")
-
 
 if __name__ == "__main__":
     main()

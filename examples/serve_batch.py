@@ -1,6 +1,6 @@
 """Serving example: batched autoregressive decoding with KV/SSM caches for
 any assigned architecture (reduced size), including the sliding-window
-long-context mode used by the long_500k dry-run shape.
+long-context mode.
 
   PYTHONPATH=src python examples/serve_batch.py --arch rwkv6-7b
   PYTHONPATH=src python examples/serve_batch.py --arch granite-8b --window 64

@@ -452,10 +452,6 @@ class FedConfig:
     transport: str = "dense"         # registered transport plugin name
     wire_dtype: str = "f32"          # registered wire codec plugin name
     staleness: int = 0               # gossip bounded delay (0 = synchronous)
-    # force the wire-dtype cast roundtrip on backends where it would
-    # otherwise no-op-fuse (CPU simulation has no physical wire) —
-    # wire-precision studies; see transport._fused_wire
-    simulate_wire: bool = False
     # --- vehicular mobility (repro.mobility) ---------------------------------
     # None (or kind="static"): one frozen graph, mixing hoisted out of the
     # round scan. Otherwise per-round radio-range topologies drive a

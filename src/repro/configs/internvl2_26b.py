@@ -1,7 +1,7 @@
 """internvl2-26b [vlm] — InternViT + InternLM2 backbone. [arXiv:2404.16821]
 
-Vision frontend (InternViT) is a STUB per spec: input_specs() provides
-precomputed patch embeddings; this config is the language backbone.
+Vision frontend (InternViT) is a STUB per spec: ``repro.models.stubs``
+makes patch embeddings; this config is the language backbone.
 """
 from repro.configs.base import ModelConfig
 

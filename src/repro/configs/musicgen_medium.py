@@ -1,7 +1,7 @@
 """musicgen-medium [audio] — decoder-only over EnCodec tokens. [arXiv:2306.05284]
 
-EnCodec frontend is a STUB per spec: input_specs() provides precomputed
-frame embeddings / codec token ids; this config is the decoder backbone.
+EnCodec frontend is a STUB per spec: ``repro.models.stubs`` makes frame
+embeddings / codec token ids; this config is the decoder backbone.
 """
 from repro.configs.base import ModelConfig
 

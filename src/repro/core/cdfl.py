@@ -27,17 +27,12 @@ The round pipeline is FLAT-RESIDENT for every algorithm (dpsgd
 included): params and Adam moments live in lane-padded ``(K, P)``
 buffers (``FedState.opt`` is a :class:`repro.optim.FlatAdamState`), the
 consensus exchange and the scan carry operate on the buffers directly,
-and params are packed once per run — not once per round. Whether the
-LOCAL STEPS also run in flat space follows the backend
-(``build_trainer(flat_local=...)``): on accelerators the fused flat
-Adam replaces 3 x n_leaves small ops per step and only the
-forward/backward reads pytree slice views; on CPU the step loop runs
-in leaf space (XLA:CPU's slice/pack lowering makes per-step buffer
-views a measured pessimization) with a one-time conversion at the scan
-boundary. Both lowerings are elementwise the same arithmetic. dpsgd —
-which gossips every SGD step, not once per round — follows the same
-split: its flat lowering mixes the resident buffer between flat Adam
-steps, its CPU lowering keeps the leaf-wise per-step mix.
+and params are packed once per run — not once per round. The LOCAL
+STEPS run in flat space too, on every backend: one fused flat-Adam
+pass a step updates the buffer and its moments, and only the
+forward/backward reads pytree slice views of the buffer. dpsgd — which
+gossips every SGD step, not once per round — mixes the resident buffer
+between flat Adam steps.
 
 Mixing weights come in two FORMATS (``FedConfig.mixing_format``):
 dense ``(K, K)`` eta matrices (default, bit-identical to previous
@@ -91,7 +86,7 @@ from repro.hierarchy import mixing as hier_lib
 from repro.ingest import scenarios as ingest_scenarios
 from repro.ingest import sketches as ingest_sketches
 from repro.ingest import weighting as ingest_weighting
-from repro.optim import FlatAdamState, adam, flat_adam
+from repro.optim import flat_adam
 
 
 # fleets whose parameters (all nodes) take more bytes than this run their
@@ -157,8 +152,7 @@ def _node_sketches(node_items, fed: FedConfig):
 
 def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                   eval_fn: Optional[Callable] = None,
-                  transport: Any = None,
-                  flat_local: Optional[bool] = None) -> Trainer:
+                  transport: Any = None) -> Trainer:
     """loss_fn(params, batch) -> scalar loss, or (loss, aux) with aux a
     dict of counters (summed over local steps and nodes into each round's
     ``metrics["counters"]``; a scalar loss traces the same program as
@@ -173,24 +167,14 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
 
     ``transport``: a ``repro.core.transport`` instance overriding the one
     ``fed.transport``/``fed.wire_dtype``/``fed.staleness`` select.
-    fedavg (centralized server average) and dpsgd (per-step leaf-wise
-    gossip) bypass the transport; see ``mix_buf``/``round_body``.
+    fedavg (centralized server average) and dpsgd (per-step gossip)
+    bypass the transport; see ``mix_buf``/``round_body``.
 
-    ``flat_local``: run the LOCAL STEPS on the flat buffer (params and
-    Adam moments never leave the (K, P) buffers; gradients are packed
-    once per step) vs. in leaf space (pytree params/moments inside the
-    step loop, converted at the scan boundary). ``None`` picks per
-    backend: flat on accelerators — where it removes ~3 x n_leaves
-    small ops per local step — and leaf space on CPU, where XLA:CPU's
-    slice/pack lowering makes the per-step buffer views a measured
-    ~10% end-to-end pessimization. For f32 params the two lowerings
-    are elementwise the same arithmetic (tested to 1e-6 incl. moments;
-    tests/test_cdfl.py). Sub-f32 param leaves (bf16) differ by design:
-    the flat loop keeps the f32 master buffer between steps, the leaf
-    loop requantizes params to leaf dtype after every Adam step — pin
-    ``flat_local`` explicitly if cross-backend reproducibility of a
-    bf16-param model matters. Either way the FedState carries the
-    moments as flat (K, P) buffers.
+    The local steps run on the flat buffer: params and Adam moments
+    never leave the (K, P) buffers, and gradients are packed once per
+    step. Sub-f32 param leaves (bf16) read their slice views at leaf
+    dtype while the buffer keeps the f32 master between steps; the
+    FedState's params are cast to leaf dtype only at unpack.
     """
     registry.ensure_plugins()
     spec = registry.algorithms.get(fed.algorithm)
@@ -202,7 +186,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
     mobile = fed.mobility is not None and fed.mobility.kind != "static"
     # Fault injection / robust mixing operate on the once-per-round
     # full-buffer wire exchange, which fedavg (server average), dpsgd
-    # (per-step leaf gossip) and cdfa_m (prefix-only wire) don't have.
+    # (per-step gossip) and cdfa_m (prefix-only wire) don't have.
     fault_capable = uses_transport and fed.algorithm != "cdfa_m"
     if fed.faults is not None and fed.faults.active and not fault_capable:
         raise ValueError(
@@ -237,7 +221,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                 raise ValueError(
                     f"{fed.algorithm} does not use the consensus "
                     f"transport (fedavg: server average; dpsgd: per-step "
-                    f"leaf-wise gossip) — got transport={fed.transport}/"
+                    f"gossip) — got transport={fed.transport}/"
                     f"{fed.wire_dtype}/staleness={fed.staleness}")
             transport = transport_lib.DenseTransport()
     # Byzantine-robust mixing replaces the eq. 5 exchange with a
@@ -284,13 +268,9 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                 "discount does not compose with it (use IngestConfig("
                 "weighting='sampling'|'none', drift_threshold=0))")
     # Every algorithm runs the flat-resident pipeline: params AND Adam
-    # moments live in (K, P) FedState buffers, the consensus exchange
-    # and the scan carry are flat, and the local-step loop
-    # representation follows ``flat_local`` (see docstring). dpsgd's
-    # per-step gossip rides the same buffers in its flat lowering and
-    # stays leaf-wise in its CPU lowering.
-    opt = adam(train.learning_rate, train.beta1, train.beta2, train.eps,
-               train.weight_decay, train.grad_clip)
+    # moments live in (K, P) FedState buffers, and the consensus
+    # exchange, the local steps (dpsgd's per-step gossip included) and
+    # the scan carry all operate on them.
     fopt = flat_adam(train.learning_rate, train.beta1, train.beta2,
                      train.eps, train.weight_decay, train.grad_clip)
     fmt = getattr(fed, "mixing_format", "dense")
@@ -306,8 +286,6 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
             "mixing_format='hierarchical' needs the dense transport's "
             "resident buffer (co-member + leader gathers); got "
             f"{type(transport).__name__}")
-    if flat_local is None:
-        flat_local = jax.default_backend() != "cpu"
     # Partially unrolling the local-step scan lets XLA build larger fusion
     # clusters (fewer per-op dispatches) without decode-time blowup;
     # unroll 4 measures ~10% over unroll 2 on the flat-resident loop
@@ -372,7 +350,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
     # to previous builds); a traced scalar overrides it at runtime so
     # the batched driver can vmap V learning rates through ONE program.
 
-    def _flat_local_step(vec, ost, batch, layout, lr=None):
+    def _local_step(vec, ost, batch, layout, lr=None):
         """One local Adam step with params resident in the flat (P,)
         vector: the forward/backward reads pytree slice VIEWS of the
         buffer, the gradient pytree is flattened ONCE, and the fused
@@ -385,17 +363,9 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
             vec, ost = fopt.update(gvec, ost, vec, lr=lr)
         return vec, ost, loss, aux
 
-    def _leaf_local_step(p, o, batch, lr=None):
-        """One leaf-space local Adam step (pytree params/moments)."""
-        with jax.named_scope("cdfl.grad"):
-            (loss, aux), grads = value_and_grad(p, batch)
-        with jax.named_scope("cdfl.adam"):
-            p, o = opt.update(grads, o, p, lr=lr)
-        return p, o, loss, aux
-
-    # ONE loop scaffold serves both representations and both batch
-    # sources: step3(params_repr, opt_repr, batch) -> (..., loss, aux);
-    # the loop returns each node's mean loss and its aux summed over steps.
+    # ONE loop scaffold serves both batch sources: step3(vec, ost, batch)
+    # -> (vec, ost, loss, aux); the loop returns each node's mean loss and
+    # its aux summed over steps.
 
     def _run_local_steps(step3, p0, o0, batches):
         """vmap over nodes of a scan over local steps.
@@ -426,66 +396,36 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                 lambda a: a.sum(0), aux)
         return jax.vmap(one_node)(p0, o0, data, idx)
 
-    def flat_local_updates(buf, opt_state, layout, batches):
+    def local_updates(buf, opt_state, layout, batches):
         return _run_local_steps(
-            lambda v, o, b: _flat_local_step(v, o, b, layout),
+            lambda v, o, b: _local_step(v, o, b, layout),
             buf, opt_state, batches)
 
-    def flat_local_updates_from_idx(buf, opt_state, layout, data, idx,
-                                    lr=None):
+    def local_updates_from_idx(buf, opt_state, layout, data, idx,
+                               lr=None):
         return _run_local_steps_from_idx(
-            lambda v, o, b: _flat_local_step(v, o, b, layout, lr=lr),
+            lambda v, o, b: _local_step(v, o, b, layout, lr=lr),
             buf, opt_state, data, idx)
-
-    # -- leaf-space local steps (the CPU lowering of the same pipeline) --
-    # The step loop carries pytree params/moments (XLA:CPU keeps leaves
-    # in gemm-preferred layouts and skips the per-step slice/pack
-    # traffic); conversion to/from the flat FedState representation
-    # happens ONCE at the loop boundary via unflatten/flatten — bit-the-
-    # same Adam arithmetic, just a different storage layout in flight.
-
-    def _leaf_opt_state(ost: FlatAdamState, layout):
-        from repro.optim.adam import AdamState
-        return AdamState(step=ost.step,
-                         m=flatten.unflatten(ost.m, layout, cast=False),
-                         v=flatten.unflatten(ost.v, layout, cast=False))
-
-    def _flat_opt_state(o, layout) -> FlatAdamState:
-        return FlatAdamState(step=o.step,
-                             m=flatten.flatten(o.m, layout)[0],
-                             v=flatten.flatten(o.v, layout)[0])
-
-    def leaf_local_updates(params, opt_state, batches):
-        return _run_local_steps(_leaf_local_step, params, opt_state,
-                                batches)
-
-    def leaf_local_updates_from_idx(params, opt_state, data, idx,
-                                    lr=None):
-        return _run_local_steps_from_idx(
-            lambda p, o, b: _leaf_local_step(p, o, b, lr=lr),
-            params, opt_state, data, idx)
 
     # -- dpsgd (Lian et al. 17): gossip-average every SGD step ---------------
     # The per-step mix couples the nodes, so dpsgd cannot vmap a
     # per-node scan like the scaffolds above: it scans over STEPS with
     # the node axis inside (mix across nodes, then one vmapped Adam
-    # step). Same flat/leaf split as the round algorithms: the flat
-    # lowering mixes the resident (K, P) buffer between fused flat-Adam
-    # steps; the CPU lowering mixes leaf-wise (reshaped (K, -1) views)
-    # with pytree moments, converted at the loop boundary.
+    # step), mixing the resident (K, P) buffer between fused flat-Adam
+    # steps.
 
-    def _dpsgd_mix(buf2d, eta, gamma):
-        """Per-step gossip on any (K, M) 2-D view — dense delta-form
+    def _dpsgd_mix(buf, eta, gamma):
+        """Per-step gossip on the (K, P) buffer — dense delta-form
         mix, the sparse top-D gather, or the two-tier hierarchical mix,
         matching the wire format."""
         if hier_fmt:
             # no re-merge burst per STEP: dpsgd already mixes
             # local_steps times a round, which IS the catch-up
-            return hier_lib.hier_mix_flat(buf2d, eta, gamma,
+            return hier_lib.hier_mix_flat(buf, eta, gamma,
                                           burst_passes=0)
         if isinstance(eta, topology.SparseEta):
-            return flatten.sparse_mix_flat(buf2d, eta.idx, eta.val, gamma)
-        return flatten.mix_flat(buf2d, eta, gamma)
+            return flatten.sparse_mix_flat(buf, eta.idx, eta.val, gamma)
+        return flatten.mix_flat(buf, eta, gamma)
 
     def _dpsgd_steps(step_all, p0, o0, xs):
         def step(carry, x):
@@ -495,40 +435,19 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                                       unroll=unroll_for(p0))
         return p, o, losses.mean() * jnp.ones((fed.num_nodes,))
 
-    def _dpsgd_flat_step(buf, ost, batch, eta, gamma, layout, lr=None):
+    def _dpsgd_step(buf, ost, batch, eta, gamma, layout, lr=None):
         buf = _dpsgd_mix(buf, eta, gamma)
         buf, ost, losses, _ = jax.vmap(
-            lambda v, o, b: _flat_local_step(v, o, b, layout, lr=lr)
+            lambda v, o, b: _local_step(v, o, b, layout, lr=lr)
         )(buf, ost, batch)
         return buf, ost, losses.mean()
-
-    def _dpsgd_leaf_step(p, o, batch, eta, gamma, lr=None):
-        def mix_leaf(leaf):
-            flat = leaf.reshape(leaf.shape[0], -1)
-            return _dpsgd_mix(flat, eta, gamma).reshape(leaf.shape)
-        p = jax.tree.map(mix_leaf, p)
-        (losses, _), grads = jax.vmap(value_and_grad)(p, batch)
-        p, o = jax.vmap(lambda g, o_, p_: opt.update(g, o_, p_, lr=lr)
-                        )(grads, o, p)
-        return p, o, losses.mean()
-
-    # Both drivers below take and return ``opt_state`` in the ambient
-    # step-loop representation — FlatAdamState when ``flat_local``,
-    # leaf AdamState otherwise — matching the main-branch convention so
-    # the scan boundary converts once, never per round.
 
     def dpsgd_updates(buf, opt_state, layout, eta, gamma, batches):
         """One dpsgd round on host-fed batches (leaves (K, S, B, ...))."""
         bt = jax.tree.map(lambda l: jnp.swapaxes(l, 0, 1), batches)
-        if flat_local:
-            return _dpsgd_steps(
-                lambda v, o, b: _dpsgd_flat_step(v, o, b, eta, gamma,
-                                                 layout),
-                buf, opt_state, bt)
-        p, o, loss = _dpsgd_steps(
-            lambda p, o, b: _dpsgd_leaf_step(p, o, b, eta, gamma),
-            flatten.unflatten(buf, layout), opt_state, bt)
-        return flatten.flatten(p, layout)[0], o, loss
+        return _dpsgd_steps(
+            lambda v, o, b: _dpsgd_step(v, o, b, eta, gamma, layout),
+            buf, opt_state, bt)
 
     def dpsgd_updates_from_idx(buf, opt_state, layout, eta, gamma,
                                data, idx, lr=None):
@@ -537,17 +456,10 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         def batch_of(i):  # i: (K, B) this step's per-node indices
             return jax.tree.map(
                 lambda a: jax.vmap(lambda ad, j: ad[j])(a, i), data)
-        steps_idx = jnp.swapaxes(idx, 0, 1)
-        if flat_local:
-            return _dpsgd_steps(
-                lambda v, o, i: _dpsgd_flat_step(v, o, batch_of(i), eta,
-                                                 gamma, layout, lr=lr),
-                buf, opt_state, steps_idx)
-        p, o, loss = _dpsgd_steps(
-            lambda p, o, i: _dpsgd_leaf_step(p, o, batch_of(i), eta,
-                                             gamma, lr=lr),
-            flatten.unflatten(buf, layout), opt_state, steps_idx)
-        return flatten.flatten(p, layout)[0], o, loss
+        return _dpsgd_steps(
+            lambda v, o, i: _dpsgd_step(v, o, batch_of(i), eta,
+                                             gamma, layout, lr=lr),
+            buf, opt_state, jnp.swapaxes(idx, 0, 1))
 
     def mix_buf(buf, sizes, eta, gamma, layout, tstate, rnd, sent=None):
         """The round's consensus exchange on the flat (K, P) buffer,
@@ -580,9 +492,8 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
             # read the — possibly fault-overridden — codec'd frames,
             # the self-cancellation keeps the node's own clean payload),
             # then run intra + leader tiers + re-merge burst in one shot
-            w_nb, w_self = transport_lib._wire_pair(
-                transport.codec, buf, sent,
-                getattr(transport, "simulate_wire", False))
+            w_nb, w_self = transport_lib._wire_pair(transport.codec, buf,
+                                                    sent)
             mixed = hier_lib.hier_mix_flat(
                 buf, eta, gamma, wire=w_nb, wire_self=w_self,
                 use_kernel=getattr(transport, "use_kernel", None),
@@ -593,8 +504,7 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
             # (codec'd like any wire traffic) instead of eq. 5
             payload = buf if sent is None else sent
             codec = transport.codec
-            if not transport_lib._cast_noops(
-                    codec, buf, getattr(transport, "simulate_wire", False)):
+            if not transport_lib._cast_noops(codec, buf):
                 payload = codec.roundtrip(payload)
             return robust_fn(buf, payload, eta, gamma), tstate
         # cdfl, cfa, metropolis — eq. (5)
@@ -619,38 +529,22 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
 
     def round_body(state: FedState, batches, eta, gamma):
         """One full round given precomputed mixing weights. The consensus
-        exchange runs on the flat buffer (one fused (K,K)@(K,P) mix).
-
-        NOTE: the per-round driver crosses the FedState boundary every
-        call, so with the leaf-space lowering (CPU) it converts the
-        flat moments to leaf space and back each round — unavoidable
-        per-call overhead that ``run_rounds`` hoists to the scan
-        boundary; multi-round work belongs on the scan driver."""
-        # flat-resident round: ONE pack at entry, the mix and (with
-        # flat_local) the local Adam steps on the (K, P) buffer, ONE
-        # unpack into the returned FedState
+        exchange runs on the flat buffer (one fused (K,K)@(K,P) mix)."""
+        # flat-resident round: ONE pack at entry, the mix and the local
+        # Adam steps on the (K, P) buffer, ONE unpack into the returned
+        # FedState
         layout = flatten.make_layout(state.params)
         buf, _ = flatten.flatten(state.params, layout)
         aux = {}
         if fed.algorithm == "dpsgd":
             tstate = state.tstate
-            o0 = (state.opt if flat_local
-                  else _leaf_opt_state(state.opt, layout))
-            buf, o, loss = dpsgd_updates(buf, o0, layout, eta, gamma,
-                                         batches)
-            opt_state = o if flat_local else _flat_opt_state(o, layout)
+            buf, opt_state, loss = dpsgd_updates(buf, state.opt, layout,
+                                                 eta, gamma, batches)
         else:
             mixed, tstate = mix_buf(buf, state.sizes, eta, gamma, layout,
                                     state.tstate, state.round)
-            if flat_local:
-                buf, opt_state, loss, aux = flat_local_updates(
-                    mixed, state.opt, layout, batches)
-            else:
-                params, o, loss, aux = leaf_local_updates(
-                    flatten.unflatten(mixed, layout),
-                    _leaf_opt_state(state.opt, layout), batches)
-                buf = flatten.flatten(params, layout)[0]
-                opt_state = _flat_opt_state(o, layout)
+            buf, opt_state, loss, aux = local_updates(
+                mixed, state.opt, layout, batches)
         metrics = _flat_metrics(buf, layout, loss, gamma, aux)
         new_state = FedState(flatten.unflatten(buf, layout), opt_state,
                              state.ratios, state.sizes,
@@ -815,16 +709,12 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
         # snapshots) — all donated. Params are packed ONCE before the
         # scan and unpacked ONCE after it; the post-local-step
         # write-back IS the buffer the next round's mix consumes (no
-        # per-round pack/unpack pass). With ``flat_local`` the moments
-        # ride the carry as (K, P) buffers and only the forward/
-        # backward reads pytree slice views; the CPU lowering instead
-        # carries the moments in leaf space (see build_trainer) —
-        # converted here ONCE at the scan boundary, never per round.
+        # per-round pack/unpack pass). The moments ride the carry as
+        # (K, P) buffers and only the forward/backward reads pytree
+        # slice views.
         with jax.named_scope("cdfl.pack"):
             layout = flatten.make_layout(state.params)
             buf0, _ = flatten.flatten(state.params, layout)
-            opt0 = (state.opt if flat_local
-                    else _leaf_opt_state(state.opt, layout))
         # ``fault_xs`` is () on the fault-free path (the scan carry and
         # body then trace to exactly the pre-fault graph) or the
         # per-round (health, byz, corrupt, straggle) stacks — the
@@ -926,17 +816,8 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
                                             gamma_r, layout, tstate, rnd,
                                             sent=sent)
                 with jax.named_scope("cdfl.local"):
-                    if flat_local:
-                        buf, opt_state, loss, aux = (
-                            flat_local_updates_from_idx(
-                                mixed, opt_state, layout, data, idx_r,
-                                lr=lr))
-                    else:
-                        params, opt_state, loss, aux = (
-                            leaf_local_updates_from_idx(
-                                flatten.unflatten(mixed, layout),
-                                opt_state, data, idx_r, lr=lr))
-                        buf = flatten.flatten(params, layout)[0]
+                    buf, opt_state, loss, aux = local_updates_from_idx(
+                        mixed, opt_state, layout, data, idx_r, lr=lr)
             with jax.named_scope("cdfl.metrics"):
                 metrics = _flat_metrics(buf, layout, loss, gamma_r, aux)
                 if hier_fmt:
@@ -975,11 +856,9 @@ def build_trainer(loss_fn: Callable, fed: FedConfig, train: TrainConfig,
             return (buf, opt_state, rnd + 1, tstate, prev, ist), metrics
 
         (buf, opt_state, rnd, tstate, prev, ist), metrics = jax.lax.scan(
-            body, (buf0, opt0, state.round, state.tstate, prev0, ing0),
+            body, (buf0, state.opt, state.round, state.tstate, prev0, ing0),
             (idx, etas, gammas, fault_xs))
         with jax.named_scope("cdfl.pack"):
-            if not flat_local:
-                opt_state = _flat_opt_state(opt_state, layout)
             final = FedState(flatten.unflatten(buf, layout), opt_state,
                              state.ratios, state.sizes, rnd, tstate, prev,
                              ist)

@@ -159,39 +159,26 @@ class _FlatTransport:
         return self.codec.wire_bytes(layout)
 
 
-def _fused_wire(codec: WireCodec, buf: jax.Array,
-                simulate: bool = False):
+def _fused_wire(codec: WireCodec, buf: jax.Array):
     """The ``wire`` argument for :func:`flatten.mix_flat`: ``None`` for
-    the identity codec, the raw cast for pure-cast codecs (the fused
-    kernel upcasts in VMEM), the decoded roundtrip otherwise.
-
-    Pure-cast codecs are GATED to backends where the fused cast wins:
-    on TPU the kernel reads the half-width wire slab straight from HBM
-    (real byte savings), but in CPU simulation there is no wire — the
-    cast is two extra full passes over the buffer for nothing (BENCH:
-    dense bf16 1364 us vs f32 834 us), so it no-op-fuses to the f32
-    master. ``simulate=True`` forces the cast roundtrip anyway (wire
-    precision studies; bf16-drift tests). Roofline byte pricing always
-    reflects the codec, never this execution shortcut."""
+    the identity codec and for a cast to the buffer's own dtype, the raw
+    cast for other pure-cast codecs (the fused kernel reads the narrow
+    wire slab and upcasts in VMEM), the decoded roundtrip otherwise."""
     if codec.cast_dtype is not None:
-        if _cast_noops(codec, buf, simulate):
+        if _cast_noops(codec, buf):
             return None
         return codec.encode(buf)
     return codec.roundtrip(buf)
 
 
-def _cast_noops(codec: WireCodec, buf: jax.Array, simulate: bool) -> bool:
-    """Whether a pure-cast codec's roundtrip is skipped for this
-    exchange: identity casts always; any cast on CPU simulation unless
-    the caller forces wire simulation (see :func:`_fused_wire`)."""
-    if codec.cast_dtype is None:
-        return False
-    if jnp.dtype(codec.cast_dtype) == buf.dtype:
-        return True
-    return jax.default_backend() == "cpu" and not simulate
+def _cast_noops(codec: WireCodec, buf: jax.Array) -> bool:
+    """Whether a pure-cast codec's roundtrip is the identity for this
+    buffer: its cast dtype is the buffer's own."""
+    return (codec.cast_dtype is not None
+            and jnp.dtype(codec.cast_dtype) == buf.dtype)
 
 
-def _wire_pair(codec: WireCodec, buf: jax.Array, sent, simulate: bool):
+def _wire_pair(codec: WireCodec, buf: jax.Array, sent):
     """``(wire, wire_self)`` for :func:`_mix`. Fault-free (``sent`` is
     None): the fused wire and no separate self payload. Fault-injected:
     per-node wire payloads (``sent``) diverge from the master buffer, so
@@ -200,8 +187,8 @@ def _wire_pair(codec: WireCodec, buf: jax.Array, sent, simulate: bool):
     never receives itself). The codec applies per GATHERED row on the
     sparse path: the gather reads the codec'd payload matrix."""
     if sent is None:
-        return _fused_wire(codec, buf, simulate), None
-    if _cast_noops(codec, buf, simulate):
+        return _fused_wire(codec, buf), None
+    if _cast_noops(codec, buf):
         return sent, buf
     return codec.roundtrip(sent), codec.roundtrip(buf)
 
@@ -221,18 +208,13 @@ def _mix(buf, eta, gamma, use_kernel, wire, wire_self):
 @dataclasses.dataclass(frozen=True)
 class DenseTransport(_FlatTransport):
     """Fused dense exchange: every node mixes every neighbor in one
-    ``(K,K)@(K,P)`` operation (the eta matrix encodes the topology).
-
-    ``simulate_wire`` forces the wire-dtype cast roundtrip on backends
-    where it would otherwise no-op-fuse (see :func:`_fused_wire`)."""
+    ``(K,K)@(K,P)`` operation (the eta matrix encodes the topology)."""
 
     wire_dtype: str = "f32"
     use_kernel: bool | None = None      # None -> auto (TPU)
-    simulate_wire: bool = False
 
     def exchange(self, buf, eta, gamma, state=(), rnd=None, sent=None):
-        wire, wire_self = _wire_pair(self.codec, buf, sent,
-                                     self.simulate_wire)
+        wire, wire_self = _wire_pair(self.codec, buf, sent)
         return _mix(buf, eta, gamma, self.use_kernel, wire,
                     wire_self), state
 
@@ -247,14 +229,10 @@ class RingShardTransport(_FlatTransport):
     vector is ppermuted in ``shards`` chunks so the mix of chunk j
     overlaps the transfer of chunk j+1 (XLA async collective-permute).
     Simulation mode has no transfer to hide and ignores it.
-
-    ``simulate_wire``: as on :class:`DenseTransport` — pure-cast codecs
-    no-op-fuse in CPU simulation unless forced.
     """
 
     wire_dtype: str = "f32"
     shards: int = 1
-    simulate_wire: bool = False
 
     def exchange(self, buf, eta, gamma, state=(), rnd=None, sent=None):
         k = buf.shape[0]
@@ -274,7 +252,7 @@ class RingShardTransport(_FlatTransport):
         # self-cancellation term stays the node's own clean buffer)
         src = buf if sent is None else sent
         codec = self.codec
-        if _cast_noops(codec, buf, self.simulate_wire):
+        if _cast_noops(codec, buf):
             w_self = buf
             w_prev = jnp.roll(src, 1, axis=0)
             w_next = jnp.roll(src, -1, axis=0)
@@ -303,16 +281,11 @@ class GossipTransport(_FlatTransport):
     ``staleness=0`` is stateless and bit-identical to
     :class:`DenseTransport`."""
 
-    # see DenseTransport. NOTE: with staleness > 0 the snapshot STATE is
-    # genuinely stored at wire size on every backend (a layout choice
-    # that must stay backend-independent for checkpoint portability), so
-    # the s > 0 exchange always pays the codec roundtrip; the documented
-    # "staleness -> 0 recovers the synchronous form term by term" holds
-    # exactly under simulate_wire=True (or on TPU), while the default
-    # CPU simulation runs the s = 0 case at f32.
+    # the snapshot STATE is stored at wire size, and the s > 0 exchange
+    # pays the codec roundtrip on its self term too, so staleness -> 0
+    # recovers the synchronous form term by term
     staleness: int = 0
     wire_dtype: str = "f32"
-    simulate_wire: bool = False
     use_kernel: bool | None = None      # None -> auto (TPU)
 
     @property
@@ -330,8 +303,7 @@ class GossipTransport(_FlatTransport):
     def exchange(self, buf, eta, gamma, state=(), rnd=None, sent=None):
         codec = self.codec
         if self.staleness == 0:
-            wire, wire_self = _wire_pair(codec, buf, sent,
-                                         self.simulate_wire)
+            wire, wire_self = _wire_pair(codec, buf, sent)
             return _mix(buf, eta, gamma, self.use_kernel, wire,
                         wire_self), state
         if rnd is None:
@@ -365,9 +337,7 @@ class GossipTransport(_FlatTransport):
 
 @transports.register("dense")
 def _make_dense(fed) -> DenseTransport:
-    return DenseTransport(wire_dtype=getattr(fed, "wire_dtype", "f32"),
-                          simulate_wire=getattr(fed, "simulate_wire",
-                                                False))
+    return DenseTransport(wire_dtype=getattr(fed, "wire_dtype", "f32"))
 
 
 @transports.register("ring")
@@ -378,17 +348,13 @@ def _make_ring(fed) -> RingShardTransport:
         raise ValueError(
             f"ring transport moves data only between ring neighbors; "
             f"topology={fed.topology!r} needs the dense transport")
-    return RingShardTransport(wire_dtype=getattr(fed, "wire_dtype", "f32"),
-                              simulate_wire=getattr(fed, "simulate_wire",
-                                                    False))
+    return RingShardTransport(wire_dtype=getattr(fed, "wire_dtype", "f32"))
 
 
 @transports.register("gossip")
 def _make_gossip(fed) -> GossipTransport:
     return GossipTransport(staleness=getattr(fed, "staleness", 0),
-                           wire_dtype=getattr(fed, "wire_dtype", "f32"),
-                           simulate_wire=getattr(fed, "simulate_wire",
-                                                 False))
+                           wire_dtype=getattr(fed, "wire_dtype", "f32"))
 
 
 # Back-compat view of the pre-registry tuple (iterates names).

@@ -1,2 +1,2 @@
-# Launch layer: meshes, sharding rules, step builders, dry-run, drivers.
+# Launch layer: meshes, sharding rules, roofline terms, drivers.
 from repro.launch import mesh, roofline, sharding  # noqa: F401

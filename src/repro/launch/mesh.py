@@ -1,69 +1,17 @@
-"""Production meshes and the federated re-view.
+"""The consensus ring over a federated mesh.
 
-make_production_mesh: the spec-mandated (16,16)/("data","model") single-pod
-mesh (256 chips) and (2,16,16)/("pod","data","model") two-pod mesh (512).
-
-make_fed_mesh: the SAME devices re-viewed as ("fed","dp","tp") — one
-federated node (paper: base station) per fed index, internally data-
-parallel (dp) and tensor-parallel (tp). Multi-pod: ("pod","fed","dp","tp"),
-with the consensus ring spanning the (pod, fed) product so neighbor
-exchange crosses the DCN exactly twice per round (ring wrap), which is
-what the multi-pod dry-run exercises.
+A federated mesh has axes ("fed","dp","tp") — one federated node (paper:
+base station) per fed index, internally data-parallel (dp) and
+tensor-parallel (tp) — or ("pod","fed","dp","tp"), with the consensus
+ring spanning the (pod, fed) product so neighbor exchange crosses the
+DCN exactly twice per round (ring wrap).
 
 Functions, not module constants: importing this module never touches jax
 device state.
 """
 from __future__ import annotations
 
-import numpy as np
-
-import jax
 from jax.sharding import Mesh
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = int(np.prod(shape))
-    devices = jax.devices()
-    if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices, have {len(devices)} — run under "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=512 "
-            f"(see launch/dryrun.py)")
-    dev = np.asarray(devices[:n]).reshape(shape)
-    return Mesh(dev, axes)
-
-
-def make_fed_mesh(mesh: Mesh, fed: int) -> Mesh:
-    """Re-view a production mesh's devices as a federated mesh.
-
-    Single-pod (16,16):  ("fed","dp","tp") = (fed, 16//fed, 16)
-    Multi-pod (2,16,16): ("pod","fed","dp","tp") = (2, fed//2, 32//fed, 16)
-    — fed nodes are split across pods; the ring spans ('pod','fed').
-    """
-    dev = mesh.devices
-    if dev.ndim == 2:                      # single pod
-        data, model = dev.shape
-        if data % fed:
-            raise ValueError(f"fed={fed} must divide data axis {data}")
-        shape = (fed, data // fed, model)
-        axes = ("fed", "dp", "tp")
-    else:                                  # multi pod
-        pods, data, model = dev.shape
-        if fed % pods:
-            raise ValueError(f"fed={fed} must be a multiple of pods={pods}")
-        per_pod = fed // pods
-        if data % per_pod:
-            raise ValueError(f"fed/pod={per_pod} must divide data={data}")
-        shape = (pods, per_pod, data // per_pod, model)
-        axes = ("pod", "fed", "dp", "tp")
-    return Mesh(dev.reshape(shape), axes)
-
-
-def fed_axes(mesh: Mesh) -> tuple:
-    """The named axes the consensus ring spans."""
-    return ("pod", "fed") if "pod" in mesh.axis_names else ("fed",)
 
 
 def fed_ring_perms(mesh: Mesh) -> tuple[list, list]:
@@ -76,14 +24,6 @@ def fed_ring_perms(mesh: Mesh) -> tuple[list, list]:
     fwd = [(i, (i + 1) % n) for i in range(n)]
     bwd = [(i, (i - 1) % n) for i in range(n)]
     return fwd, bwd
-
-
-def dp_size(mesh: Mesh) -> int:
-    return mesh.shape["dp"]
-
-
-def tp_size(mesh: Mesh) -> int:
-    return mesh.shape["tp"]
 
 
 def fed_size(mesh: Mesh) -> int:

@@ -1,28 +1,22 @@
-"""Roofline-term extraction from a compiled dry-run artifact.
+"""Roofline terms of a compiled program, per chip.
 
-    compute term    = HLO_FLOPs / peak_FLOP/s          (per chip)
-    memory term     = HLO_bytes / HBM_bw               (per chip)
-    collective term = wire_bytes / link_bw             (per chip)
+    compute term    = HLO_FLOPs / peak_FLOP/s
+    memory term     = HLO_bytes / HBM_bw
+    collective term = wire_bytes / link_bw
 
-cost_analysis() is post-SPMD, i.e. per-device; collective bytes are not in
-cost_analysis, so we parse the compiled HLO text and sum the result-shape
-bytes of every collective op, weighted by a wire factor (ring all-reduce
-moves ~2x the buffer; the others ~1x). The per-chip peaks come from
+Collective bytes are weighted by a wire factor (ring all-reduce moves
+~2x the buffer; the others ~1x). The per-chip peaks come from
 :data:`PEAKS`, keyed by the device's ``device_kind``; a kind without
 published peaks is an error, never a default.
 
 The CONSENSUS share of the collective term is transport-aware: the
-compiled fed train step always lowers the dense f32 ring roll, but the
 selected ``repro.core.transport`` backend may put half the bytes on the
 wire (bf16) or restrict links to the physical ring —
 :func:`transport_consensus_bytes` prices the exchange from the
-transport's own ``wire_bytes(layout)`` so ``dryrun_*.json`` sweeps
-reflect the backend that would actually run (see
-``Roofline.with_consensus``).
+transport's own ``wire_bytes(layout)`` (see ``Roofline.with_consensus``).
 """
 from __future__ import annotations
 
-import re
 from dataclasses import dataclass, field
 from dataclasses import replace as dataclass_replace
 
@@ -54,37 +48,7 @@ def peaks(device_kind: str) -> Peaks:
             f"(known: {', '.join(sorted(PEAKS))})") from None
 
 
-_DTYPE_BYTES = {
-    "f64": 8, "f32": 4, "f16": 2, "bf16": 2,
-    "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,
-    "s8": 1, "u8": 1, "pred": 1, "c64": 8, "c128": 16,
-    "f8e4m3fn": 1, "f8e5m2": 1, "f8e4m3": 1,
-}
-
-_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter",
-                "all-to-all", "collective-permute")
 _WIRE_FACTOR = {"all-reduce": 2.0}          # ring AR ~2x; others ~1x
-
-_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-# result shape(s) precede ` <opname>(`; ops may be fused names like
-# `all-gather-start`; match the base op.
-_OP_RE = re.compile(
-    r"=\s*((?:\([^)]*\))|(?:\w+\[[\d,]*\][^ ]*))\s+"
-    r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start)?\(")
-
-
-def _shape_bytes(text: str) -> int:
-    total = 0
-    for dt, dims in _SHAPE_RE.findall(text):
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
 
 
 @dataclass
@@ -96,20 +60,6 @@ class CollectiveStats:
     def wire_bytes(self) -> float:
         return sum(_WIRE_FACTOR.get(op, 1.0) * b
                    for op, b in self.bytes_by_op.items())
-
-    @property
-    def total(self) -> int:
-        return sum(self.count_by_op.values())
-
-
-def parse_collectives(hlo_text: str) -> CollectiveStats:
-    stats = CollectiveStats()
-    for m in _OP_RE.finditer(hlo_text):
-        shape_txt, op = m.group(1), m.group(2)
-        b = _shape_bytes(shape_txt)
-        stats.bytes_by_op[op] = stats.bytes_by_op.get(op, 0) + b
-        stats.count_by_op[op] = stats.count_by_op.get(op, 0) + 1
-    return stats
 
 
 def transport_consensus_bytes(transport, layout, adj) -> float:
@@ -141,9 +91,8 @@ class Roofline:
         """Re-price the consensus share of the collective term for the
         selected transport backend.
 
-        The measured collective-permute bytes (the lowered dense f32
-        ring roll — the only collective-permute in the fed train HLO)
-        are swapped for :func:`transport_consensus_bytes` spread over
+        The measured collective-permute bytes (the consensus ring's
+        neighbour shifts) are swapped for :func:`transport_consensus_bytes` spread over
         the node's device group. Non-consensus collectives (TP
         all-reduce/all-gather) are untouched.
         """
@@ -170,44 +119,3 @@ class Roofline:
         terms = {"compute": self.t_compute, "memory": self.t_memory,
                  "collective": self.t_collective}
         return max(terms, key=terms.get)
-
-    @property
-    def useful_ratio(self) -> float:
-        return self.model_flops / self.flops if self.flops else 0.0
-
-    def row(self) -> dict:
-        return {
-            "t_compute_s": self.t_compute,
-            "t_memory_s": self.t_memory,
-            "t_collective_s": self.t_collective,
-            "bottleneck": self.bottleneck,
-            "hlo_gflops": self.flops / 1e9,
-            "hbm_gb": self.hbm_bytes / 1e9,
-            "wire_gb": self.wire_bytes / 1e9,
-            "useful_flops_ratio": self.useful_ratio,
-            "n_collectives": self.collectives.total,
-        }
-
-
-def model_flops_per_device(cfg, shape, num_devices: int,
-                           fed_nodes: int = 0) -> float:
-    """Analytic MODEL_FLOPS: 6*N*D train (fwd+bwd), 2*N*D inference, with
-    N = active params (MoE: top-k only). D = tokens processed globally.
-    Federated: every node trains its own replica -> multiply by F."""
-    n_active = cfg.active_param_count()
-    if shape.mode == "train":
-        tokens = shape.global_batch * shape.seq_len
-        total = 6.0 * n_active * tokens
-    elif shape.mode == "prefill":
-        tokens = shape.global_batch * shape.seq_len
-        total = 2.0 * n_active * tokens
-    else:  # decode: one token per sequence
-        total = 2.0 * n_active * shape.global_batch
-    return total / num_devices
-
-
-def format_row(name: str, r: Roofline) -> str:
-    d = r.row()
-    return (f"{name:42s} {d['t_compute_s']:>10.3e} {d['t_memory_s']:>10.3e} "
-            f"{d['t_collective_s']:>10.3e} {d['bottleneck']:>10s} "
-            f"{d['useful_flops_ratio']:>6.2f} {d['n_collectives']:>4d}")

@@ -1,6 +1,5 @@
 """Batched serving driver: prefill a batch of prompts, then decode tokens
-autoregressively with the KV/SSM caches — the runnable counterpart of the
-decode dry-run shapes, at reduced size.
+autoregressively with the KV/SSM caches, at reduced size.
 
   PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-7b \
       --batch 4 --prompt-len 32 --gen 16

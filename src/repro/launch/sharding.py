@@ -17,9 +17,8 @@ the specs.
 """
 from __future__ import annotations
 
-import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def _assign(shape, axes_sizes, skip_dims=()):
@@ -110,34 +109,6 @@ def serve_param_spec(shape, mesh: Mesh, fsdp: bool = True,
     return P(*inner)
 
 
-def _leaf_name(path) -> str | None:
-    for p in reversed(path):
-        key = getattr(p, "key", getattr(p, "name", None))
-        if isinstance(key, str):
-            return key
-    return None
-
-
-def _tree_specs(tree, spec_fn, mesh, **kw):
-    def leaf_spec(path, leaf):
-        shape = tuple(leaf.shape)
-        if len(shape) == 0:
-            return P()
-        return spec_fn(shape, mesh, name=_leaf_name(path), **kw)
-    return jax.tree_util.tree_map_with_path(leaf_spec, tree)
-
-
-def fed_state_shardings(state_shapes, mesh: Mesh, fsdp: bool = True):
-    """NamedShardings for a FedState-like pytree of ShapeDtypeStructs."""
-    specs = _tree_specs(state_shapes, fed_param_spec, mesh, fsdp=fsdp)
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
-
-
-def serve_state_shardings(tree_shapes, mesh: Mesh, fsdp: bool = True):
-    specs = _tree_specs(tree_shapes, serve_param_spec, mesh, fsdp=fsdp)
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
-
-
 def fed_batch_spec(shape, mesh: Mesh) -> P:
     """Batch leaf (F, B, ...) on a fed mesh."""
     fed = ("pod", "fed") if "pod" in mesh.axis_names else "fed"
@@ -178,11 +149,3 @@ def cache_spec(shape, mesh: Mesh) -> P:
             break
     return P(*spec)
 
-
-def with_sharding(tree, mesh: Mesh, spec_fn):
-    """Attach NamedShardings to a ShapeDtypeStruct pytree."""
-    def attach(leaf):
-        spec = spec_fn(tuple(leaf.shape), mesh) if leaf.shape else P()
-        return jax.ShapeDtypeStruct(
-            leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec))
-    return jax.tree.map(attach, tree)

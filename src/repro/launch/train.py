@@ -1,8 +1,7 @@
 """End-to-end federated training driver (single host, any device count).
 
 Runs the C-DFL round loop (consensus + local Adam) for a selected
-architecture at a REDUCED size on synthetic token-LM data — the runnable
-counterpart of the dry-run (which exercises the full configs abstractly).
+architecture at a REDUCED size on synthetic token-LM data.
 
 Declared through the ``repro.experiment`` API: the CLI builds ONE
 ``RunConfig``, ``Experiment(config).compile(...)`` assembles the trainer
@@ -171,10 +170,6 @@ def main(argv=None):
     ap.add_argument("--max-cluster-size", type=int, default=16,
                     help="proximity-split cap on hierarchical cluster "
                          "membership (>= 2)")
-    ap.add_argument("--simulate-wire", action="store_true",
-                    help="force the wire-dtype cast roundtrip on backends "
-                         "where it would otherwise no-op-fuse (CPU "
-                         "simulation) — wire-precision studies")
     ap.add_argument("--mobility",
                     choices=("static",) + registry.mobility_traces.names(),
                     default="static",
@@ -305,21 +300,14 @@ def main(argv=None):
     if args.quick:
         n_seqs, args.batch = 64, min(args.batch, 4)
         args.seq = min(args.seq, 32)
-    import jax as _jax
-    if (args.wire_dtype != "f32" and not args.simulate_wire
-            and _jax.default_backend() == "cpu"):
-        print(f"note: wire_dtype={args.wire_dtype} no-op-fuses in CPU "
-              f"simulation (no physical wire; bytes below still priced "
-              f"at {args.wire_dtype}) — pass --simulate-wire to force "
-              f"the cast roundtrip for wire-precision studies")
 
     run_cfg = RunConfig(
         model=cfg,
         fed=FedConfig(num_nodes=args.nodes, local_steps=args.local_steps,
                       algorithm=args.algorithm, transport=args.transport,
                       wire_dtype=args.wire_dtype, staleness=args.staleness,
-                      simulate_wire=args.simulate_wire, mobility=mobility,
-                      faults=faults, robust=args.robust, trim=args.trim,
+                      mobility=mobility, faults=faults,
+                      robust=args.robust, trim=args.trim,
                       mixing_format=args.mixing_format,
                       hierarchy=hierarchy,
                       degree=(min(8, args.nodes - 1)

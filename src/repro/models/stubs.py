@@ -2,9 +2,8 @@
 
 [vlm] and [audio] architectures specify the transformer backbone only; the
 ViT/SigLIP vision encoder and the EnCodec conv codec are NOT implemented.
-``input_specs`` in repro.launch provides ShapeDtypeStruct stand-ins; these
-helpers generate concrete embeddings/tokens of the right shape for smoke
-tests and examples.
+These helpers generate concrete embeddings/tokens of the right shape for
+smoke tests and examples.
 
 musicgen note: real MusicGen decodes 4 interleaved EnCodec codebooks with a
 delay pattern; per the assignment ("decoder-only over EnCodec tokens",

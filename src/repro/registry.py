@@ -156,7 +156,7 @@ class AlgorithmSpec:
     ``mixing``: the :data:`mixing_policies` name its exchange weights
     use. ``uses_transport``: False for schemes with no once-per-round
     flat-buffer exchange to route (fedavg's server average, dpsgd's
-    per-step leaf-wise gossip). ``make``: trainer constructor with the
+    per-step gossip). ``make``: trainer constructor with the
     ``(loss_fn, fed, train, **kw) -> Trainer`` signature.
     """
 

@@ -164,12 +164,11 @@ def test_run_rounds_with_eval_fn():
 
 # --- flat-resident Adam: parity against the pytree-Adam oracle --------------
 #
-# FedState carries the Adam moments as (K, P) buffers and (with
-# flat_local=True, the accelerator lowering, forced here so CPU CI
-# covers it) the local steps run entirely on the flat buffer. The
-# oracle below re-implements a round from primitives — transport
-# exchange on the flat buffer, then per-node pytree Adam — with the
-# scan driver's documented batch-sampling contract, and must agree to
+# FedState carries the Adam moments as (K, P) buffers and the local
+# steps run entirely on the flat buffer. The oracle below re-implements
+# a round from primitives — transport exchange on the flat buffer, then
+# per-node pytree Adam on the f32 master params — with the scan
+# driver's documented batch-sampling contract, and must agree to
 # <=1e-6 over 20 rounds for every transport and under a mobility stack.
 
 from repro.core import flatten, topology, transport as transport_lib
@@ -178,16 +177,20 @@ from repro.optim import adam as make_adam
 
 
 def _oracle_run(fed, train_cfg, state, data, rounds, rng, etas, gammas,
-                trans):
+                trans, loss=None):
     """Pytree-Adam reference: flat mix via the transport, leaf-space
-    local steps, sampling keyed on the absolute round index."""
-    loss = simple.make_mlp_loss(MLP_CONFIG)
+    local steps on f32 master params (the gradient is taken at the
+    params' own dtype), sampling keyed on the absolute round index.
+    Returns the params at their own dtype and the Adam state."""
+    loss = loss or simple.make_mlp_loss(MLP_CONFIG)
     opt = make_adam(train_cfg.learning_rate, train_cfg.beta1,
                     train_cfg.beta2, train_cfg.eps,
                     train_cfg.weight_decay, train_cfg.grad_clip)
-    params = state.params
+    dtypes = jax.tree.map(lambda l: l.dtype, state.params)
+    layout = flatten.make_layout(state.params)
+    params = flatten.unflatten(flatten.flatten(state.params, layout)[0],
+                               layout, cast=False)
     opt_state = jax.vmap(opt.init)(params)
-    layout = flatten.make_layout(params)
     tstate = state.tstate
     max_items = data["x"].shape[1]
     k, s, b = fed.num_nodes, fed.local_steps, train_cfg.batch_size
@@ -197,12 +200,15 @@ def _oracle_run(fed, train_cfg, state, data, rounds, rng, etas, gammas,
         buf, _ = flatten.flatten(params, layout)
         buf, tstate = trans.exchange(buf, etas[r], gammas[r], tstate,
                                      jnp.int32(r))
-        params = flatten.unflatten(buf, layout)
+        params = flatten.unflatten(buf, layout, cast=False)
 
         def one_node(p, o, nd, ni):
             for t in range(s):
                 batch = jax.tree.map(lambda a: a[ni[t]], nd)
-                _, grads = jax.value_and_grad(loss)(p, batch)
+                view = jax.tree.map(lambda l, d: l.astype(d), p, dtypes)
+                _, grads = jax.value_and_grad(loss)(view, batch)
+                grads = jax.tree.map(lambda g: g.astype(jnp.float32),
+                                     grads)
                 p, o = opt.update(grads, o, p)
             return p, o
 
@@ -217,7 +223,7 @@ def _oracle_run(fed, train_cfg, state, data, rounds, rng, etas, gammas,
             os_.append(o_i)
         params = jax.tree.map(lambda *ls: jnp.stack(ls), *ps)
         opt_state = jax.tree.map(lambda *ls: jnp.stack(ls), *os_)
-    return params, opt_state
+    return jax.tree.map(lambda l, d: l.astype(d), params, dtypes), opt_state
 
 
 def _parity_setup(fed_kw, rounds=20, local_steps=2):
@@ -226,8 +232,7 @@ def _parity_setup(fed_kw, rounds=20, local_steps=2):
     loss = simple.make_mlp_loss(MLP_CONFIG)
     fed = FedConfig(num_nodes=4, local_steps=local_steps, **fed_kw)
     train_cfg = TrainConfig(learning_rate=1e-3, batch_size=16)
-    tr = build_trainer(lambda p, b: loss(p, b), fed, train_cfg,
-                       flat_local=True)
+    tr = build_trainer(lambda p, b: loss(p, b), fed, train_cfg)
     state = tr.init(jax.random.PRNGKey(0),
                     lambda r: simple.mlp_init(r, MLP_CONFIG),
                     jnp.asarray(batcher.node_items()))
@@ -284,37 +289,54 @@ def test_flat_adam_matches_pytree_oracle_under_mobility():
                                    atol=1e-6)
 
 
-def test_flat_and_leaf_local_representations_agree():
-    """The accelerator lowering (flat_local=True: params/moments stay in
-    the (K, P) buffers through the step loop) and the CPU lowering
-    (leaf-space loop, scan-boundary conversion) are the same arithmetic
-    in a different storage layout — results must agree to fusion noise,
-    with identical flat moments in FedState either way."""
-    rounds, rng = 8, jax.random.PRNGKey(9)
-    results = []
-    for flat_local in (True, False):
-        nodes = [synthetic.synthetic_mnist(seed=i, n=96) for i in range(4)]
-        batcher = pipeline.FederatedBatcher(nodes, 16, 2)
-        loss = simple.make_mlp_loss(MLP_CONFIG)
-        tr = build_trainer(lambda p, b: loss(p, b),
-                           FedConfig(num_nodes=4, local_steps=2),
-                           TrainConfig(learning_rate=1e-3, batch_size=16),
-                           flat_local=flat_local)
-        state = tr.init(jax.random.PRNGKey(0),
-                        lambda r: simple.mlp_init(r, MLP_CONFIG),
-                        jnp.asarray(batcher.node_items()))
-        data = {"x": jnp.asarray(np.stack([d.x for d in nodes])),
-                "y": jnp.asarray(np.stack([d.y for d in nodes]))}
-        results.append(tr.run_rounds(state, data, rounds, rng=rng)[0])
-    a, b = results
-    for x, y in zip(jax.tree.leaves(a.params), jax.tree.leaves(b.params)):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(a.opt.m), np.asarray(b.opt.m),
+def test_bf16_params_keep_an_f32_master_between_local_steps():
+    """A bf16-param model trains on the f32 master buffer: each local
+    step reads bf16 views of it, and the Adam update lands in f32, so
+    steps smaller than a bf16 ulp still accumulate. The oracle keeps the
+    same f32 master between steps; a loop that requantized the params
+    after every step would leave them where they started."""
+    rounds, rng = 3, jax.random.PRNGKey(4)
+    rs = np.random.default_rng(2)
+    data = {"x": jnp.asarray(rs.normal(size=(4, 32, 6)), jnp.float32),
+            "y": jnp.asarray(rs.normal(size=(4, 32)), jnp.float32)}
+
+    def loss(p, b):
+        return jnp.mean((b["x"] @ p["w"].astype(jnp.float32) + p["b"]
+                         - b["y"]) ** 2)
+
+    def init(r):
+        return {"w": (1.0 + jax.random.uniform(r, (6,))).astype(
+                    jnp.bfloat16),
+                "b": jnp.ones((1,), jnp.bfloat16)}
+
+    fed = FedConfig(num_nodes=4, local_steps=3)
+    train_cfg = TrainConfig(learning_rate=1e-3, batch_size=8)
+    tr = build_trainer(loss, fed, train_cfg)
+    items = jax.random.randint(jax.random.PRNGKey(1), (4, 32, 4), 0, 50)
+    state = tr.init(jax.random.PRNGKey(0), init, items)
+    eta = tr.eta_fn(state)
+    gamma = topology.stable_gamma(eta, fed.gamma)
+    etas = jnp.broadcast_to(eta, (rounds,) + eta.shape)
+    gammas = jnp.full((rounds,), gamma)
+    init_w = np.asarray(state.params["w"], np.float32)
+    exp_params, exp_opt = _oracle_run(
+        fed, train_cfg, state, data, rounds, rng, etas, gammas,
+        transport_lib.make_transport(fed), loss=loss)
+    final, _ = tr.run_rounds(state, data, rounds, rng=rng)
+    for a, b in zip(jax.tree.leaves(final.params),
+                    jax.tree.leaves(exp_params)):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    layout = flatten.make_layout(exp_params)
+    np.testing.assert_allclose(np.asarray(final.opt.m),
+                               np.asarray(flatten.flatten(exp_opt.m,
+                                                          layout)[0]),
                                atol=1e-6)
-    np.testing.assert_allclose(np.asarray(a.opt.v), np.asarray(b.opt.v),
-                               atol=1e-6)
-    np.testing.assert_array_equal(np.asarray(a.opt.step),
-                                  np.asarray(b.opt.step))
+    # 9 steps of about lr each move the weights by more than a bf16 ulp
+    # at 1.0-2.0 (2 ** -7), which a step of lr alone never does
+    assert np.abs(np.asarray(final.params["w"], np.float32)
+                  - init_w).max() >= 2 ** -7
 
 
 # --- the compiled prepare program -------------------------------------------
@@ -419,14 +441,14 @@ def test_continuing_static_session_lowers_nothing_after_second_call():
     assert session.rounds_completed == 180
 
 
-@pytest.mark.parametrize("flat_local", [False, True],
-                         ids=["leaf_local", "flat_local"])
-def test_scalar_loss_and_loss_with_empty_aux_lower_alike(flat_local):
+@pytest.mark.parametrize("fmt", ["dense", "sparse"])
+def test_scalar_loss_and_loss_with_empty_aux_lower_alike(fmt):
     """A loss may return ``(loss, aux)``; one that returns a scalar traces
     the very program that the same loss returning ``(loss, {})`` does, so
     models without counters run what they ran before."""
     loss = simple.make_mlp_loss(MLP_CONFIG)
-    fed = FedConfig(num_nodes=4, local_steps=3)
+    fed = FedConfig(num_nodes=4, local_steps=3, mixing_format=fmt,
+                    degree=2)
     train = TrainConfig(batch_size=8)
     key = jax.random.PRNGKey(0)
     data = {"x": jax.random.normal(key, (4, 32, 784)),
@@ -434,7 +456,7 @@ def test_scalar_loss_and_loss_with_empty_aux_lower_alike(flat_local):
     items = jax.random.randint(key, (4, 32, 16), 0, 4096)
     texts = []
     for fn in (loss, lambda p, b: (loss(p, b), {})):
-        tr = build_trainer(fn, fed, train, flat_local=flat_local)
+        tr = build_trainer(fn, fed, train)
         state = tr.init(key, lambda r: simple.mlp_init(r, MLP_CONFIG), items)
         texts.append(tr.lower_rounds(state, data, 2, rng=key).as_text())
     assert texts[0] == texts[1]

@@ -220,12 +220,56 @@ def test_sparse_training_matches_dense_when_degree_covers(algorithm):
     assert np.isfinite(np.asarray(ms["loss"])).all()
 
 
-def test_dpsgd_flat_and_leaf_lowerings_agree():
+def _dpsgd_oracle(loss_fn, train_cfg, params, data, eta, gamma, rounds,
+                  rng, local_steps, batch_size):
+    """D-PSGD from primitives: every local step mixes each parameter
+    leaf by the eq. 5 delta form, then takes one pytree-Adam step per
+    node; a round's minibatch indices come from ``fold_in(rng, r)``."""
+    from repro.optim import adam
+    opt = adam(train_cfg.learning_rate, train_cfg.beta1, train_cfg.beta2,
+               train_cfg.eps, train_cfg.weight_decay, train_cfg.grad_clip)
+    opt_state = jax.vmap(opt.init)(params)
+    eta = jnp.asarray(eta, jnp.float32)
+    row = eta.sum(axis=1)
+    k, n = data["x"].shape[:2]
+
+    def mix(leaf):
+        flat = leaf.reshape(k, -1)
+        return (flat + gamma * (eta @ flat - row[:, None] * flat)
+                ).reshape(leaf.shape)
+
+    for r in range(rounds):
+        idx = jax.random.randint(jax.random.fold_in(rng, r),
+                                 (k, local_steps, batch_size), 0, n)
+        for t in range(local_steps):
+            params = jax.tree.map(mix, params)
+            batch = jax.tree.map(
+                lambda a: jnp.stack([a[i][idx[i, t]] for i in range(k)]),
+                data)
+            grads = jax.vmap(jax.grad(loss_fn))(params, batch)
+            params, opt_state = jax.vmap(opt.update)(grads, opt_state,
+                                                     params)
+    return params
+
+
+def test_dpsgd_matches_per_step_gossip_oracle():
+    loss_fn, init_params, data, items = _mini_problem()
     fed = FedConfig(num_nodes=6, topology="ring", algorithm="dpsgd",
                     local_steps=3)
-    w_flat, _ = _final_params(fed, flat_local=True)
-    w_leaf, _ = _final_params(fed, flat_local=False)
-    np.testing.assert_allclose(w_flat, w_leaf, atol=1e-6)
+    train_cfg = TrainConfig(batch_size=8, learning_rate=1e-2, seed=0)
+    tr = cdfl.build_trainer(loss_fn, fed, train_cfg)
+    st = tr.init(jax.random.PRNGKey(0), init_params, items,
+                 same_init=False)
+    eta = tr.eta_fn(st)
+    gamma = topology.stable_gamma(eta, fed.gamma)
+    rounds, rng = 3, jax.random.PRNGKey(11)
+    data = jax.tree.map(jnp.asarray, data)
+    expect = _dpsgd_oracle(loss_fn, train_cfg, st.params, data, eta,
+                           gamma, rounds, rng, fed.local_steps,
+                           train_cfg.batch_size)
+    final, _ = tr.run_rounds(st, data, rounds, rng=rng)
+    np.testing.assert_allclose(np.asarray(final.params["w"]),
+                               np.asarray(expect["w"]), atol=1e-6)
 
 
 def test_dpsgd_opt_state_is_flat_resident():
